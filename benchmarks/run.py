@@ -213,7 +213,11 @@ def write_bench_serving(rows: "list[dict]") -> None:
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
+
     from . import filter_variants, overhead, pruning, robustness, state_of_art, trace_stats
+
+    use_compile_cache(_ROOT)
 
     benches = {
         "trace_stats": trace_stats.main,  # Table 1 / Fig 8

@@ -125,12 +125,13 @@ def _prompt(template: int, tmpl_len: int, rid: int, suffix_len: int) -> list:
 
 def run_load(policy: str, admission: str, trace, *, arch: str = LOAD_ARCH,
              ws_frac: float = 0.15, chunk: "int | None" = None,
-             block_size: int = 16, max_running: int = 16) -> dict:
+             block_size: int = 16, max_running: int = 16):
     """Drive one arrival trace end to end: submit on the arrival clock,
     schedule (live KV blocks from the shared pool, preempting under
     pressure), look up / offer each prefilled prompt, decode to
     completion. Pure bookkeeping — wall time is dominated by the
-    admission path, which is the thing under test."""
+    admission path, which is the thing under test. Returns the result
+    row and the driven :class:`PrefixCache`."""
     cfg = get_config(arch)
     bpt = kv_bytes_per_token(cfg)
     tmpl_lens = {}
@@ -230,7 +231,7 @@ def run_load(policy: str, admission: str, trace, *, arch: str = LOAD_ARCH,
         "us_per_access": round(wall / max(1, n) * 1e6, 2),
     }
     cache.pool.check_invariants()
-    return row
+    return row, cache
 
 
 def trace_name(trace) -> str:
@@ -258,10 +259,10 @@ def load_main(quick: "bool | None" = None) -> list[dict]:
         run_load(LOAD_POLICY, adm, trace)
 
     rows = []
-    sync_row = run_load(LOAD_POLICY, "sync", trace)
-    async_row = run_load(LOAD_POLICY, "async", trace)
+    sync_row, _ = run_load(LOAD_POLICY, "sync", trace)
+    async_row, _ = run_load(LOAD_POLICY, "async", trace)
     rows += [sync_row, async_row]
-    rows.append(run_load("wtlfu-av", "async", trace))  # host-plane context
+    rows.append(run_load("wtlfu-av", "async", trace)[0])  # host-plane context
 
     # acceptance: equal hit ratios (byte-identical decisions), higher
     # sustained request rate for the async pipeline

@@ -29,6 +29,10 @@ import numpy as np
 __all__ = ["CMSSketch"]
 
 
+def _pow2(n: int) -> int:
+    return 1 << max(0, n - 1).bit_length()
+
+
 class CMSSketch:
     """Drop-in ``increment``/``estimate`` sketch backed by the batched CMS
     kernels, plus ``estimate_batch`` for one-call victim-set scoring.
@@ -64,29 +68,21 @@ class CMSSketch:
         import jax  # deferred: keep repro.core importable without jax
         import jax.numpy as jnp
 
-        from repro.kernels.cms.cms import (
-            cms_estimate_pallas,
-            cms_update_estimate_pallas,
-            cms_update_pallas,
-        )
+        from repro.kernels.cms.ops import flush_scores_jit
         from repro.kernels.cms.ref import (
             ROWS,
             cms_estimate_ref,
             cms_update_estimate_ref,
             cms_update_ref,
-            row_indexes,
         )
 
         self._jnp = jnp
         self._on_tpu = jax.default_backend() == "tpu"
         self.use_pallas = self._on_tpu if use_pallas is None else use_pallas
-        self._update_pallas = cms_update_pallas
-        self._estimate_pallas = cms_estimate_pallas
-        self._update_estimate_pallas = cms_update_estimate_pallas
+        self._flush_scores = flush_scores_jit
         self._update_ref = cms_update_ref
         self._estimate_ref = cms_estimate_ref
         self._update_estimate_ref = cms_update_estimate_ref
-        self._row_indexes = row_indexes
 
         expected_entries = max(16, int(expected_entries))
         width = 128
@@ -103,14 +99,26 @@ class CMSSketch:
         self._pending: list[int] = []
 
     # -- batched data plane ------------------------------------------------
+    def _pallas(self, upd: np.ndarray, est: np.ndarray):
+        """Apply ``upd`` increments, then estimate ``est`` on the updated
+        table: one jitted fused Pallas call. Both batches are padded to
+        powers of two (padded update lanes masked to the out-of-range
+        sentinel, padded estimates dropped), so the batch lengths a run
+        meets compile a handful of programs, not one each."""
+        u = np.zeros(_pow2(len(upd)), np.int32)
+        u[: len(upd)] = upd.astype(np.int32)
+        e = np.zeros(_pow2(len(est)), np.int32)
+        e[: len(est)] = est.astype(np.int32)
+        table, vals = self._flush_scores(
+            self.table, u, len(upd), e, cap=self.cap, use_pallas=True,
+            interpret=not self._on_tpu)
+        return table, np.asarray(vals)[: len(est)]
+
     def _apply(self, keys_np: np.ndarray) -> None:
-        keys = self._jnp.asarray(keys_np.astype(np.int32))
         if self.use_pallas:
-            idx = self._row_indexes(keys, self.width)
-            self.table = self._update_pallas(
-                self.table, idx, cap=self.cap, interpret=not self._on_tpu
-            )
+            self.table, _ = self._pallas(keys_np, keys_np[:0])
         else:
+            keys = self._jnp.asarray(keys_np.astype(np.int32))
             self.table = self._update_ref(self.table, keys, cap=self.cap)
 
     def flush(self) -> None:
@@ -156,30 +164,18 @@ class CMSSketch:
         if 0 < n <= self.flush_block and self._ops + n < self.sample_size:
             upd = np.asarray(pending, dtype=np.int64).astype(np.int32)
             est = np.asarray(keys, dtype=np.int64).astype(np.int32)
-            jupd = self._jnp.asarray(upd)
-            jest = self._jnp.asarray(est)
             if self.use_pallas:
-                upd_idx = self._row_indexes(jupd, self.width)
-                est_idx = self._row_indexes(jest, self.width)
-                self.table, vals = self._update_estimate_pallas(
-                    self.table, upd_idx, est_idx, cap=self.cap,
-                    interpret=not self._on_tpu,
-                )
-                vals = vals.min(0)
+                self.table, vals = self._pallas(upd, est)
             else:
                 self.table, vals = self._update_estimate_ref(
-                    self.table, jupd, jest, cap=self.cap
+                    self.table, self._jnp.asarray(upd),
+                    self._jnp.asarray(est), cap=self.cap
                 )
             self._ops += n
             self._pending = []
             return np.asarray(vals)
         self.flush()
         keys = np.asarray(keys, dtype=np.int64).astype(np.int32)
-        jkeys = self._jnp.asarray(keys)
         if self.use_pallas:
-            idx = self._row_indexes(jkeys, self.width)
-            vals = self._estimate_pallas(self.table, idx, interpret=not self._on_tpu)
-            vals = vals.min(0)
-        else:
-            vals = self._estimate_ref(self.table, jkeys)
-        return np.asarray(vals)
+            return self._pallas(keys[:0], keys)[1]
+        return np.asarray(self._estimate_ref(self.table, self._jnp.asarray(keys)))

@@ -70,7 +70,6 @@ through the same int32 hash-input truncation as ``CMSSketch``.
 from __future__ import annotations
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -80,12 +79,6 @@ from repro.core import crng
 
 from .cms.ops import _mix64_u32, _mul64_const, flush_scores
 from .cms.ref import row_indexes
-
-# Buffer donation is a no-op off-accelerator; silence the one warning
-# XLA:CPU emits per launch so CPU test runs stay clean.
-warnings.filterwarnings(
-    "ignore", message="Some donated buffers were not usable", category=UserWarning
-)
 
 __all__ = [
     "DeviceAdmissionPlane",
@@ -1150,13 +1143,20 @@ class DeviceBatchedAdmissionPlane:
         self._queue = suffix + [(k, s, bb - applied_b) for k, s, bb in self._queue]
         self._rebuild_pending()
 
+    def _statics(self) -> dict:
+        """The static kwargs of `_decide_sampled_chunk` for this plane."""
+        sk, main, dev = self.sketch, self.main, self.device
+        return dict(discipline=dev.discipline, rule=main.rule,
+                    sample=main.SAMPLE, early_pruning=dev.early_pruning,
+                    cap=sk.cap, use_pallas=sk.use_pallas,
+                    interpret=dev._interpret, vcap=self.victim_cap)
+
     def _dispatch(self, pol, q) -> "_InFlightChunk":
         """One `_decide_sampled_chunk` launch over ``q`` — host-side prep
         plus the (async) kernel call, WITHOUT materializing the result.
         Pair with :meth:`_apply`."""
         sk = self.sketch
         main = self.main
-        dev = self.device
         n0 = len(main.keys)
         nq = len(q)
         b_last = q[-1][2]
@@ -1194,11 +1194,7 @@ class DeviceBatchedAdmissionPlane:
         mkeys, msizes, wr = self.mirror.device_state_packed()
         table, mkeys, msizes, out, victims = _decide_sampled_chunk(
             sk.table, mkeys, msizes, wr, jnp.asarray(upd), jnp.asarray(meta),
-            jnp.asarray(scal), jnp.asarray(key_limbs),
-            discipline=dev.discipline, rule=main.rule, sample=main.SAMPLE,
-            early_pruning=dev.early_pruning, cap=sk.cap,
-            use_pallas=sk.use_pallas, interpret=dev._interpret,
-            vcap=self.victim_cap)
+            jnp.asarray(scal), jnp.asarray(key_limbs), **self._statics())
         self.chunk_calls += 1
         # adopt the (async) output buffers NOW: the inputs were donated to
         # the launch and must not be read again. The scan masks segment

@@ -24,6 +24,7 @@ __all__ = [
     "update_estimate",
     "update_estimate_segments",
     "flush_scores",
+    "flush_scores_jit",
     "reset",
     "counter_draws",
     "DeviceSketch",
@@ -165,6 +166,11 @@ def flush_scores(table, upd_keys, n_pend, est_keys, *, cap, use_pallas, interpre
     new_table = jnp.minimum(table + counts, cap)
     vals = jnp.take_along_axis(new_table, est_idx, axis=1)
     return new_table, vals.min(0)
+
+
+#: :func:`flush_scores` as one jitted call, for callers outside a trace.
+flush_scores_jit = jax.jit(flush_scores,
+                           static_argnames=("cap", "use_pallas", "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "use_pallas"))

@@ -51,7 +51,6 @@ compares and the int32 sketch hash-input truncation both hold.
 from __future__ import annotations
 
 import functools
-import warnings
 from collections import OrderedDict
 
 import jax
@@ -74,12 +73,6 @@ from .cms.ref import row_indexes
 
 __all__ = ["DeviceFullSimulationPlane", "OrderedDeviceMirror"]
 
-# Donating the state buffers is a no-op off-accelerator; silence the one
-# warning XLA:CPU emits per launch so CPU test runs stay clean.
-warnings.filterwarnings(
-    "ignore", message="Some donated buffers were not usable", category=UserWarning
-)
-
 #: Renormalize recency stamps (via a host download/re-upload) before the
 #: int32 tick counter could overflow mid-chunk.
 _TICK_RENORM = 1 << 30
@@ -97,6 +90,8 @@ _CONST_FIELDS = (
     "win_min", "win_max", "a_n",
 )
 _SCAL_IDX = {name: i for i, name in enumerate(_CARRY_FIELDS + _CONST_FIELDS)}
+#: Packed fields that may wrap: the climber only reads their int32 delta.
+_WRAP_FIELDS = ("hits", "prev_hits")
 
 
 def _freq_of(table, keys32):
@@ -1062,8 +1057,12 @@ class DeviceFullSimulationPlane:
             return True
         if (self._n + self._wn + take > self.mirror.slots
                 or self._wn + take > self.mirror.wslots):
-            self.mirror.grow(self._n + self._wn + max(take, self.chunk),
-                             self._wn + max(take, self.chunk))
+            # grow Main straight to the sketch width (sized for the expected
+            # resident count): each slot count is its own compiled program,
+            # and every access already sweeps that width in the CMS kernel
+            self.mirror.grow(
+                max(self._n + self._wn + max(take, self.chunk), sk.width),
+                self._wn + max(take, self.chunk))
             self.resyncs += 1
             self.resync_reasons["mirror_grow"] += 1
         return False
@@ -1091,6 +1090,11 @@ class DeviceFullSimulationPlane:
             ("win_min", max(1, pol.capacity // 100)),
             ("win_max", pol.capacity // 2), ("a_n", take),
         ):
+            if name not in _WRAP_FIELDS and not -(2**31) <= v < 2**31:
+                raise ValueError(
+                    f"device_full plane: {name}={v} does not fit int32 "
+                    f"(capacity {pol.capacity}); byte quantities past "
+                    "2**31 - 1 are not supported on the device planes")
             vals[_SCAL_IDX[name]] = v
         return np.asarray(vals, np.int64).astype(np.int32)
 
@@ -1121,7 +1125,9 @@ class DeviceFullSimulationPlane:
         self._preflight(pol, take)
         scal = self._pack_scal(pol, take)
         limbs = self._rng_limbs()
-        pad = _next_pow2(max(8, take))
+        # one scan length per plane (not per take): a take cut short at an
+        # aging boundary must not compile a new program
+        pad = _next_pow2(max(8, self.chunk))
         xhi = np.zeros(pad, np.int32)
         xlo = np.zeros(pad, np.int32)
         xsz = np.zeros(pad, np.int32)

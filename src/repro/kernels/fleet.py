@@ -79,9 +79,14 @@ def _fleet_chunk(table, mk_hi, mk_lo, msz, mstamp, mseg,
         _simulate_chunk_impl, discipline=discipline, rule=rule, sample=sample,
         early_pruning=early_pruning, adaptive=adaptive, main_kind=main_kind,
         cap=cap, use_pallas=use_pallas, interpret=interpret)
-    return jax.vmap(f)(table, mk_hi, mk_lo, msz, mstamp, mseg,
-                       wk_hi, wk_lo, wsz, wstamp,
-                       xs_hi, xs_lo, xs_sz, scal, key_limbs)
+    args = (table, mk_hi, mk_lo, msz, mstamp, mseg, wk_hi, wk_lo, wsz, wstamp,
+            xs_hi, xs_lo, xs_sz, scal, key_limbs)
+    if table.shape[0] == 1:
+        # nothing to batch: under vmap every lax.cond of the scan becomes a
+        # select that runs both branches on every access, so a one-lane
+        # bucket (each combo of a grid sweep) runs the single-cache program
+        return tuple(out[None] for out in f(*(a[0] for a in args)))
+    return jax.vmap(f)(*args)
 
 
 @functools.partial(jax.jit, donate_argnums=tuple(range(10)))
@@ -391,7 +396,7 @@ class FleetEngine:
         self._ensure_stacks(b, uploaded)
 
         n = len(b.members)
-        pad = _next_pow2(max(8, max(takes)))
+        pad = _next_pow2(max([8] + [m.pipe.chunk for m in b.members]))
         xhi = np.zeros((n, pad), np.int32)
         xlo = np.zeros((n, pad), np.int32)
         xsz = np.zeros((n, pad), np.int32)

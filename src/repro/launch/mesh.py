@@ -15,12 +15,19 @@ HBM_BW = 819e9  # bytes/s per chip
 ICI_BW_PER_LINK = 50e9  # bytes/s per link (~)
 
 
+def _auto(axes):
+    """``Auto`` axis types: ``jax.make_mesh`` defaults to ``Explicit``, which
+    ``with_sharding_constraint`` (``distributed/sharding.py``) refuses."""
+    return (jax.sharding.AxisType.Auto,) * len(axes)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_host_mesh(data: int = 2, model: int = 4):
     """Small mesh over host CPU devices for distributed tests."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(("data", "model")))

@@ -797,6 +797,24 @@ class TestDeviceFullPlane:
         for got, want in zip(pipe.mirror.window, outs[6:10]):
             assert got is want, "mirror window array was copied, not adopted"
 
+    @pytest.mark.parametrize("drive", ["sequential", "fleet"])
+    def test_capacity_past_int32_refused(self, drive):
+        """The packed scalar vector is int32: a 4 GiB capacity used to wrap
+        silently into a negative capacity. Both drives must refuse it."""
+        from repro.kernels.fleet import FleetEngine
+
+        p = REGISTRY.build("wtlfu-av-slru?data_plane=device_full&chunk=8",
+                           4 << 30, expected_entries=64)
+        keys = np.arange(16, dtype=np.int64)
+        sizes = np.full(16, 1000, np.int64)
+        with pytest.raises(ValueError, match="int32"):
+            if drive == "sequential":
+                p.access_batch(keys, sizes)
+            else:
+                eng = FleetEngine()
+                eng.add(p, keys, sizes)
+                eng.run()
+
     def test_device_batched_dispatch_adopts_donated_buffers(self):
         """ISSUE 7 satellite (device_batched side): `_decide_sampled_chunk`
         donates (table, mkeys, msizes); the pipeline must adopt the launch

@@ -5,6 +5,7 @@ run in a subprocess (device count must be set before jax init)."""
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -143,6 +144,6 @@ class TestSmallMeshLowering:
         )
         out = subprocess.run(
             [sys.executable, "-c", script], capture_output=True, text=True,
-            timeout=560, cwd="/root/repo",
+            timeout=560, cwd=Path(__file__).resolve().parents[1],
         )
         assert "SUBPROC_OK" in out.stdout, out.stderr[-2000:]

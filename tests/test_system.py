@@ -177,6 +177,53 @@ class TestBenchTrajectory:
             m.BENCH_OVERHEAD_PATH.read_text())["history"]) == 3
 
 
+class TestCompileCachePlacement:
+    """``repro.compile_cache.use_compile_cache``: compiled programs land in
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set and nowhere else, and in
+    ``<root>/.jax_cache`` when it is not. Each case runs in a fresh process,
+    because JAX opens its persistent cache once per process."""
+
+    SCRIPT = (
+        "import sys, jax, jax.numpy as jnp\n"
+        "from repro.compile_cache import use_compile_cache\n"
+        "print(use_compile_cache(sys.argv[1]))\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "jax.config.update('jax_persistent_cache_min_entry_size_bytes', 0)\n"
+        "jax.jit(lambda x: x * 3 + 1)(jnp.arange(8)).block_until_ready()\n"
+    )
+
+    def _run(self, root, env_dir):
+        import os
+        import subprocess
+        import sys
+
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env["JAX_PLATFORMS"] = "cpu"
+        src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+        env["PYTHONPATH"] = os.pathsep.join(
+            [src] + [p for p in [env.get("PYTHONPATH")] if p])
+        if env_dir is not None:
+            env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+        out = subprocess.run([sys.executable, "-c", self.SCRIPT, str(root)],
+                             env=env, capture_output=True, text=True,
+                             timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        return out.stdout.strip().splitlines()[-1]
+
+    def test_default_is_fixed_dir_in_root(self, tmp_path):
+        used = self._run(tmp_path, None)
+        assert used == str(tmp_path.resolve() / ".jax_cache")
+        assert any((tmp_path / ".jax_cache").iterdir())
+
+    def test_env_dir_wins_and_root_untouched(self, tmp_path):
+        env_dir = tmp_path / "from_env"
+        used = self._run(tmp_path, env_dir)
+        assert used == str(env_dir)
+        assert any(env_dir.iterdir())
+        assert not (tmp_path / ".jax_cache").exists()
+
+
 @pytest.fixture(scope="module")
 def traces():
     return {n: make_trace(n, seed=0, scale=0.03) for n in ("msr2", "cdn1")}

@@ -31,7 +31,7 @@ def bench_scale() -> float:
 
 def sequential_mode() -> bool:
     """``--sequential`` escape hatch: drive sweeps as per-policy loops
-    instead of the vmapped fleet path."""
+    instead of the fleet path."""
     return bool(os.environ.get("REPRO_BENCH_SEQUENTIAL"))
 
 
@@ -101,11 +101,11 @@ def snapshot_dicts(snapshots) -> list[dict]:
 
 def run_policies_fleet(jobs, trace, *, snapshot_every: "int | None" = None,
                        with_snapshots: bool = False) -> list[dict]:
-    """Drive many W-TinyLFU configs over one trace as ONE vmapped fleet.
+    """Drive many W-TinyLFU configs over one trace as ONE fleet.
 
     ``jobs`` is a list of ``(spec, cap)`` pairs; every member is built with
     ``data_plane="device_full"`` and the whole grid advances through
-    :class:`repro.kernels.fleet.FleetEngine` — one vmapped launch per
+    :class:`repro.kernels.fleet.FleetEngine` — one launch per
     shape-bucket per chunk instead of a sequential per-policy loop.
     Returns result rows parallel to ``jobs`` (same fields as
     :func:`run_policy`, plus ``mode="fleet"``; ``us_per_access`` is the

@@ -334,8 +334,8 @@ FLEET_SEEDS = (0, 1, 2, 3)
 #: a sequential loop pays it once per instance per chunk (the fleet once
 #: per shape-bucket per chunk). Finer chunks are also the low-latency
 #: end of the device plane's sync-cadence knob, not a synthetic setting.
-#: At the default chunk (64) the scan body dominates and vmapping its
-#: both-branch ``lax.cond`` lanes roughly breaks even on XLA-CPU.
+#: At the default chunk (64) the scan body dominates, and a bucket's
+#: launch costs about the sum of its lanes' scans.
 FLEET_CHUNK = 8
 
 
@@ -347,7 +347,7 @@ def fleet_rows(traces=("msr2",), frac=0.001, seeds=FLEET_SEEDS,
     policy combo x per-instance seed, one shape-bucket per combo) are
     driven twice: once as the sequential per-policy loop the sweeps used
     to be, once stacked in one :class:`repro.kernels.fleet.FleetEngine`
-    (one vmapped launch per shape-bucket per chunk), both at the same
+    (one launch per shape-bucket per chunk), both at the same
     ``chunk`` (see :data:`FLEET_CHUNK`). Both arms are warmed untimed
     first. Per-instance hit ratios must match exactly (hard ``raise``);
     ``fleet_speedup`` = sequential wall over fleet wall — the tentpole

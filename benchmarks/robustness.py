@@ -56,7 +56,7 @@ def sharded_rows(tname=SHARDED_TRACE, n_shards=SHARDED_SHARDS,
                  spec=SHARDED_SPEC, frac=0.01) -> list[dict]:
     """Hash-partitioned deployment curves: one trace split over
     ``n_shards`` cache shards (aggregate + per-shard hit ratios), the
-    whole fleet advancing in vmapped launches."""
+    whole fleet advancing in one launch per bucket."""
     from repro.core import REGISTRY, PolicySpec
     from repro.kernels.fleet import FleetEngine
 

@@ -24,11 +24,11 @@ rows against the trajectory baseline: a >15% accesses/sec drop for any
 ``(policy, data_plane, trace, capacity, backend, mode)`` row flags the
 row in the written entry and — under ``REPRO_BENCH_STRICT=1`` (the
 nightly bench jobs) — fails the run. The key includes the hardware
-backend and the drive mode (vmapped fleet vs sequential) so a CPU row
+backend and the drive mode (fleet vs sequential) so a CPU row
 landing after an accelerator row, or a per-policy-loop row after a fleet
 row, can never raise a false regression.
 
-Flags: ``--quick`` (smoke tier), ``--sequential`` (bypass the vmapped
+Flags: ``--quick`` (smoke tier), ``--sequential`` (bypass the
 fleet sweep path in state_of_art/robustness/overhead; also honored as
 ``REPRO_BENCH_SEQUENTIAL=1``).
 """
@@ -246,7 +246,7 @@ def main() -> None:
         args.remove("--quick")
         os.environ["REPRO_BENCH_QUICK"] = "1"
     if "--sequential" in args:  # escape hatch: per-policy loops instead of
-        args.remove("--sequential")  # the vmapped fleet sweep path
+        args.remove("--sequential")  # the fleet sweep path
         os.environ["REPRO_BENCH_SEQUENTIAL"] = "1"
     selected = args or list(benches)
     print("name,us_per_call,derived")

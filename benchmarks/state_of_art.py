@@ -22,7 +22,7 @@ def main(traces=PAPER_TRACES, fracs=FRACS, policies=POLICIES) -> list[dict]:
         caps = {frac: max(1, int(tr.total_object_bytes * frac))
                 for frac in fracs}
         # the W-TinyLFU grid (every policy x capacity for this trace) rides
-        # one vmapped fleet; the comparison policies keep the scalar loop
+        # one fleet; the comparison policies keep the scalar loop
         fleet = {}
         wtlfu = [(pol, frac) for frac in fracs for pol in policies
                  if pol.startswith("wtlfu")]

@@ -16,14 +16,19 @@ entry points a user calls (registry spec -> ``SimulationEngine`` /
    window contents must be identical.
 2. ``fleet``: the 21 admission x main combos over the first 200k accesses in
    one ``FleetEngine`` (past the cache fill and three sketch agings; the
-   cut from the whole trace keeps the run inside its time limit); two
-   members must equal their sequential ``device_full`` runs.
-3. ``serving``: the bursty prefix-cache load under sync and async admission;
+   cut from the whole trace keeps the run inside its time limit). Each
+   combo is its own one-lane bucket; two members must equal their
+   sequential ``device_full`` runs.
+3. ``sharded``: ``FleetEngine.sharded`` splits the same 200k accesses over
+   4 shard caches of each single spec, one 4-lane bucket (one launch per
+   round for all four); every shard must equal its sequential run.
+4. ``serving``: the bursty prefix-cache load under sync and async admission;
    their request, token and byte hit ratios must be identical.
 
 On the chip every plane must run compiled Pallas (``use_pallas`` and not
-``interpret``), the chunk programs must hold a ``tpu_custom_call``, and the
-donated sketch table must be consumed by the first launch. Each phase
+``interpret``), the first launch of each chunk program is also lowered with
+its own arguments and must hold a ``tpu_custom_call``, and the donated
+sketch table must be consumed by the first launch. Each phase
 prints one JSON line of readings; the last line is
 ``{"ok": true, "device": {...}}``. A failed check raises, so the script
 exits non-zero and prints no such line.
@@ -32,6 +37,7 @@ exits non-zero and prints no such line.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -54,6 +60,8 @@ STAT_FIELDS = ("accesses", "hits", "bytes_hit", "bytes_requested",
                "victims_examined", "admissions", "rejections", "evictions")
 #: cache capacity as a share of the trace's total object bytes
 CAPACITY_SHARE = 0.10
+#: shard caches of the ``sharded`` phase, each with its share of capacity
+SHARDS = 4
 
 #: ``ref``: accesses checked against the scalar reference; ``fleet``:
 #: accesses per fleet member. The rehearsal cuts scale only.
@@ -139,52 +147,31 @@ def check_pallas(pol, env: Env, what: str) -> None:
         check(interpret is True, f"{what}: compiled Pallas requested off-TPU")
 
 
-def _sds(shape, dtype=None):
-    import jax
-    import jax.numpy as jnp
+@contextlib.contextmanager
+def first_launch_lowered(module, name: str, env: Env, what: str):
+    """Wrap the jitted chunk program ``module.name`` so its first launch is
+    also lowered with that launch's own arguments (lowering reads shapes
+    only, so the donated buffers are untouched). On the chip the program
+    must call the Pallas kernel; the rehearsal lowers too, so the shapes
+    are checked before a chip run. Yields the lowered launch's arguments."""
+    real = getattr(module, name)
+    first: list = []
 
-    return jax.ShapeDtypeStruct(tuple(shape), dtype or jnp.int32)
+    def probe(*args, **kw):
+        if not first:
+            first.append(args)
+            text = real.lower(*args, **kw).as_text()
+            if env.on_tpu:
+                check("tpu_custom_call" in text,
+                      f"{what}: no tpu_custom_call in the lowered {name}")
+        return real(*args, **kw)
 
-
-def check_lowered(fn, args, statics: dict, env: Env) -> None:
-    """Lower ``fn`` (a chunk program) at a plane's current shapes; on the
-    chip it must call the Pallas kernel. The rehearsal lowers too, so the
-    shapes are checked before a chip run."""
-    text = fn.lower(*args, **statics).as_text()
-    if env.on_tpu:
-        check("tpu_custom_call" in text,
-              f"{fn.__name__}: no tpu_custom_call in the lowered chunk program")
-
-
-def check_chunk_program(fn, pol, env: Env, lanes: "int | None" = None) -> None:
-    """``check_lowered`` for the single-cache or the fleet chunk program."""
-    import jax.numpy as jnp
-
-    from repro.kernels.device_full import _SCAL_IDX, _next_pow2
-
-    pipe = pol._device_pipeline
-    lead = () if lanes is None else (lanes,)
-    pad = _next_pow2(max(8, pipe.chunk))
-    bufs = [pipe.sketch.table, *pipe.mirror.main, *pipe.mirror.window]
-    args = [_sds(lead + a.shape, a.dtype) for a in bufs]
-    args += [_sds(lead + (pad,))] * 3
-    args += [_sds(lead + (len(_SCAL_IDX),)), _sds(lead + (2,), jnp.uint32)]
-    check_lowered(fn, args, pipe._statics(pol), env)
-
-
-def check_decision_program(pipe, env: Env) -> None:
-    """``check_lowered`` for the serving plane's decision-chunk program
-    (an 8-decision chunk with the smallest pending-segment bucket)."""
-    import jax.numpy as jnp
-
-    from repro.kernels.admission import _WRITE_PAD, _decide_sampled_chunk
-
-    table = pipe.sketch.table
-    slots = (pipe.mirror._cap,)
-    args = [_sds(table.shape, table.dtype), _sds(slots), _sds(slots),
-            _sds((3, _WRITE_PAD)), _sds((8, 16)), _sds((8, 4)), _sds((3,)),
-            _sds((2,), jnp.uint32)]
-    check_lowered(_decide_sampled_chunk, args, pipe._statics(), env)
+    setattr(module, name, probe)
+    try:
+        yield first
+    finally:
+        setattr(module, name, real)
+    check(bool(first), f"{what}: {name} never launched")
 
 
 def cache_state(pol) -> dict:
@@ -214,7 +201,9 @@ def compare_states(a: dict, b: dict, what: str) -> None:
 
 def plane_counters(pol) -> dict:
     pipe = pol._device_pipeline
-    return {"launches": pipe.chunk_calls, "uploads": pipe.uploads,
+    return {"launches": pipe.chunk_calls,
+            "scan_steps": pipe.chunk_calls * pipe.scan_len(),
+            "uploads": pipe.uploads,
             "resyncs": pipe.resyncs,
             "resync_reasons": dict(pipe.resync_reasons),
             "per_decision_dispatches": pol.admission_policy._device.calls}
@@ -240,7 +229,7 @@ def phase_single(spec: str, tr, cap: int, ee: int, env: Env) -> dict:
     import numpy as np
 
     from repro.core import REGISTRY, HitMaskRecorder, Instrument, SimulationEngine
-    from repro.kernels.device_full import _simulate_chunk
+    from repro.kernels import device_full
 
     ref_n = env.sizes["ref"]
 
@@ -265,7 +254,9 @@ def phase_single(spec: str, tr, cap: int, ee: int, env: Env) -> dict:
     rec, cp = HitMaskRecorder(), Checkpoint()
     m0 = env.meter.read()
     t0 = time.perf_counter()
-    SimulationEngine(chunk_size=ref_n // 10, instruments=(rec, cp)).run(pol, tr)
+    with first_launch_lowered(device_full, "_simulate_chunk", env, spec):
+        SimulationEngine(chunk_size=ref_n // 10,
+                         instruments=(rec, cp)).run(pol, tr)
     pol.sync_deferred()
     t1 = time.perf_counter()
     check(cp.state is not None, "checkpoint never reached")
@@ -281,7 +272,6 @@ def phase_single(spec: str, tr, cap: int, ee: int, env: Env) -> dict:
     if env.on_tpu:
         check(table0.is_deleted(),
               "the donated sketch table outlived the first launch")
-    check_chunk_program(_simulate_chunk, pol, env)
 
     t = time.perf_counter()
     ref_hits, ref_state = reference_run(spec, tr.slice(ref_n), cap, ee)
@@ -294,11 +284,35 @@ def phase_single(spec: str, tr, cap: int, ee: int, env: Env) -> dict:
     return row
 
 
-def phase_fleet(tr, cap: int, ee: int, env: Env) -> dict:
+def check_sequential(member, spec: str, cap: int, ee: int, env: Env,
+                     what: str):
+    """A fleet member must equal its own sequential ``device_full`` run over
+    its trace: one engine chunk over the whole of it, so launch boundaries
+    (and so the counters) line up with the fleet's. Returns the scan steps
+    the sequential run launched."""
     import numpy as np
 
-    from repro.core import REGISTRY, HitMaskRecorder, SimulationEngine
-    from repro.kernels.fleet import FleetEngine, _fleet_chunk
+    from repro.core import REGISTRY, AccessTrace, HitMaskRecorder, SimulationEngine
+
+    seq = REGISTRY.build(spec, cap, expected_entries=ee, **env.build_kw)
+    rec = HitMaskRecorder()
+    trace = AccessTrace(what, member.keys, member.sizes)
+    SimulationEngine(chunk_size=max(1, len(trace)),
+                     instruments=(rec,)).run(seq, trace)
+    check(np.array_equal(rec.hits, member.hit_mask),
+          f"{what}: hit stream differs from its sequential run")
+    compare_states(cache_state(seq), cache_state(member.policy), what)
+    a, b = seq._device_pipeline, member.pipe
+    check((dict(a.resync_reasons), a.uploads)
+          == (dict(b.resync_reasons), b.uploads),
+          f"{what}: resync/upload counters differ")
+    return a.chunk_calls * a.scan_len()
+
+
+def phase_fleet(tr, cap: int, ee: int, env: Env) -> dict:
+    from repro.core import REGISTRY
+    from repro.kernels import fleet
+    from repro.kernels.fleet import FleetEngine
 
     n = env.sizes["fleet"]
     prefix = tr.slice(n)
@@ -310,7 +324,8 @@ def phase_fleet(tr, cap: int, ee: int, env: Env) -> dict:
         members[spec] = eng.add(pol, prefix.keys, prefix.sizes, label=spec)
     m0 = env.meter.read()
     t0 = time.perf_counter()
-    eng.run()
+    with first_launch_lowered(fleet, "_fleet_chunk", env, "fleet"):
+        eng.run()
     wall = time.perf_counter() - t0
     planes = [m.pipe for m in members.values()]
     ratios = [m.policy.stats.hit_ratio for m in members.values()]
@@ -326,30 +341,69 @@ def phase_fleet(tr, cap: int, ee: int, env: Env) -> dict:
            "resyncs": sum(p.resyncs for p in planes)}
     for m in members.values():
         check(m.policy.stats.accesses == n, f"{m.label}: not fully driven")
-
-    # sequential twins: one engine chunk over the whole prefix, so their
-    # launch boundaries (and so the counters) line up with the fleet's
     for spec in SINGLE_SPECS:
-        m = members[spec]
-        seq = REGISTRY.build(spec, cap, expected_entries=ee, **env.build_kw)
-        rec = HitMaskRecorder()
-        SimulationEngine(chunk_size=n, instruments=(rec,)).run(seq, prefix)
-        check(np.array_equal(rec.hits, m.hit_mask),
-              f"fleet {spec}: hit stream differs from its sequential run")
-        compare_states(cache_state(seq), cache_state(m.policy), f"fleet {spec}")
-        a, b = seq._device_pipeline, m.pipe
-        check((dict(a.resync_reasons), a.uploads)
-              == (dict(b.resync_reasons), b.uploads),
-              f"fleet {spec}: resync/upload counters differ")
-    # a one-lane bucket runs the single-cache program: lower two lanes so
-    # the vmapped program is the one checked
-    check_chunk_program(_fleet_chunk, members[FLEET_SPECS[0]].policy, env, lanes=2)
+        check_sequential(members[spec], spec, cap, ee, env, f"fleet {spec}")
     row["sequential_match"] = list(SINGLE_SPECS)
+    return row
+
+
+def phase_sharded(spec: str, tr, cap: int, ee: int, env: Env) -> dict:
+    """A hash-partitioned deployment: ``SHARDS`` shard caches of ``spec``,
+    each with its share of capacity, as one multi-lane fleet bucket (one
+    launch per round). ``scan_step_us`` is the launch time (wall
+    minus compile) per scan step: against ``sequential_step_us`` it shows
+    what one step costs for all lanes at once."""
+    from repro.core import REGISTRY
+    from repro.kernels import fleet
+    from repro.kernels.fleet import FleetEngine
+
+    prefix = tr.slice(env.sizes["fleet"])
+    scap, see = cap // SHARDS, max(64, ee // SHARDS)
+    pols = [REGISTRY.build(spec, scap, expected_entries=see, **env.build_kw)
+            for _ in range(SHARDS)]
+    for pol in pols:
+        check_pallas(pol, env, f"sharded {spec}")
+    eng = FleetEngine.sharded(pols, prefix.keys, prefix.sizes)
+    m0 = env.meter.read()
+    t0 = time.perf_counter()
+    with first_launch_lowered(fleet, "_fleet_chunk", env,
+                              f"sharded {spec}") as first:
+        eng.run()
+    wall = time.perf_counter() - t0
+    comp = env.meter.since(m0)
+    lanes = first[0][0].shape[0]
+    check(lanes == SHARDS and eng.launches < sum(p._device_pipeline.chunk_calls
+                                                 for p in pols),
+          f"sharded {spec}: shards did not share launches")
+    pad = pols[0]._device_pipeline.scan_len()
+    row = {"phase": "sharded", "spec": spec, "lanes": lanes,
+           "accesses": len(prefix), "shard_accesses":
+           [len(m.keys) for m in eng.members], "wall_s": wall,
+           "accesses_per_s": len(prefix) / wall,
+           "hit_ratios": [p.stats.hit_ratio for p in pols], **comp,
+           "launches": eng.launches,
+           "scan_step_us": (wall - comp["compile_s"])
+           / (eng.launches * pad) * 1e6,
+           "resyncs": sum(p._device_pipeline.resyncs for p in pols)}
+    for m in eng.members:
+        check(m.policy.stats.accesses == len(m.keys),
+              f"{m.label}: not fully driven")
+    m1 = env.meter.read()
+    t1 = time.perf_counter()
+    steps = sum(check_sequential(m, spec, scap, see, env,
+                                 f"sharded {spec} {m.label}")
+                for m in eng.members)
+    seq = env.meter.since(m1)
+    row["sequential_s"] = time.perf_counter() - t1
+    row["sequential_step_us"] = (row["sequential_s"]
+                                 - seq["compile_s"]) / steps * 1e6
+    row["sequential_match"] = [m.label for m in eng.members]
     return row
 
 
 def phase_serving(env: Env) -> dict:
     from benchmarks.serving_cache import LOAD_POLICY, run_load
+    from repro.kernels import admission as admission_plane
     from repro.traces import ARRIVAL_SPECS, make_arrivals
 
     trace = make_arrivals(ARRIVAL_SPECS["bursty_small"], seed=0,
@@ -357,11 +411,18 @@ def phase_serving(env: Env) -> dict:
     row = {"phase": "serving", "policy": LOAD_POLICY, "requests": len(trace)}
     for admission in ("sync", "async"):
         m0 = env.meter.read()
-        res, cache = run_load(LOAD_POLICY, admission, trace)
+        # sync admission decides one launch per decision; async batches
+        # the decisions it can defer into decision-chunk launches
+        programs = ["_decide_sampled"] + (
+            ["_decide_sampled_chunk"] if admission == "async" else [])
+        with contextlib.ExitStack() as probes:
+            for name in programs:
+                probes.enter_context(first_launch_lowered(
+                    admission_plane, name, env, f"serving {admission}"))
+            res, cache = run_load(LOAD_POLICY, admission, trace)
         check_pallas(cache.policy, env, f"serving {admission}")
         cache.pool.check_invariants()
         pipe = cache.policy._device_pipeline
-        check_decision_program(pipe, env)
         row[admission] = {
             "accesses": cache.policy.stats.accesses,
             "hit_ratio": cache.policy.stats.hit_ratio,
@@ -370,6 +431,7 @@ def phase_serving(env: Env) -> dict:
                                    "decision_p50_ms", "decision_p99_ms")},
             "requests_per_s": len(trace) / res["wall_s"],
             **env.meter.since(m0), "launches": pipe.chunk_calls,
+            "per_decision_dispatches": cache.policy.admission_policy._device.calls,
             "resyncs": pipe.resyncs,
             "resync_reasons": dict(pipe.resync_reasons)}
     for k in ("request_hit_ratio", "token_hit_ratio", "byte_hit_ratio"):
@@ -407,6 +469,8 @@ def main(argv=None) -> int:
     for spec in SINGLE_SPECS:
         emit(phase_single(spec, tr, cap, ee, env))
     emit(phase_fleet(tr, cap, ee, env))
+    for spec in SINGLE_SPECS:
+        emit(phase_sharded(spec, tr, cap, ee, env))
     emit(phase_serving(env))
     emit({"ok": True, "device": env.device})
     return 0

@@ -1,11 +1,11 @@
 #!/usr/bin/env python
-"""Tier-1 CI smoke row for the vmapped fleet driver.
+"""Tier-1 CI smoke row for the fleet driver.
 
 Fast end-to-end check (one workload-shift trace, a 4-instance grid) that
 :class:`repro.kernels.fleet.FleetEngine`
 
 * shape-buckets mixed specs and drives each bucket's chunk rounds in
-  single vmapped launches (launch count well under the members' summed
+  single fleet launches (launch count well under the members' summed
   chunk count),
 * leaves every member byte-identical to the SAME spec driven through the
   sequential ``device_full`` loop — hit stream, ``CacheStats``, final
@@ -29,7 +29,7 @@ from repro.kernels.fleet import FleetEngine
 from repro.traces import make_trace
 
 # one combo x four seeds: a single shape-bucket, so the canary pays ONE
-# vmapped compile (the mixed-bucket case is covered by the test suite)
+# fleet compile (the mixed-bucket case is covered by the test suite)
 SPECS = [f"wtlfu-av-slru?sketch_backend=cms&seed={s}" for s in (1, 2, 3, 4)]
 
 
@@ -86,7 +86,7 @@ def main() -> int:
         m.policy.access(10**12, 1)  # host-authoritative scalar path works
 
     if eng.launches >= total_chunks:
-        print(f"FAIL: no amortization — {eng.launches} vmapped launches "
+        print(f"FAIL: no amortization — {eng.launches} fleet launches "
               f"for {total_chunks} member chunks", file=sys.stderr)
         return 1
     print(

@@ -753,7 +753,7 @@ def _simulate_chunk_impl(table, mk_hi, mk_lo, msz, mstamp, mseg,
 
 
 #: single-instance entry point — the un-jitted ``_simulate_chunk_impl`` stays
-#: importable so :mod:`repro.kernels.fleet` can ``vmap`` it across stacked
+#: importable so :mod:`repro.kernels.fleet` can map it over stacked
 #: instances under its own jit.
 _simulate_chunk = jax.jit(
     _simulate_chunk_impl,
@@ -958,7 +958,7 @@ class DeviceFullSimulationPlane:
         self._inflight: "_InFlightSim | None" = None
         self._host_auth = True  # host structures current?
         #: set by repro.kernels.fleet while this instance is enrolled in a
-        #: vmapped fleet: lane-materialization callback run by ensure_host
+        #: fleet: lane-materialization callback run by ensure_host
         self._fleet_restore = None
         # device-side shadows (committed scalars the host can't derive
         # without a download)
@@ -1120,25 +1120,35 @@ class DeviceFullSimulationPlane:
             cap=self.sketch.cap, use_pallas=self.sketch.use_pallas,
             interpret=self.device._interpret)
 
+    def scan_len(self) -> int:
+        """The scan length of every launch: one per plane, not per take, so
+        a take cut short (aging boundary, trace end) compiles no new
+        program. Its padded steps are masked but still run."""
+        return _next_pow2(max(8, self.chunk))
+
+    def launch_inputs(self, pol, khi, klo, szs, take, pad=None) -> tuple:
+        """The host-built inputs of a ``take``-access launch, after the
+        state buffers: key limbs and sizes padded to ``pad`` (default
+        :meth:`scan_len`), the packed scalars, the RNG stream-key limbs."""
+        xs = np.zeros((3, self.scan_len() if pad is None else pad), np.int32)
+        xs[0, :take] = khi
+        xs[1, :take] = klo
+        xs[2, :take] = szs
+        return (*xs, self._pack_scal(pol, take), self._rng_limbs())
+
+    def chunk_args(self, pol, khi=(), klo=(), szs=(), take=0) -> tuple:
+        """The positional args of one :func:`_simulate_chunk` launch: the
+        donated state buffers (sketch table, Main, Window), then
+        :meth:`launch_inputs`."""
+        return (self.sketch.table, *self.mirror.main, *self.mirror.window,
+                *(jnp.asarray(a) for a in
+                  self.launch_inputs(pol, khi, klo, szs, take)))
+
     def _dispatch(self, pol, khi, klo, szs, take) -> "_InFlightSim":
         sk = self.sketch
         self._preflight(pol, take)
-        scal = self._pack_scal(pol, take)
-        limbs = self._rng_limbs()
-        # one scan length per plane (not per take): a take cut short at an
-        # aging boundary must not compile a new program
-        pad = _next_pow2(max(8, self.chunk))
-        xhi = np.zeros(pad, np.int32)
-        xlo = np.zeros(pad, np.int32)
-        xsz = np.zeros(pad, np.int32)
-        xhi[:take] = khi
-        xlo[:take] = klo
-        xsz[:take] = szs
-        outs = _simulate_chunk(
-            sk.table, *self.mirror.main, *self.mirror.window,
-            jnp.asarray(xhi), jnp.asarray(xlo), jnp.asarray(xsz),
-            jnp.asarray(scal), jnp.asarray(limbs),
-            **self._statics(pol))
+        outs = _simulate_chunk(*self.chunk_args(pol, khi, klo, szs, take),
+                               **self._statics(pol))
         self.chunk_calls += 1
         # adopt the async results immediately: the inputs were donated
         sk.table = outs[0]
@@ -1207,7 +1217,7 @@ class DeviceFullSimulationPlane:
         if self._host_auth:
             return
         if self._fleet_restore is not None:
-            # enrolled in a vmapped fleet: the authoritative state lives in
+            # enrolled in a fleet: the authoritative state lives in
             # the fleet's stacked buffers — materialize this instance's lane
             # into the mirror (and sketch table) before downloading
             self._fleet_restore()

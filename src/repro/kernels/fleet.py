@@ -1,18 +1,19 @@
-"""Fleet-scale vmapped simulation: N independent ``device_full`` caches
-advanced by ONE ``jax.vmap``-of-``lax.scan`` launch per chunk.
+"""Fleet-scale simulation: N independent ``device_full`` caches advanced
+by ONE launch per shape-bucket per chunk.
 
 PR 7 made a simulation chunk a pure device function
 (:func:`repro.kernels.device_full._simulate_chunk_impl` — state in, state
 out, no host round-trips mid-chunk). This module stacks N independent
 cache instances — each with its own seed, capacity, admission/eviction
 combo, trace slice, and adaptive-window carry — along a leading batch
-axis and resolves a chunk for the **entire fleet** in one jitted
-``vmap``-of-``scan`` launch with donated stacked buffers.
+axis and resolves a chunk for a **whole bucket** in one jitted launch
+with donated stacked buffers (a ``lax.map`` of the chunk scan over the
+lanes, see :func:`_fleet_chunk`).
 
 Shape-bucketing
 ---------------
-``vmap`` needs a common shape and a common set of static kernel
-arguments per launch, so members are grouped into *buckets* keyed on the
+A launch needs a common shape and a common set of static kernel
+arguments, so members are grouped into *buckets* keyed on the
 kernel statics (eviction discipline/rule/sample width, main kind,
 adaptive flag, sketch saturation cap, pallas routing) **plus the sketch
 table shape** — CMS tables cannot be padded (the width participates in
@@ -51,9 +52,7 @@ from repro.core.engine import StatsSnapshot
 from repro.kernels.device_full import (
     DeviceFullSimulationPlane,
     _InFlightSim,
-    _SCAL_IDX,
     _limbs_of,
-    _next_pow2,
     _simulate_chunk_impl,
 )
 
@@ -74,19 +73,23 @@ def _fleet_chunk(table, mk_hi, mk_lo, msz, mstamp, mseg,
     """One trace chunk for a whole shape-bucket: every positional buffer
     carries a leading lane axis; per-lane take lengths ride in
     ``scal[:, a_n]`` (invalid scan iterations are masked in the kernel, so
-    ragged and even zero-length lanes are exact no-ops)."""
+    ragged and even zero-length lanes are exact no-ops).
+
+    The lanes run one after another inside the launch (``lax.map`` of the
+    single-cache program), not vectorized: under ``vmap`` every
+    ``lax.cond`` of the scan becomes a select that runs both branches on
+    every lane at every access. On a TPU v5e a 4-lane ``vmap`` bucket,
+    even with each branch rewritten as a guarded ``while_loop``, cost as
+    much per lane-step as sequential lanes for an SLRU main and 13x more
+    for a sampled main; the map costs about a quarter less than
+    sequential lanes for both."""
     f = functools.partial(
         _simulate_chunk_impl, discipline=discipline, rule=rule, sample=sample,
         early_pruning=early_pruning, adaptive=adaptive, main_kind=main_kind,
         cap=cap, use_pallas=use_pallas, interpret=interpret)
-    args = (table, mk_hi, mk_lo, msz, mstamp, mseg, wk_hi, wk_lo, wsz, wstamp,
-            xs_hi, xs_lo, xs_sz, scal, key_limbs)
-    if table.shape[0] == 1:
-        # nothing to batch: under vmap every lax.cond of the scan becomes a
-        # select that runs both branches on every access, so a one-lane
-        # bucket (each combo of a grid sweep) runs the single-cache program
-        return tuple(out[None] for out in f(*(a[0] for a in args)))
-    return jax.vmap(f)(*args)
+    return jax.lax.map(lambda lane: f(*lane), (
+        table, mk_hi, mk_lo, msz, mstamp, mseg, wk_hi, wk_lo, wsz, wstamp,
+        xs_hi, xs_lo, xs_sz, scal, key_limbs))
 
 
 @functools.partial(jax.jit, donate_argnums=tuple(range(10)))
@@ -180,7 +183,7 @@ class _Bucket:
 
 class FleetEngine:
     """Batches chunk streaming for N ``device_full`` instances into one
-    vmapped launch per shape-bucket per round, demuxing stats, hit
+    launch per shape-bucket per round, demuxing stats, hit
     streams, and snapshots per instance.
 
     Usage::
@@ -204,7 +207,7 @@ class FleetEngine:
         self.collect_hits = collect_hits
         self.members: list[FleetMember] = []
         self.buckets: dict[tuple, _Bucket] = {}
-        self.launches = 0  # vmapped fleet-kernel launches
+        self.launches = 0  # fleet-kernel launches
 
     # -- membership ---------------------------------------------------------
     def add(self, policy, keys, sizes, label: str | None = None) -> FleetMember:
@@ -395,25 +398,15 @@ class FleetEngine:
                 uploaded.append(m)
         self._ensure_stacks(b, uploaded)
 
-        n = len(b.members)
-        pad = _next_pow2(max([8] + [m.pipe.chunk for m in b.members]))
-        xhi = np.zeros((n, pad), np.int32)
-        xlo = np.zeros((n, pad), np.int32)
-        xsz = np.zeros((n, pad), np.int32)
-        scal = np.zeros((n, len(_SCAL_IDX)), np.int32)
-        limbs = np.zeros((n, 2), np.uint32)
-        for i, (m, t) in enumerate(zip(b.members, takes)):
-            scal[i] = m.pipe._pack_scal(m.policy, t)
-            limbs[i] = m.pipe._rng_limbs()
-            if t:
-                s = m.pos
-                xhi[i, :t] = m.khi[s: s + t]
-                xlo[i, :t] = m.klo[s: s + t]
-                xsz[i, :t] = m.sizes[s: s + t]
-
+        pad = max(m.pipe.scan_len() for m in b.members)
+        inputs = zip(*(
+            m.pipe.launch_inputs(m.policy, m.khi[m.pos: m.pos + t],
+                                 m.klo[m.pos: m.pos + t],
+                                 m.sizes[m.pos: m.pos + t], t, pad)
+            for m, t in zip(b.members, takes)))
         outs = _fleet_chunk(
             b.table, *b.main, *b.window,
-            xhi, xlo, xsz, scal, limbs, **b.statics)
+            *(np.stack(a) for a in inputs), **b.statics)
         self.launches += 1
         # adopt immediately: the stacked inputs were donated
         b.table = outs[0]

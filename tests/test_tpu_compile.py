@@ -15,11 +15,14 @@ import os
 
 import pytest
 
-WIDTH = 16384  # sketch width of the smoke's msr2 cache
+CAP = 345_000_000  # ~10 % of the msr2 trace's object bytes
+EE = 8600  # expected resident objects: sketch width 16384
+WIDTH = 16384
 SLOTS = 16384  # Main slots, grown straight to the sketch width
 WSLOTS = 512
 PAD = 256  # scan length of a chunk-256 plane
 LANES = 21  # one lane per admission x main combo
+SPECS = {"slru": "wtlfu-av-slru", "sampled": "wtlfu-qv-sampled_frequency_size"}
 
 
 @pytest.fixture(scope="module")
@@ -52,25 +55,25 @@ def _sds(sharding, shape, dtype=None):
     return jax.ShapeDtypeStruct(shape, dtype or jnp.int32, sharding=sharding)
 
 
-def _chunk_args(sharding, lanes=None):
-    import jax.numpy as jnp
+def _chunk_program(sharding, main_kind, lanes=None):
+    """Arg stand-ins and statics of a smoke-sized ``device_full`` plane's
+    chunk program, as its own ``chunk_args`` lays them out (``lanes`` adds
+    a fleet bucket's lane axis), compiled Pallas."""
+    import jax
 
-    from repro.kernels.cms.ref import ROWS
-    from repro.kernels.device_full import _SCAL_IDX
+    from repro.core import REGISTRY
 
+    pol = REGISTRY.build(f"{SPECS[main_kind]}?data_plane=device_full&chunk={PAD}",
+                         CAP, expected_entries=EE,
+                         sketch_kwargs={"use_pallas": True})
+    pipe = pol._device_pipeline
+    assert (pipe.sketch.width, pipe.scan_len()) == (WIDTH, PAD)
+    pipe.mirror.upload([], [], pipe.sampled, PAD)
+    pipe.mirror.grow(SLOTS, WSLOTS)
     lead = () if lanes is None else (lanes,)
-
-    def s(*shape, dtype=None):
-        return _sds(sharding, lead + shape, dtype)
-
-    return ([s(ROWS, WIDTH)] + [s(SLOTS)] * 5 + [s(WSLOTS)] * 4
-            + [s(PAD)] * 3 + [s(len(_SCAL_IDX)), s(2, dtype=jnp.uint32)])
-
-
-def _statics(main_kind, discipline, rule):
-    return dict(discipline=discipline, rule=rule, sample=5,
-                early_pruning=True, adaptive=False, main_kind=main_kind,
-                cap=15, use_pallas=True, interpret=False)
+    args = [jax.ShapeDtypeStruct(lead + a.shape, a.dtype, sharding=sharding)
+            for a in pipe.chunk_args(pol)]
+    return args, {**pipe._statics(pol), "interpret": False}
 
 
 def _assert_kernel(compiled):
@@ -146,17 +149,15 @@ def test_kernel_payload_free_of_checkout_path(one_chip):
     assert all(b"src/repro/kernels/cms/cms.py" in p for p in canon)
 
 
-@pytest.mark.parametrize("main_kind,discipline,rule", [
-    ("slru", "av", "frequency"),
-    ("sampled", "qv", "frequency_size"),
+@pytest.mark.parametrize("main_kind", [
+    pytest.param("slru", id="slru-av-frequency"),
+    pytest.param("sampled", id="sampled-qv-frequency_size"),
 ])
-def test_simulate_chunk(one_chip, main_kind, discipline, rule):
+def test_simulate_chunk(one_chip, main_kind):
     from repro.kernels.device_full import _simulate_chunk
 
-    compiled = _simulate_chunk.lower(
-        *_chunk_args(one_chip), **_statics(main_kind, discipline, rule)
-    ).compile()
-    _assert_kernel(compiled)
+    args, statics = _chunk_program(one_chip, main_kind)
+    _assert_kernel(_simulate_chunk.lower(*args, **statics).compile())
 
 
 def test_decision_chunk(one_chip):
@@ -180,9 +181,8 @@ def test_decision_chunk(one_chip):
 def test_fleet_chunk_21_lanes(one_chip):
     from repro.kernels.fleet import _fleet_chunk
 
-    compiled = _fleet_chunk.lower(
-        *_chunk_args(one_chip, lanes=LANES),
-        **_statics("sampled", "av", "frequency")).compile()
+    args, statics = _chunk_program(one_chip, "sampled", lanes=LANES)
+    compiled = _fleet_chunk.lower(*args, **statics).compile()
     _assert_kernel(compiled)
     mem = compiled.memory_analysis()
     assert mem is None or mem.temp_size_in_bytes < 16 << 30

@@ -115,6 +115,7 @@ def cms_update_pallas(table, idx, *, cap: int = 15, block_w: int = DEFAULT_BLOCK
         out_specs=pl.BlockSpec((ROWS, block_w), lambda w: (0, w)),
         out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
         interpret=interpret,
+        name="cms_update",
     )(idx, table)
 
 
@@ -135,6 +136,7 @@ def cms_estimate_pallas(table, idx, *, block_w: int = DEFAULT_BLOCK_W,
         out_specs=pl.BlockSpec(idx.shape, lambda w: (0, 0)),  # accumulated
         out_shape=jax.ShapeDtypeStruct(idx.shape, table.dtype),
         interpret=interpret,
+        name="cms_estimate",
     )(idx, table)
 
 
@@ -166,4 +168,5 @@ def cms_update_estimate_pallas(table, upd_idx, est_idx, *, cap: int = 15,
             jax.ShapeDtypeStruct(est_idx.shape, table.dtype),
         ),
         interpret=interpret,
+        name="cms_update_estimate",
     )(upd_idx, est_idx, table)

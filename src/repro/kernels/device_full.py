@@ -50,6 +50,7 @@ compares and the int32 sketch hash-input truncation both hold.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from collections import OrderedDict
 
@@ -71,7 +72,7 @@ from .admission import (
 from .cms.ops import _mix64_u32, flush_scores
 from .cms.ref import row_indexes
 
-__all__ = ["DeviceFullSimulationPlane", "OrderedDeviceMirror"]
+__all__ = ["DeviceFullSimulationPlane", "OrderedDeviceMirror", "PLANE_SPANS"]
 
 #: Renormalize recency stamps (via a host download/re-upload) before the
 #: int32 tick counter could overflow mid-chunk.
@@ -92,6 +93,17 @@ _CONST_FIELDS = (
 _SCAL_IDX = {name: i for i, name in enumerate(_CARRY_FIELDS + _CONST_FIELDS)}
 #: Packed fields that may wrap: the climber only reads their int32 delta.
 _WRAP_FIELDS = ("hits", "prev_hits")
+
+#: The plane's host spans: a launch's phases in the order it opens them,
+#: then the resync span (``reason=`` as counted in ``resync_reasons``).
+#: Each carries ``launch=<chunk_calls>``, the launch's index, so one
+#: launch's spans share it; a profiler trace records them on its host
+#: plane, on the device lines' clock.
+PLANE_SPANS = (_PREPARE, _PACK, _ENQUEUE, _WAIT, _FETCH, _COMMIT, _RESYNC) = (
+    "plane.prepare", "plane.pack", "plane.enqueue",
+    "plane.wait", "plane.fetch", "plane.commit", "plane.resync")
+#: Opens every span above.
+_span = jax.profiler.TraceAnnotation
 
 
 def _freq_of(table, keys32):
@@ -950,7 +962,6 @@ class DeviceFullSimulationPlane:
         self.mirror = OrderedDeviceMirror()
         self.chunk_calls = 0  # simulation-kernel launches
         self.decisions = 0  # admission decisions resolved (all on device)
-        self.flushes = 0  # kept for plane-surface parity (unused here)
         self.resyncs = 0
         self.resync_reasons = {"aging": 0, "mirror_grow": 0}
         self.defer_collect = False
@@ -989,47 +1000,51 @@ class DeviceFullSimulationPlane:
         """Drive one access chunk — observationally identical to the
         scalar ``access`` loop. Returns the hit bitmap (an un-materialized
         device array when the whole chunk was one deferred launch)."""
-        arr = np.asarray(keys, np.int64)
-        szs = np.asarray(sizes, np.int64)
-        n = len(arr)
-        if n and int(szs.max()) > self.device.max_size:
-            raise ValueError(
-                f"device_full plane: object size {int(szs.max())} exceeds "
-                f"the exact-arithmetic bound {self.device.max_size}")
-        khi, klo = _limbs_of(arr)
-        self._collect(pol)  # resolve any launch left in flight
-        sk = self.sketch
-        hits = np.empty(n, dtype=bool)
-        i = 0
-        while i < n:
-            if sk._pending:
-                # host-path increments (boundary accesses) flush first so
-                # the in-scan increments land on the settled table
-                sk.flush()
-            safe = sk.sample_size - sk._ops - 1
-            if safe <= 0:
-                # the next access's estimates would straddle the aging
-                # reset: replay it through the host path (staged flush
-                # splits at the boundary), then re-upload
-                self.ensure_host(pol)
-                self.resyncs += 1
-                self.resync_reasons["aging"] += 1
-                hits[i] = pol.access(int(arr[i]), int(szs[i]))
-                i += 1
-                continue
-            take = min(n - i, self.chunk, safe)
-            inf = self._dispatch(pol, khi[i: i + take], klo[i: i + take],
-                                 szs[i: i + take], take)
-            if self.defer_collect and i == 0 and take == n:
-                # the whole chunk resolved in one launch: leave it in
-                # flight (double-buffered with the caller's next gather)
+        with contextlib.ExitStack() as prepare:  # closed at the first launch
+            prepare.enter_context(_span(_PREPARE, launch=self.chunk_calls))
+            arr = np.asarray(keys, np.int64)
+            szs = np.asarray(sizes, np.int64)
+            n = len(arr)
+            if n and int(szs.max()) > self.device.max_size:
+                raise ValueError(
+                    f"device_full plane: object size {int(szs.max())} exceeds "
+                    f"the exact-arithmetic bound {self.device.max_size}")
+            khi, klo = _limbs_of(arr)
+            self._collect(pol)  # resolve any launch left in flight
+            sk = self.sketch
+            hits = np.empty(n, dtype=bool)
+            i = 0
+            while i < n:
+                if sk._pending:
+                    # host-path increments (boundary accesses) flush first so
+                    # the in-scan increments land on the settled table
+                    sk.flush()
+                safe = sk.sample_size - sk._ops - 1
+                if safe <= 0:
+                    # the next access's estimates would straddle the aging
+                    # reset: replay it through the host path (staged flush
+                    # splits at the boundary), then re-upload
+                    with _span(_RESYNC, launch=self.chunk_calls, reason="aging"):
+                        self.ensure_host(pol)
+                        self.resyncs += 1
+                        self.resync_reasons["aging"] += 1
+                        hits[i] = pol.access(int(arr[i]), int(szs[i]))
+                    i += 1
+                    continue
+                take = min(n - i, self.chunk, safe)
+                prepare.close()
+                inf = self._dispatch(pol, khi[i: i + take], klo[i: i + take],
+                                     szs[i: i + take], take)
+                if self.defer_collect and i == 0 and take == n:
+                    # the whole chunk resolved in one launch: leave it in
+                    # flight (double-buffered with the caller's next gather)
+                    self._inflight = inf
+                    self.deferred_dispatches += 1
+                    return inf.outs[12]
                 self._inflight = inf
-                self.deferred_dispatches += 1
-                return inf.outs[12]
-            self._inflight = inf
-            self._collect(pol)
-            hits[i: i + take] = np.asarray(self._last_hits[:take])
-            i += take
+                self._collect(pol)
+                hits[i: i + take] = np.asarray(self._last_hits[:take])
+                i += take
         return hits
 
     def _preflight(self, pol, take) -> bool:
@@ -1060,11 +1075,12 @@ class DeviceFullSimulationPlane:
             # grow Main straight to the sketch width (sized for the expected
             # resident count): each slot count is its own compiled program,
             # and every access already sweeps that width in the CMS kernel
-            self.mirror.grow(
-                max(self._n + self._wn + max(take, self.chunk), sk.width),
-                self._wn + max(take, self.chunk))
-            self.resyncs += 1
-            self.resync_reasons["mirror_grow"] += 1
+            with _span(_RESYNC, launch=self.chunk_calls, reason="mirror_grow"):
+                self.mirror.grow(
+                    max(self._n + self._wn + max(take, self.chunk), sk.width),
+                    self._wn + max(take, self.chunk))
+                self.resyncs += 1
+                self.resync_reasons["mirror_grow"] += 1
         return False
 
     def _pack_scal(self, pol, take) -> np.ndarray:
@@ -1146,14 +1162,18 @@ class DeviceFullSimulationPlane:
 
     def _dispatch(self, pol, khi, klo, szs, take) -> "_InFlightSim":
         sk = self.sketch
-        self._preflight(pol, take)
-        outs = _simulate_chunk(*self.chunk_args(pol, khi, klo, szs, take),
-                               **self._statics(pol))
-        self.chunk_calls += 1
-        # adopt the async results immediately: the inputs were donated
-        sk.table = outs[0]
-        self.mirror.adopt(tuple(outs[1:6]), tuple(outs[6:10]))
-        self._host_auth = False
+        launch = self.chunk_calls
+        with _span(_PACK, launch=launch):
+            self._preflight(pol, take)
+            args = self.chunk_args(pol, khi, klo, szs, take)
+            statics = self._statics(pol)
+        with _span(_ENQUEUE, launch=launch):
+            outs = _simulate_chunk(*args, **statics)
+            self.chunk_calls += 1
+            # adopt the async results immediately: the inputs were donated
+            sk.table = outs[0]
+            self.mirror.adopt(tuple(outs[1:6]), tuple(outs[6:10]))
+            self._host_auth = False
         return _InFlightSim(outs, take, szs, pol.stats)
 
     def _collect(self, pol) -> None:
@@ -1162,8 +1182,23 @@ class DeviceFullSimulationPlane:
         if self._inflight is None:
             return
         inf, self._inflight = self._inflight, None
-        scal = np.asarray(inf.outs[10]).astype(np.int64)
-        hits = np.asarray(inf.outs[12])
+        launch = self.chunk_calls - 1  # the in-flight launch is the last one
+        with _span(_WAIT, launch=launch):
+            # the scalars' copy is queued behind the launch and their
+            # arrival ends the wait: a block of its own would queue the
+            # copies only after the host wakes
+            scal = np.asarray(inf.outs[10])
+        with _span(_FETCH, launch=launch):
+            scal = scal.astype(np.int64)
+            hits = np.asarray(inf.outs[12])
+        with _span(_COMMIT, launch=launch):
+            self._commit(pol, inf, scal, hits)
+        if self._tick > _TICK_RENORM:
+            self.ensure_host(pol)  # re-upload next launch with fresh ticks
+
+    def _commit(self, pol, inf, scal, hits) -> None:
+        """Commit a collected launch's stats, caps, adaptive-climber state
+        and scalar shadows, and keep its hit mask as ``_last_hits``."""
         a_n = inf.a_n
         sk = self.sketch
         sk._ops += a_n
@@ -1205,8 +1240,6 @@ class DeviceFullSimulationPlane:
         delta = (rel("hits") - rel("prev_hits")) & 0xFFFFFFFF
         pol._adapt_prev_hits = st.hits - delta
         self._last_hits = hit_mask
-        if self._tick > _TICK_RENORM:
-            self.ensure_host(pol)  # re-upload next launch with fresh ticks
 
     def ensure_host(self, pol) -> None:
         """Restore host authority: collect any in-flight launch, download

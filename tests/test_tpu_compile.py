@@ -154,10 +154,17 @@ def test_kernel_payload_free_of_checkout_path(one_chip):
     pytest.param("sampled", id="sampled-qv-frequency_size"),
 ])
 def test_simulate_chunk(one_chip, main_kind):
+    """The chunk program; its fused CMS kernel is an instruction of its own
+    name, which a chip trace names the kernel's events by."""
+    import re
+
     from repro.kernels.device_full import _simulate_chunk
 
     args, statics = _chunk_program(one_chip, main_kind)
-    _assert_kernel(_simulate_chunk.lower(*args, **statics).compile())
+    compiled = _simulate_chunk.lower(*args, **statics).compile()
+    _assert_kernel(compiled)
+    named = r'%cms_update_estimate(\.\d+)? = .*custom_call_target="tpu_custom_call"'
+    assert re.search(named, compiled.as_text()), "fused CMS kernel not named"
 
 
 def test_decision_chunk(one_chip):

@@ -1,8 +1,17 @@
 """Pallas TPU kernels for the batched TinyLFU count-min sketch.
 
-TPU adaptation of the paper's hot data structure (DESIGN.md §3): instead of
-pointer-chasing per key, a batch of N keys is processed with dense,
-lane-aligned VPU work — per width-block one-hot comparisons:
+TPU adaptation of the paper's hot data structure (DESIGN.md §3). The
+per-access increment of the chunk scan touches one key's counters only:
+
+* update one (in place): the key's ROWS row indexes and a 0/1 ``valid``
+  are scalar-prefetched into SMEM; the table stays in HBM, aliased to the
+  output. Each counter's [ROWS, 128] HBM tile (the table's tiling; a single
+  row cannot be moved alone) is fetched into VMEM, every row's increment
+  that falls in it is applied, and the tiles are written back. Its cost
+  does not grow with the width.
+
+Batches of keys use dense, lane-aligned VPU work over the whole table —
+per width-block one-hot comparisons:
 
 * update: for each table block [ROWS, BW], add the number of keys hashing
   into each cell (broadcasted iota==index compare, summed over keys),
@@ -15,9 +24,9 @@ lane-aligned VPU work — per width-block one-hot comparisons:
   from the *updated* block, so an admission decision's sketch flush and
   victim scoring land in a single kernel launch.
 
-The table block (BW lanes) and the key-index vectors live in VMEM; grids
-iterate width-blocks. Both kernels are validated against ref.py in
-interpret mode across shape/dtype sweeps.
+The sweeps keep the table block (BW lanes) and the key-index vectors in
+VMEM; their grids iterate width-blocks. All kernels are validated against
+ref.py in interpret mode across shape/dtype sweeps.
 """
 
 from __future__ import annotations
@@ -27,10 +36,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ref import ROWS
 
 DEFAULT_BLOCK_W = 512
+#: lanes of one HBM tile of the table; a tile spans all ROWS rows
+TILE_W = 128
 
 
 def _update_kernel(idx_ref, table_ref, out_ref, *, cap: int, block_w: int):
@@ -170,3 +182,65 @@ def cms_update_estimate_pallas(table, upd_idx, est_idx, *, cap: int = 15,
         interpret=interpret,
         name="cms_update_estimate",
     )(upd_idx, est_idx, table)
+
+
+def _update_one_kernel(idx_ref, valid_ref, table_hbm, out_hbm, tiles, sems, *, cap: int):
+    """One key's increment, in place. ``idx_ref`` [ROWS] and ``valid_ref``
+    [1] are in SMEM; the table stays in HBM (``out_hbm`` aliases
+    ``table_hbm``). Row ``r``'s counter lies in the [ROWS, TILE_W] tile at
+    column ``cols[r]``: fetch the four tiles, apply in each every row's
+    increment that falls in it (tiles at the same columns then hold the
+    same bytes), write them back."""
+    del table_hbm  # the same buffer as out_hbm
+    cols = [pl.multiple_of(idx_ref[r] // TILE_W * TILE_W, TILE_W) for r in range(ROWS)]
+
+    def copy(r, src, dst):
+        return pltpu.make_async_copy(src, dst, sems.at[r])
+
+    reads = [copy(r, out_hbm.at[:, pl.ds(cols[r], TILE_W)], tiles.at[r]) for r in range(ROWS)]
+    for c in reads:
+        c.start()
+    for c in reads:
+        c.wait()
+    row = jax.lax.broadcasted_iota(jnp.int32, (ROWS, TILE_W), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (ROWS, TILE_W), 1)
+    valid = valid_ref[0]
+    for t in range(ROWS):
+        hit = jnp.zeros((ROWS, TILE_W), jnp.bool_)
+        for r in range(ROWS):
+            at = jnp.where(cols[r] == cols[t], idx_ref[r] - cols[r], -1)
+            hit = hit | ((row == r) & (lane == at))
+        tile = tiles[t]
+        tiles[t] = jnp.where(hit, jnp.minimum(tile + valid, cap), tile)
+    writes = [copy(r, tiles.at[r], out_hbm.at[:, pl.ds(cols[r], TILE_W)]) for r in range(ROWS)]
+    for c in writes:
+        c.start()
+    for c in writes:
+        c.wait()
+
+
+def cms_update_one_pallas(table, idx, valid, *, cap: int = 15, interpret: bool = True):
+    """Increment one key's ``ROWS`` counters in place: ``idx`` [ROWS] int32
+    holds its row indexes, ``valid`` (0 or 1) the increment. Reads and
+    writes only the [ROWS, TILE_W] tiles that hold the four counters, so
+    its cost does not grow with the width. Returns the table (the input
+    buffer, aliased), equal to ``cms_update_pallas`` of that key when
+    ``valid`` and unchanged otherwise (counters at most ``cap``, as every
+    increment leaves them)."""
+    rows, width = table.shape
+    assert rows == ROWS and width % TILE_W == 0
+    return pl.pallas_call(
+        functools.partial(_update_one_kernel, cap=cap),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((ROWS, ROWS, TILE_W), table.dtype),
+                            pltpu.SemaphoreType.DMA((ROWS,))],
+        ),
+        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
+        input_output_aliases={2: 0},
+        interpret=interpret,
+        name="cms_update_one",
+    )(idx.astype(jnp.int32), jnp.asarray(valid, jnp.int32).reshape(1), table)

@@ -14,7 +14,12 @@ from repro.core.crng import GAMMA as _CRNG_GAMMA
 from repro.core.crng import MIX_M1 as _MIX_M1
 from repro.core.crng import MIX_M2 as _MIX_M2
 
-from .cms import cms_estimate_pallas, cms_update_estimate_pallas, cms_update_pallas
+from .cms import (
+    cms_estimate_pallas,
+    cms_update_estimate_pallas,
+    cms_update_one_pallas,
+    cms_update_pallas,
+)
 from .ref import ROWS, cms_estimate_ref, cms_update_estimate_ref, cms_update_ref, row_indexes
 
 __all__ = [
@@ -144,17 +149,26 @@ def flush_scores(table, upd_keys, n_pend, est_keys, *, cap, use_pallas, interpre
     padded int32 batch), then estimate ``est_keys`` on the updated table —
     the fused flush+score step shared by every device decision kernel.
 
-    With ``use_pallas`` this IS the fused ``cms_update_estimate`` Pallas
-    launch; otherwise a scatter-add + gather with identical values (the
-    same saturating non-conservative semantics as ``cms_update_ref``).
+    With ``use_pallas`` a batch of one key (a static shape: the per-access
+    increment of the chunk scan) is the in-place ``cms_update_one`` Pallas
+    launch, which touches only that key's counters, and the estimates a
+    gather from its table; a longer batch is the fused
+    ``cms_update_estimate`` launch, which sweeps the whole table and sums
+    duplicate keys. Otherwise a scatter-add + gather with identical values
+    (the same saturating non-conservative semantics as ``cms_update_ref``).
     Padded update lanes are masked to the out-of-range ``width`` sentinel,
     which no width block ever matches. Traceable (``n_pend`` may be
     dynamic), so it composes into ``lax.scan`` decision chunks.
     """
     width = table.shape[1]
     upd_idx = row_indexes(upd_keys, width)
-    upd_idx = jnp.where(jnp.arange(upd_keys.shape[0])[None, :] < n_pend, upd_idx, width)
     est_idx = row_indexes(est_keys, width)
+    if use_pallas and upd_keys.shape[0] == 1:
+        new_table = cms_update_one_pallas(table, upd_idx[:, 0], n_pend > 0,
+                                          cap=cap, interpret=interpret)
+        vals = jnp.take_along_axis(new_table, est_idx, axis=1)
+        return new_table, vals.min(0)
+    upd_idx = jnp.where(jnp.arange(upd_keys.shape[0])[None, :] < n_pend, upd_idx, width)
     if use_pallas:
         new_table, vals = cms_update_estimate_pallas(
             table, upd_idx, est_idx, cap=cap, interpret=interpret)

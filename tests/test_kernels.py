@@ -116,6 +116,75 @@ class TestCMSKernel:
         assert est[0] >= 5 and est[1] == 0
 
 
+def _crafted_indexes(width):
+    """Row indexes [case, ROWS] that stress the one-key kernel's tiles: all
+    four rows in one tile, two rows sharing a tile, lanes 0 and 127, the
+    last tile."""
+    last = width - 128
+    mid = width // 2 // 128 * 128
+    return np.asarray([
+        [last + 3, last + 3, last + 100, last + 127],  # all rows, one tile
+        [0, 127, mid + 5, width - 1],  # rows 0, 1 share tile 0
+        [0, mid, mid + 127, last],  # lanes 0 and 127
+        [width - 1] * 4,  # the last lane of the last tile
+    ], np.int32)
+
+
+class TestCMSUpdateOne:
+    """The one-key in-place increment (``cms_update_one``) == the oracle's
+    saturating increment of the key's four counters, whatever the width."""
+
+    CAP = 15
+
+    def _bump(self, table, idx):
+        """``cms_update_ref``'s increment, at given row indexes."""
+        onehot = jax.nn.one_hot(idx, table.shape[1], dtype=table.dtype)
+        return jnp.minimum(table + onehot, self.CAP)
+
+    def _one(self, table, idx, valid):
+        from repro.kernels.cms.cms import cms_update_one_pallas
+
+        one = jax.jit(cms_update_one_pallas, static_argnames=("cap",))
+        return one(table, jnp.asarray(idx, jnp.int32), valid, cap=self.CAP)
+
+    @pytest.mark.parametrize("case", ["random", "crafted", "invalid", "flush"])
+    @pytest.mark.parametrize("width", [128, 1024, 65536, 131072])
+    def test_matches_ref(self, width, case):
+        rng = np.random.default_rng(width)
+        table = jnp.asarray(rng.integers(0, self.CAP + 1, (cms_ref.ROWS, width)), jnp.int32)
+        keys = jnp.asarray(rng.integers(0, 1 << 31, 4), jnp.int32)
+        if case == "random":
+            for k in keys:
+                key = k.reshape(1)
+                want, _ = cms_ref.cms_update_estimate_ref(table, key, key, cap=self.CAP)
+                got = self._one(table, cms_ref.row_indexes(key, width)[:, 0], 1)
+                np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        elif case == "crafted":
+            for idx in _crafted_indexes(width):
+                for at_cap in (False, True):
+                    t = table.at[jnp.arange(cms_ref.ROWS), idx].set(self.CAP) if at_cap else table
+                    got = self._one(t, idx, 1)
+                    np.testing.assert_array_equal(np.asarray(got), np.asarray(self._bump(t, idx)))
+        elif case == "invalid":
+            for idx in [*_crafted_indexes(width), cms_ref.row_indexes(keys[:1], width)[:, 0]]:
+                np.testing.assert_array_equal(np.asarray(self._one(table, idx, 0)),
+                                              np.asarray(table))
+        else:  # flush_scores on one key == the width-sweep kernel's flush
+            from repro.kernels.cms.cms import cms_update_estimate_pallas
+
+            est = jnp.concatenate([keys, jnp.asarray(rng.integers(0, 1 << 31, 3), jnp.int32)])
+            est_idx = cms_ref.row_indexes(est, width)
+            for n_pend in (1, 0):
+                upd_idx = cms_ref.row_indexes(keys[:1], width)
+                upd_idx = jnp.where(n_pend > 0, upd_idx, width)
+                want_t, want_v = cms_update_estimate_pallas(table, upd_idx, est_idx, cap=self.CAP)
+                got_t, got_v = cms_ops.flush_scores_jit(
+                    table, keys[:1], n_pend, est, cap=self.CAP, use_pallas=True,
+                    interpret=True)
+                np.testing.assert_array_equal(np.asarray(got_t), np.asarray(want_t))
+                np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v.min(0)))
+
+
 class TestDeviceSketchAging:
     """Regression (ISSUE 4): ``DeviceSketch.increment`` applied a whole
     batch and then reset at most once, so a 1000-key batch at

@@ -331,6 +331,33 @@ class TestDeviceFullResyncs:
         assert pipe.resyncs == sum(pipe.resync_reasons.values())
         _assert_identical(a, e, ha, he, f"{spec} across resets")
 
+    @pytest.mark.parametrize("admission,eviction",
+                             [("av", "slru"), ("qv", "sampled_frequency")])
+    def test_pallas_sketch_across_padded_launches(self, admission, eviction):
+        """The Pallas branch (``use_pallas=True``: the in-place one-key CMS
+        kernel once per scan step) over batches of 29 on a chunk of 8, so
+        every batch's last launch runs padded steps (``valid = 0``), with a
+        tiny sketch forcing aging resets: the sketch table and its resets
+        equal the scalar reference's."""
+        rng = np.random.default_rng([DIFF_SEED, 0x9AD, _combo_key(admission, eviction)])
+        keys, sizes = _synth_trace(rng, n=210, key_space=40, size_mode="clustered")
+        keys = [k + 1 for k in keys]  # padded steps carry key 0: keep it out
+        cap = max(120, int(np.mean(sizes) * 8))
+        spec = f"wtlfu-{admission}-{eviction}?seed={DIFF_SEED}&sketch_backend=cms"
+        a, ha = _run_plane(spec, cap, keys, sizes, "scalar", expected_entries=16)
+        e, he = _run_plane_chunked(spec, cap, keys, sizes, "device_full",
+                                   expected_entries=16, chunk=8,
+                                   sketch_kwargs={"use_pallas": True})
+        e.sync_deferred()
+        assert e.sketch.use_pallas
+        assert a.sketch.resets > 0, "trace too short to age the sketch"
+        assert a.sketch.resets == e.sketch.resets
+        a.sketch.flush()
+        e.sketch.flush()
+        np.testing.assert_array_equal(np.asarray(a.sketch.table),
+                                      np.asarray(e.sketch.table))
+        _assert_identical(a, e, ha, he, f"{spec} use_pallas=True")
+
     def test_forced_mirror_grow_resync(self):
         """A trace whose live-entry count keeps growing outruns the initial
         device slot arrays: the mirror zero-pads ON DEVICE (counted as a

@@ -81,7 +81,8 @@ def _assert_kernel(compiled):
 
 
 def test_fused_cms_kernel(one_chip):
-    """The per-access fused update+estimate kernel of the chunk scan."""
+    """The CMS flush of the chunk scan (one key: the in-place increment)
+    and of a padded pending batch (the width sweep)."""
     import jax
 
     from repro.kernels.cms.ops import flush_scores
@@ -91,10 +92,12 @@ def test_fused_cms_kernel(one_chip):
         return flush_scores(table, upd, n, est, cap=15, use_pallas=True,
                             interpret=False)
 
-    compiled = jax.jit(step).lower(
-        _sds(one_chip, (ROWS, WIDTH)), _sds(one_chip, (1,)),
-        _sds(one_chip, ()), _sds(one_chip, (1,))).compile()
-    _assert_kernel(compiled)
+    for n_keys, name in ((1, "cms_update_one"), (PAD, "cms_update_estimate")):
+        compiled = jax.jit(step).lower(
+            _sds(one_chip, (ROWS, WIDTH)), _sds(one_chip, (n_keys,)),
+            _sds(one_chip, ()), _sds(one_chip, (1,))).compile()
+        _assert_kernel(compiled)
+        assert f"%{name}" in compiled.as_text(), f"{name} missing"
 
 
 def _kernel_payloads(lowered_text):
@@ -154,17 +157,23 @@ def test_kernel_payload_free_of_checkout_path(one_chip):
     pytest.param("sampled", id="sampled-qv-frequency_size"),
 ])
 def test_simulate_chunk(one_chip, main_kind):
-    """The chunk program; its fused CMS kernel is an instruction of its own
-    name, which a chip trace names the kernel's events by."""
+    """The chunk program; its CMS kernel is an instruction of its own name
+    that returns the table, which a chip trace names the kernel's events
+    by, and the table is updated in place: no copy of it is made."""
     import re
 
+    from repro.kernels.cms.ref import ROWS
     from repro.kernels.device_full import _simulate_chunk
 
     args, statics = _chunk_program(one_chip, main_kind)
     compiled = _simulate_chunk.lower(*args, **statics).compile()
     _assert_kernel(compiled)
-    named = r'%cms_update_estimate(\.\d+)? = .*custom_call_target="tpu_custom_call"'
-    assert re.search(named, compiled.as_text()), "fused CMS kernel not named"
+    text = compiled.as_text()
+    table = re.escape(f"s32[{ROWS},{WIDTH}]") + r"\{[^}]*\}"
+    named = (rf'%cms_update_one(\.\d+)? = {table} custom-call\(.*'
+             r'custom_call_target="tpu_custom_call"')
+    assert re.search(named, text), "one-key CMS kernel not named"
+    assert not re.search(rf"= {table} copy\(", text), "sketch table copied"
 
 
 def test_decision_chunk(one_chip):

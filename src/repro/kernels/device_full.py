@@ -1192,23 +1192,28 @@ class DeviceFullSimulationPlane:
             scal = scal.astype(np.int64)
             hits = np.asarray(inf.outs[12])
         with _span(_COMMIT, launch=launch):
-            self._commit(pol, inf, scal, hits)
-        if self._tick > _TICK_RENORM:
+            self._commit(pol, inf.stats_obj, inf.a_n, inf.sizes, scal, hits)
+        if self.renorm_due:
             self.ensure_host(pol)  # re-upload next launch with fresh ticks
 
-    def _commit(self, pol, inf, scal, hits) -> None:
-        """Commit a collected launch's stats, caps, adaptive-climber state
-        and scalar shadows, and keep its hit mask as ``_last_hits``."""
-        a_n = inf.a_n
+    @property
+    def renorm_due(self) -> bool:
+        """Whether the recency stamps must be renormalized (host authority
+        restored, fresh ticks on the next upload) before another launch."""
+        return self._tick > _TICK_RENORM
+
+    def _commit(self, pol, st, a_n, sizes, scal, hits) -> None:
+        """Commit a collected ``a_n``-access launch into ``st`` (the
+        policy's stats at dispatch) and the policy: caps, adaptive-climber
+        state and scalar shadows; keep its hit mask as ``_last_hits``."""
         sk = self.sketch
         sk._ops += a_n
         main = self.main
-        st = inf.stats_obj
         st.accesses += a_n
-        st.bytes_requested += int(inf.sizes.sum())
+        st.bytes_requested += int(sizes.sum())
         hit_mask = hits[:a_n]
         st.hits += int(hit_mask.sum())
-        st.bytes_hit += int(inf.sizes[hit_mask].sum())
+        st.bytes_hit += int(sizes[hit_mask].sum())
 
         def rel(name):
             return int(scal[_SCAL_IDX[name]])

@@ -38,6 +38,10 @@ Everything a sequential ``device_full`` run observes — admission
 decisions, ``CacheStats``, cache contents, upload and resync counters —
 is byte-identical per instance (asserted in the differential suite and
 the ``scripts/smoke_fleet.py`` canary).
+
+A round records its host phases as profiler spans (:data:`FLEET_SPANS`);
+``FleetEngine.lane_uploads`` and ``lane_restores`` count the lanes
+scattered into and gathered out of the stacked buffers.
 """
 
 from __future__ import annotations
@@ -51,12 +55,23 @@ import numpy as np
 from repro.core.engine import StatsSnapshot
 from repro.kernels.device_full import (
     DeviceFullSimulationPlane,
-    _InFlightSim,
     _limbs_of,
     _simulate_chunk_impl,
 )
 
-__all__ = ["FleetEngine", "FleetMember", "fleet_plane_of"]
+__all__ = ["FLEET_SPANS", "FleetEngine", "FleetMember", "fleet_plane_of"]
+
+#: A round's host spans in the order it opens them: its lanes' takes,
+#: upload, pack, enqueue, wait, fetch, commit. ``fleet.resync`` opens inside
+#: ``fleet.take`` (one per counted aging resync) or ``fleet.commit`` (a tick
+#: renorm), with ``lane=`` and ``reason=``. Each carries
+#: ``round=<launches>``, the index of the round's launch; a profiler trace
+#: records them on its host plane, on the device lines' clock.
+FLEET_SPANS = (_TAKE, _RESYNC, _UPLOAD, _PACK, _ENQUEUE, _WAIT, _FETCH, _COMMIT) = (
+    "fleet.take", "fleet.resync", "fleet.upload", "fleet.pack",
+    "fleet.enqueue", "fleet.wait", "fleet.fetch", "fleet.commit")
+#: Opens every span above.
+_span = jax.profiler.TraceAnnotation
 
 
 @functools.partial(
@@ -208,6 +223,8 @@ class FleetEngine:
         self.members: list[FleetMember] = []
         self.buckets: dict[tuple, _Bucket] = {}
         self.launches = 0  # fleet-kernel launches
+        self.lane_uploads = 0  # lanes scattered into a bucket's stacks
+        self.lane_restores = 0  # lanes gathered back to their host mirrors
 
     # -- membership ---------------------------------------------------------
     def add(self, policy, keys, sizes, label: str | None = None) -> FleetMember:
@@ -291,6 +308,7 @@ class FleetEngine:
             return  # bucket never launched: host state is still authoritative
         table, main, window = _gather_lane(
             b.table, *b.main, *b.window, m.lane)
+        self.lane_restores += 1
         m.pipe.sketch.table = table
         m.pipe.mirror.main = main
         m.pipe.mirror.window = window
@@ -307,11 +325,13 @@ class FleetEngine:
                 sk.flush()
             safe = sk.sample_size - sk._ops - 1
             if safe <= 0:
-                pipe.ensure_host(pol)  # restores ONLY this lane
-                pipe.resyncs += 1
-                pipe.resync_reasons["aging"] += 1
-                hit = pol.access(int(m.keys[m.pos]), int(m.sizes[m.pos]))
-                self._advance(m, np.asarray([hit], dtype=bool))
+                with _span(_RESYNC, round=self.launches, lane=m.lane,
+                           reason="aging"):
+                    pipe.ensure_host(pol)  # restores ONLY this lane
+                    pipe.resyncs += 1
+                    pipe.resync_reasons["aging"] += 1
+                    hit = pol.access(int(m.keys[m.pos]), int(m.sizes[m.pos]))
+                    self._advance(m, np.asarray([hit], dtype=bool))
                 continue
             take = min(end - m.pos, pipe.chunk, safe)
             if self.snapshot_every:
@@ -379,6 +399,7 @@ class FleetEngine:
         b.table, b.main, b.window = _scatter_lanes(
             b.table, *b.main, *b.window, idx, trows, tuple(mrows),
             tuple(wrows))
+        self.lane_uploads += k
         b.main = list(b.main)
         b.window = list(b.window)
         for m in uploaded:
@@ -387,43 +408,45 @@ class FleetEngine:
             m.pipe._host_auth = False
 
     def _step(self, b: _Bucket) -> bool:
-        takes = [self._member_take(m) for m in b.members]
+        rnd = self.launches
+        with _span(_TAKE, round=rnd):
+            takes = [self._member_take(m) for m in b.members]
         if not any(takes):
             return False
-        uploaded = []
-        for m, t in zip(b.members, takes):
-            if not t:
-                continue
-            if m.pipe._preflight(m.policy, t):
-                uploaded.append(m)
-        self._ensure_stacks(b, uploaded)
-
-        pad = max(m.pipe.scan_len() for m in b.members)
-        inputs = zip(*(
-            m.pipe.launch_inputs(m.policy, m.khi[m.pos: m.pos + t],
-                                 m.klo[m.pos: m.pos + t],
-                                 m.sizes[m.pos: m.pos + t], t, pad)
-            for m, t in zip(b.members, takes)))
-        outs = _fleet_chunk(
-            b.table, *b.main, *b.window,
-            *(np.stack(a) for a in inputs), **b.statics)
-        self.launches += 1
-        # adopt immediately: the stacked inputs were donated
-        b.table = outs[0]
-        b.main = list(outs[1:6])
-        b.window = list(outs[6:10])
-        scal_out = np.asarray(outs[10])
-        hits_out = np.asarray(outs[12])
-
-        for i, (m, t) in enumerate(zip(b.members, takes)):
-            if not t:
-                continue
-            m.pipe.chunk_calls += 1
-            fouts = [None] * 13
-            fouts[10] = scal_out[i]
-            fouts[12] = hits_out[i]
-            m.pipe._inflight = _InFlightSim(
-                tuple(fouts), t, m.sizes[m.pos: m.pos + t], m.policy.stats)
-            m.pipe._collect(m.policy)  # tick renorm restores only this lane
-            self._advance(m, np.asarray(m.pipe._last_hits[:t], dtype=bool))
+        live = [(m, t) for m, t in zip(b.members, takes) if t]
+        with _span(_UPLOAD, round=rnd):
+            uploaded = [m for m, t in live if m.pipe._preflight(m.policy, t)]
+            self._ensure_stacks(b, uploaded)
+        with _span(_PACK, round=rnd):
+            pad = max(m.pipe.scan_len() for m in b.members)
+            inputs = [np.stack(a) for a in zip(*(
+                m.pipe.launch_inputs(m.policy, m.khi[m.pos: m.pos + t],
+                                     m.klo[m.pos: m.pos + t],
+                                     m.sizes[m.pos: m.pos + t], t, pad)
+                for m, t in zip(b.members, takes)))]
+        with _span(_ENQUEUE, round=rnd):
+            outs = _fleet_chunk(b.table, *b.main, *b.window, *inputs,
+                                **b.statics)
+            self.launches += 1
+            # adopt immediately: the stacked inputs were donated
+            b.table = outs[0]
+            b.main = list(outs[1:6])
+            b.window = list(outs[6:10])
+        with _span(_WAIT, round=rnd):
+            # the scalars' copy is queued behind the launch: its arrival
+            # ends the wait
+            scal_out = np.asarray(outs[10])
+        with _span(_FETCH, round=rnd):
+            scal_out = scal_out.astype(np.int64)
+            hits_out = np.asarray(outs[12])
+        with _span(_COMMIT, round=rnd):
+            for m, t in live:
+                pipe, pol = m.pipe, m.policy
+                pipe.chunk_calls += 1
+                pipe._commit(pol, pol.stats, t, m.sizes[m.pos: m.pos + t],
+                             scal_out[m.lane], hits_out[m.lane])
+                if pipe.renorm_due:
+                    with _span(_RESYNC, round=rnd, lane=m.lane, reason="renorm"):
+                        pipe.ensure_host(pol)  # restores only this lane
+                self._advance(m, hits_out[m.lane][:t].astype(bool))
         return True

@@ -1155,10 +1155,12 @@ class DeviceFullSimulationPlane:
     def chunk_args(self, pol, khi=(), klo=(), szs=(), take=0) -> tuple:
         """The positional args of one :func:`_simulate_chunk` launch: the
         donated state buffers (sketch table, Main, Window), then
-        :meth:`launch_inputs`."""
+        :meth:`launch_inputs` as the NumPy arrays it builds. Nothing moves
+        to the device here: the jitted call transfers those inputs in its
+        own dispatch, as the fleet's does. Every launch passes the same
+        kinds of argument, so the program has one compiled entry."""
         return (self.sketch.table, *self.mirror.main, *self.mirror.window,
-                *(jnp.asarray(a) for a in
-                  self.launch_inputs(pol, khi, klo, szs, take)))
+                *self.launch_inputs(pol, khi, klo, szs, take))
 
     def _dispatch(self, pol, khi, klo, szs, take) -> "_InFlightSim":
         sk = self.sketch
@@ -1174,19 +1176,24 @@ class DeviceFullSimulationPlane:
             sk.table = outs[0]
             self.mirror.adopt(tuple(outs[1:6]), tuple(outs[6:10]))
             self._host_auth = False
+            # queue both readbacks behind the program: the mask's copy then
+            # follows the scalars' instead of starting when the host wakes
+            outs[10].copy_to_host_async()
+            outs[12].copy_to_host_async()
         return _InFlightSim(outs, take, szs, pol.stats)
 
     def _collect(self, pol) -> None:
         """Materialize the in-flight launch (blocking) and commit stats,
-        caps, adaptive-climber state, and the scalar shadows."""
+        caps, adaptive-climber state, and the scalar shadows. Both
+        readbacks were queued at enqueue (:meth:`_dispatch`): the scalars'
+        arrival ends the wait, and the hit mask's copy has run behind it."""
         if self._inflight is None:
             return
         inf, self._inflight = self._inflight, None
         launch = self.chunk_calls - 1  # the in-flight launch is the last one
         with _span(_WAIT, launch=launch):
-            # the scalars' copy is queued behind the launch and their
-            # arrival ends the wait: a block of its own would queue the
-            # copies only after the host wakes
+            # the scalars' arrival ends the wait: a block of its own would
+            # only add a host wake-up
             scal = np.asarray(inf.outs[10])
         with _span(_FETCH, launch=launch):
             scal = scal.astype(np.int64)

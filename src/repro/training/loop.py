@@ -80,6 +80,9 @@ def train(
         ckpt.save(step, tree, metadata={"host": loop_cfg.host})
 
     def restore() -> int:
+        # drain the async writer first: a queued re-save of the step about
+        # to be read deletes its directory before publishing it again
+        ckpt.wait()
         step = ckpt.latest_step()
         if step is None:
             return 0

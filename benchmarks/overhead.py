@@ -22,31 +22,17 @@ in ``BENCH_overhead.json``:
   one batched ``estimate_batch`` call vs N scalar ``estimate`` calls. This
   is the data plane the batching is built for (one kernel dispatch instead
   of N); ``batched_speedup`` here is the headline batching win.
-* **Device plane** — ``data_plane=device`` (the whole decision as ONE
-  jitted sample->score->select call, ``repro.kernels.admission``) vs the
-  scalar walk on the SAME CMS backend. Off-TPU these rows measure kernel
-  semantics plus XLA-CPU dispatch, not accelerator speed — the point is
-  the per-PR trajectory (``BENCH_overhead.json`` at the repo root, written
-  by ``benchmarks/run.py``), and a hard hit-ratio equality check fails the
-  run if the planes ever stop deciding identically.
-* **Decision-batched device plane** — ``data_plane=device_batched`` (a
-  chunk of decisions per launch: speculative window-cascade unrolling in
-  one ``lax.scan``) vs the per-decision device plane, both on the CMS
-  backend. This is the dispatch-amortization claim: the per-decision
-  plane pays one jitted call per admission decision, the batched plane
-  one per buffered chunk. Rows are measured **steady-state** (an untimed
-  warm run first compiles every kernel variant), since jit compilation is
-  a one-time cost the paper's CPU-overhead comparison is not about;
-  ``decision_batch_speedup`` is the headline number and the same hard
-  hit-ratio equality check applies.
 * **Whole-simulation device plane** — ``data_plane=device_full`` (the
   ENTIRE simulation step for a chunk of accesses in one ``lax.scan``:
   window hits, recency updates, miss cascade, adaptive climber, with the
-  cache state device-resident between chunks) vs ``device_batched``
-  (which flushes speculation to the host on every main hit and resolves
-  prefix-main decisions one launch each). Same steady-state protocol and
-  hard hit-ratio equality check; ``whole_sim_speedup`` is the ISSUE 7
-  tentpole number.
+  cache state device-resident between chunks) vs the scalar walk on the
+  SAME CMS backend. Rows are measured **steady-state** (an untimed warm
+  run first compiles every kernel variant), since jit compilation is a
+  one-time cost the paper's CPU-overhead comparison is not about. Off-TPU
+  these rows measure kernel semantics plus XLA-CPU dispatch, not
+  accelerator speed, and a hard hit-ratio equality check fails the run if
+  the planes ever stop deciding identically; ``speedup_vs_scalar`` is the
+  scalar us/access over the device_full us/access.
 """
 
 from __future__ import annotations
@@ -75,30 +61,13 @@ DATA_PLANE_POLICIES = (
 )
 #: Victim-set sizes for the sketch-level data-plane comparison.
 SKETCH_BATCH_SIZES = (8, 32, 128)
-#: Specs run under the device-resident plane vs the scalar walk (both on
-#: the CMS backend): one per admission discipline, covering the mirror-walk
-#: kernel (sampled/random mains) and the covering-prefix kernel (SLRU).
-DEVICE_PLANE_POLICIES = (
-    "wtlfu-av-slru",
-    "wtlfu-qv-sampled_frequency",
-    "wtlfu-iv-random",
-)
 #: Accesses driven per device-plane row: enough decisions to amortize jit
 #: compilation into the noise floor while keeping the off-TPU (XLA-CPU)
 #: comparison affordable.
 DEVICE_PLANE_LIMIT = 6_000
-#: Specs for the decision-batched comparison: mirror-slot (sampled/random)
-#: mains, where decision chunking actually batches (prefix mains resolve
-#: per decision by design — their victim order lives in host dicts).
-DEVICE_BATCHED_POLICIES = (
-    "wtlfu-qv-sampled_frequency",
-    "wtlfu-av-sampled_frequency_size",
-    "wtlfu-iv-random",
-)
-#: Specs for the whole-simulation comparison (ISSUE 7): the sampled mains
-#: where device_batched is at its best, PLUS the prefix mains (LRU/SLRU)
-#: it must resolve per decision — device_full keeps their recency order on
-#: device, so those rows isolate the tentpole win.
+#: Specs for the whole-simulation comparison: sampled/random mains (the
+#: in-scan sample walk) and the recency mains (LRU/SLRU, whose order the
+#: device keeps as stamps).
 DEVICE_FULL_POLICIES = (
     "wtlfu-qv-sampled_frequency",
     "wtlfu-av-sampled_frequency_size",
@@ -138,136 +107,19 @@ def sketch_data_plane_rows(batch_sizes=SKETCH_BATCH_SIZES, repeats: int = 30) ->
     return rows
 
 
-def device_plane_rows(traces=("msr2",), frac=0.01, limit=DEVICE_PLANE_LIMIT) -> list[dict]:
-    """Device-resident vs scalar admission plane on the CMS sketch backend.
-
-    Each pair's hit ratios must agree (checked with a hard ``raise``, so a
-    plane divergence fails the bench run — at ``limit`` accesses the
-    5-decimal rounding cannot mask even a single differing decision);
-    ``device_speedup`` = scalar us/access over device us/access.
-    """
-    rows = []
-    for tname in traces:
-        tr = get_trace(tname)
-        cap = max(1, int(tr.total_object_bytes * frac))
-        for pol in DEVICE_PLANE_POLICIES:
-            spec = PolicySpec.parse(pol)
-            pair = {}
-            for plane in ("device", "scalar"):
-                rp = run_policy(spec.with_params(data_plane=plane, sketch_backend="cms"),
-                                tr, cap, limit=limit)
-                rp["frac"] = frac
-                pair[plane] = rp
-                rows.append(rp)
-            if pair["device"]["hit_ratio"] != pair["scalar"]["hit_ratio"]:
-                raise AssertionError(
-                    f"{pol}: device plane diverged from scalar "
-                    f"({pair['device']['hit_ratio']} vs {pair['scalar']['hit_ratio']})"
-                )
-            pair["device"]["hit_ratio_matches_scalar"] = True
-            pair["device"]["device_speedup"] = round(
-                pair["scalar"]["us_per_access"]
-                / max(1e-9, pair["device"]["us_per_access"]),
-                3,
-            )
-    return rows
-
-
-def device_batched_rows(traces=("msr2",), frac=0.001,
-                        limit=DEVICE_PLANE_LIMIT) -> list[dict]:
-    """Per-decision device plane vs the decision-batched pipeline.
-
-    Steady-state measurement: each (spec, plane) pair runs once untimed to
-    compile every kernel variant (scan-length/segment-pad buckets), then
-    the timed run measures pure dispatch+execute. The ``device`` baseline
-    pins the per-decision path (``access_batch`` normally auto-upgrades it
-    to the batched pipeline — which is the point of this comparison).
-    Hit ratios must match exactly (hard ``raise`` on divergence).
-
-    The default 0.1% capacity point is the decision-heavy regime the
-    paper's CPU-overhead comparison targets: misses generate admission
-    decisions, and every Main hit is a speculation barrier that flushes
-    the decision buffer — so batching wins grow as the hit ratio falls
-    (2-3.6x on XLA-CPU at 0.1%, tapering toward ~1.5-2x at 1%).
-    """
-    rows = []
-    for tname in traces:
-        tr = get_trace(tname)
-        cap = max(1, int(tr.total_object_bytes * frac))
-        ee = max(64, int(cap / max(1.0, tr.mean_object_size)))
-        for pol in DEVICE_BATCHED_POLICIES:
-            spec = PolicySpec.parse(pol)
-            pair = {}
-            for plane in ("device", "device_batched"):
-                sp = spec.with_params(data_plane=plane, sketch_backend="cms")
-
-                def build():
-                    p = REGISTRY.build(sp, cap, expected_entries=ee)
-                    if plane == "device":
-                        # pin one launch per decision (fail loudly if the
-                        # routing attribute ever moves — a silent no-op here
-                        # would make both arms measure the batched pipeline)
-                        assert p._device_pipeline is not None
-                        p._device_pipeline = None
-                    return p
-
-                SimulationEngine().run(build(), tr, limit=limit)  # warm jit
-                policy = build()
-                t0 = time.perf_counter()
-                res = SimulationEngine().run(policy, tr, limit=limit)
-                wall = time.perf_counter() - t0
-                st = res.stats
-                rp = {
-                    "policy": sp.to_string(),
-                    "trace": tr.name,
-                    "capacity": cap,
-                    "frac": frac,
-                    "accesses": st.accesses,
-                    "hit_ratio": round(st.hit_ratio, 5),
-                    "us_per_access": round(wall / max(1, st.accesses) * 1e6, 3),
-                    "wall_s": round(wall, 3),
-                    "data_plane": plane,
-                    "warmed": True,
-                }
-                if plane == "device_batched":
-                    pipe = policy.admission_policy._device_batch
-                    rp.update(
-                        decisions=pipe.decisions,
-                        chunk_calls=pipe.chunk_calls,
-                        batched_decisions=pipe.batched_decisions,
-                        resyncs=pipe.resyncs,
-                    )
-                pair[plane] = rp
-                rows.append(rp)
-            if pair["device"]["hit_ratio"] != pair["device_batched"]["hit_ratio"]:
-                raise AssertionError(
-                    f"{pol}: device_batched diverged from device "
-                    f"({pair['device_batched']['hit_ratio']} vs "
-                    f"{pair['device']['hit_ratio']})"
-                )
-            pair["device_batched"]["hit_ratio_matches_device"] = True
-            pair["device_batched"]["decision_batch_speedup"] = round(
-                pair["device"]["us_per_access"]
-                / max(1e-9, pair["device_batched"]["us_per_access"]),
-                3,
-            )
-    return rows
-
-
 def device_full_rows(traces=("msr2",), frac=0.001,
                      limit=DEVICE_PLANE_LIMIT) -> list[dict]:
-    """Whole-simulation-on-device vs the decision-batched pipeline.
+    """Whole-simulation-on-device vs the scalar walk, both on the CMS
+    sketch backend.
 
     ``device_full`` resolves an entire access chunk — window hits,
     recency updates, the miss cascade — in ONE ``lax.scan`` launch with
-    the cache state device-resident between chunks, where
-    ``device_batched`` flushes speculation to the host on every main hit
-    and resolves prefix-main (LRU/SLRU) decisions one launch each. Same
-    steady-state protocol as :func:`device_batched_rows` (untimed warm
-    run compiles every shape bucket, then the timed run measures pure
-    dispatch+execute); hit ratios must match exactly (hard ``raise``).
-    ``whole_sim_speedup`` = device_batched us/access over device_full
-    us/access — the tentpole number, largest on the prefix mains.
+    the cache state device-resident between chunks. Steady-state
+    protocol: an untimed warm run compiles every shape bucket, then the
+    timed run measures pure dispatch+execute. Hit ratios must match
+    exactly (hard ``raise``; at ``limit`` accesses the 5-decimal rounding
+    cannot mask a single differing decision). ``speedup_vs_scalar`` =
+    scalar us/access over device_full us/access.
     """
     rows = []
     for tname in traces:
@@ -277,7 +129,7 @@ def device_full_rows(traces=("msr2",), frac=0.001,
         for pol in DEVICE_FULL_POLICIES:
             spec = PolicySpec.parse(pol)
             pair = {}
-            for plane in ("device_batched", "device_full"):
+            for plane in ("scalar", "device_full"):
                 sp = spec.with_params(data_plane=plane, sketch_backend="cms")
                 SimulationEngine().run(
                     REGISTRY.build(sp, cap, expected_entries=ee), tr,
@@ -309,15 +161,15 @@ def device_full_rows(traces=("msr2",), frac=0.001,
                     )
                 pair[plane] = rp
                 rows.append(rp)
-            if pair["device_full"]["hit_ratio"] != pair["device_batched"]["hit_ratio"]:
+            if pair["device_full"]["hit_ratio"] != pair["scalar"]["hit_ratio"]:
                 raise AssertionError(
-                    f"{pol}: device_full diverged from device_batched "
+                    f"{pol}: device_full diverged from scalar "
                     f"({pair['device_full']['hit_ratio']} vs "
-                    f"{pair['device_batched']['hit_ratio']})"
+                    f"{pair['scalar']['hit_ratio']})"
                 )
-            pair["device_full"]["hit_ratio_matches_device_batched"] = True
-            pair["device_full"]["whole_sim_speedup"] = round(
-                pair["device_batched"]["us_per_access"]
+            pair["device_full"]["hit_ratio_matches_scalar"] = True
+            pair["device_full"]["speedup_vs_scalar"] = round(
+                pair["scalar"]["us_per_access"]
                 / max(1e-9, pair["device_full"]["us_per_access"]),
                 3,
             )
@@ -470,8 +322,6 @@ def main(traces=PAPER_TRACES, fracs=FRACS) -> list[dict]:
                     / max(1e-9, pair["batched"]["us_per_access"]),
                     3,
                 )
-    rows.extend(device_plane_rows())
-    rows.extend(device_batched_rows())
     rows.extend(device_full_rows())
     if not sequential_mode():
         rows.extend(fleet_rows())

@@ -105,14 +105,14 @@ def main() -> list[dict]:
 # End-to-end load benchmark (ISSUE 6): bursty multi-tenant open-loop
 # arrivals driven through scheduler -> prefix cache, with the admission
 # pipeline either synchronous (per-access verdicts, the baseline) or async
-# (deferred device-batched decision chunks). Measures sustained
+# (deferred device_full chunk launches). Measures sustained
 # requests/sec, p50/p99 admission-decision latency, queue depth, and the
 # three hit ratios; appended to BENCH_serving.json by benchmarks.run.
 # ---------------------------------------------------------------------------
 
-#: Device-batched W-TinyLFU: the paper's AV discipline over a sampled
-#: main, one lax.scan launch per decision chunk.
-LOAD_POLICY = "wtlfu-av-sampled_frequency?data_plane=device_batched&chunk=64&sketch_backend=cms"
+#: Device W-TinyLFU: the paper's AV discipline over a sampled main, one
+#: lax.scan launch per chunk of accesses.
+LOAD_POLICY = "wtlfu-av-sampled_frequency?data_plane=device_full&chunk=64&sketch_backend=cms"
 LOAD_ARCH = "smollm-135m"
 MAX_NEW_TOKENS = 16
 
@@ -240,7 +240,7 @@ def trace_name(trace) -> str:
 
 def load_main(quick: "bool | None" = None) -> list[dict]:
     """The registered ``serving`` benchmark: async pipeline vs the
-    synchronous per-access baseline on the same device-batched policy
+    synchronous per-access baseline on the same device_full policy
     spec (byte-identical decisions by construction — asserted), plus a
     host-plane async row for context."""
     if quick is None:

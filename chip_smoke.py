@@ -404,6 +404,7 @@ def phase_sharded(spec: str, tr, cap: int, ee: int, env: Env) -> dict:
 def phase_serving(env: Env) -> dict:
     from benchmarks.serving_cache import LOAD_POLICY, run_load
     from repro.kernels import admission as admission_plane
+    from repro.kernels import device_full
     from repro.traces import ARRIVAL_SPECS, make_arrivals
 
     trace = make_arrivals(ARRIVAL_SPECS["bursty_small"], seed=0,
@@ -411,14 +412,11 @@ def phase_serving(env: Env) -> dict:
     row = {"phase": "serving", "policy": LOAD_POLICY, "requests": len(trace)}
     for admission in ("sync", "async"):
         m0 = env.meter.read()
-        # sync admission decides one launch per decision; async batches
-        # the decisions it can defer into decision-chunk launches
-        programs = ["_decide_sampled"] + (
-            ["_decide_sampled_chunk"] if admission == "async" else [])
-        with contextlib.ExitStack() as probes:
-            for name in programs:
-                probes.enter_context(first_launch_lowered(
-                    admission_plane, name, env, f"serving {admission}"))
+        # sync admission decides one launch per decision; async drains
+        # whole access chunks, one chunk-program launch each
+        module, name = ((admission_plane, "_decide_sampled") if admission == "sync"
+                        else (device_full, "_simulate_chunk"))
+        with first_launch_lowered(module, name, env, f"serving {admission}"):
             res, cache = run_load(LOAD_POLICY, admission, trace)
         check_pallas(cache.policy, env, f"serving {admission}")
         cache.pool.check_invariants()

@@ -5,7 +5,7 @@ Fast end-to-end check (<30s: one small fixed-seed arrival trace) that
 
 * the async admission pipeline stays byte-identical to the synchronous
   per-access baseline — same entries, same hit ratios, same policy stats,
-* deferred decision chunks actually engage (deferred dispatches > 0 and
+* deferred chunk launches actually engage (deferred dispatches > 0 and
   fewer chunk launches than decisions),
 * the shared BlockPool survives with its refcount invariants intact, and
 * the cache operates in a sane regime (nonzero hit ratio, bounded
@@ -24,7 +24,7 @@ import time
 from repro.serving import PrefixCache, PrefixCacheConfig
 from repro.traces import ARRIVAL_SPECS, make_arrivals
 
-SPEC = "wtlfu-av-sampled_frequency?data_plane=device_batched&chunk=16&sketch_backend=cms"
+SPEC = "wtlfu-av-sampled_frequency?data_plane=device_full&chunk=16&sketch_backend=cms"
 BPT = 2 * 3 * 64 * 2  # smollm-class per-token KV bytes
 BLOCK = 16
 
@@ -75,7 +75,7 @@ def main() -> int:
         return 1
     m = a.admission.metrics()
     if m["deferred_dispatches"] == 0:
-        print("FAIL: async pipeline never deferred a decision chunk",
+        print("FAIL: async pipeline never deferred a chunk launch",
               file=sys.stderr)
         return 1
     if m["chunk_calls"] >= m["decisions"]:

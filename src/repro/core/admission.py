@@ -19,9 +19,10 @@ Three planes are implemented for every discipline — ``admit`` (batched),
 ``admit_scalar`` (the reference per-victim walk; also what
 ``SizeAwareWTinyLFU(data_plane="auto")`` resolves to on the host sketch,
 where direct calls beat batching abstraction at typical victim counts) and
-``admit_device`` (the closed-loop device plane: victim draws, gather, fused
-CMS flush+estimate, verdict replay and victim selection all in ONE jitted
-call — see :mod:`repro.kernels.admission`) — and are
+``admit_device`` (the per-decision device kernel: victim draws, gather,
+fused CMS flush+estimate, verdict replay and victim selection all in ONE
+jitted call — see :mod:`repro.kernels.admission`; it is how
+``data_plane="device_full"`` decides on its host path) — and are
 **byte-identical**: same admissions, same evictions in the same order, same
 ``CacheStats`` counters, asserted trace-wide in
 ``tests/test_admission_data_plane.py``. The equivalence arguments, per
@@ -177,8 +178,8 @@ class AdmissionPolicy:
     # -- device data plane -------------------------------------------------
     def bind_device_plane(self, main: "EvictionPolicy"):
         """Build this discipline's device-resident decision engine over
-        ``main`` (the ``data_plane="device"`` plumbing; requires the CMS
-        sketch backend and a peek-stable main — see
+        ``main`` (``data_plane="device_full"``'s host-path decider;
+        requires the CMS sketch backend and a peek-stable main — see
         :mod:`repro.kernels.admission`). Returns the bound plane."""
         from repro.kernels.admission import DeviceAdmissionPlane
 
@@ -195,32 +196,6 @@ class AdmissionPolicy:
         returns to the host. Byte-identical to both host planes, asserted
         across the full admission x eviction grid in tests."""
         return self._device.decide(key, size, needed, main, stats)
-
-    def bind_device_batch_plane(self, main: "EvictionPolicy", *,
-                                chunk: int = 64, victim_cap: int = 16):
-        """Build the decision-batched device pipeline over ``main`` (the
-        ``data_plane="device_batched"`` engine; also what ``"device"``
-        auto-upgrades to when the engine drives ``access_batch``). Wraps
-        the per-decision plane from :meth:`bind_device_plane` — binding it
-        first if needed — so speculation-depth resyncs fall back onto the
-        exact same per-decision kernels. Returns the bound pipeline."""
-        from repro.kernels.admission import DeviceBatchedAdmissionPlane
-
-        if not hasattr(self, "_device"):
-            self.bind_device_plane(main)
-        self._device_batch = DeviceBatchedAdmissionPlane(
-            self._device, chunk=chunk, victim_cap=victim_cap)
-        return self._device_batch
-
-    def admit_device_batch(self, key: int, size: int, needed: int,
-                           main: "EvictionPolicy", stats: "CacheStats") -> bool:
-        """Scalar-drive twin of the decision-batched plane: a lone
-        ``access()`` call (or an adaptive-window drain) offers exactly one
-        decision, so it resolves through the per-decision device kernel —
-        byte-identical by construction. Decision *batching* engages on the
-        chunk path (``DeviceBatchedAdmissionPlane.drive_chunk``), which the
-        owning policy's ``access_batch`` routes whole chunks into."""
-        return self.admit_device(key, size, needed, main, stats)
 
 
 class IVAdmission(AdmissionPolicy):

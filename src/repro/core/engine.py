@@ -144,7 +144,7 @@ class SimulationResult:
     wall_seconds: float = 0.0
     used_batch: bool = False
     #: The policy's resolved admission data plane ("scalar" / "batched" /
-    #: "device" / "device_batched"), or None for policies without one —
+    #: "device_full"), or None for policies without one —
     #: benchmark rows key their per-plane throughput comparisons on this.
     data_plane: str | None = None
 

@@ -365,20 +365,13 @@ class SampledEviction(EvictionPolicy):
         self._mirror = None
 
     def attach_mirror(self, mirror) -> None:
-        """Register a slot-write observer and replay the current table into
-        it. The mirror sees ``record(slot, key, size)`` for the insert
-        append and the swap-remove back-fill — exactly the writes that keep
-        a dense ``slot -> (key, size)`` twin in sync with ``self.keys``.
-        Mirrors exposing the batched ``load`` hook (the device admission
-        planes') get the existing table as one vectorized scatter instead
-        of len(keys) per-slot records."""
+        """Register a slot-write observer and load the current table into
+        it (``load(keys, sizes)``, one vectorized fill). The mirror then
+        sees ``record(slot, key, size)`` for the insert append and the
+        swap-remove back-fill — exactly the writes that keep a dense
+        ``slot -> (key, size)`` twin in sync with ``self.keys``."""
         self._mirror = mirror
-        load = getattr(mirror, "load", None)
-        if load is not None:
-            load(self.keys, self.sizes)
-            return
-        for i, k in enumerate(self.keys):
-            mirror.record(i, k, self.sizes[k])
+        mirror.load(self.keys, self.sizes)
 
     def insert(self, key: int, size: int) -> None:
         self.sizes[key] = size
@@ -498,9 +491,7 @@ class SampledEviction(EvictionPolicy):
         self.keys = [k for k, _, _ in rows]
         self.pos = {k: i for i, k in enumerate(self.keys)}
         if self._mirror is not None:
-            load = getattr(self._mirror, "load", None)
-            if load is not None:
-                load(self.keys, self.sizes)
+            self._mirror.load(self.keys, self.sizes)
 
 
 class RandomEviction(SampledEviction):

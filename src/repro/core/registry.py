@@ -204,7 +204,7 @@ class PolicySpec:
     def with_params(self, **overrides: Any) -> "PolicySpec":
         """A copy with ``overrides`` merged over the existing params — how
         the benchmark sweeps derive per-data-plane variants of one spec
-        (``spec.with_params(data_plane="device")``)."""
+        (``spec.with_params(data_plane="device_full")``)."""
         merged = self.params_dict
         merged.update(overrides)
         return PolicySpec.make(self.name, **merged)

@@ -53,8 +53,7 @@ EVICTIONS = (
 )
 
 SKETCH_BACKENDS = ("auto", "host", "cms")
-DATA_PLANES = (
-    "auto", "batched", "scalar", "device", "device_batched", "device_full")
+DATA_PLANES = ("auto", "batched", "scalar", "device_full")
 
 
 def _wtlfu_alias(name: str) -> dict | None:
@@ -104,28 +103,24 @@ class SizeAwareWTinyLFU:
         flushed lazily before estimates, which is exactly equivalent to
         scalar driving — see :mod:`repro.core.cms_sketch`). The default
         ``"auto"`` resolves to ``"host"`` except under
-        ``data_plane="device"``, which requires (and implies) ``"cms"``.
-    data_plane: ``"batched"`` scores each admission decision with one
+        ``data_plane="device_full"``, which requires (and implies) ``"cms"``.
+    data_plane: ``"scalar"`` pins the reference per-victim walk;
+        ``"batched"`` scores each admission decision with one
         ``estimate_batch`` call over the lazily-gathered victim prefix;
-        ``"scalar"`` pins the reference per-victim walk; ``"device"`` runs
-        the WHOLE decision — victim draws, key/size gather, fused CMS
-        flush+estimate, verdict replay, victim selection — as one jitted
-        device call (CMS backend only; see
-        :mod:`repro.kernels.admission`); ``"device_batched"`` additionally
-        batches whole *chunks* of decisions per launch (speculative
-        window-cascade unrolling in a ``lax.scan``; ``chunk=`` sets the
-        buffer). Under ``access_batch`` (the engine's default drive path)
-        ``"device"`` auto-upgrades to the same decision-batched pipeline —
-        per-decision dispatch is pure overhead once the caller already
-        hands over chunks. The default ``"auto"`` picks per sketch backend
-        (``sketch.batched_native``): batched for the CMS kernels — one
-        fused launch per decision beats per-victim kernel calls — and the
-        scalar walk for the host sketch, where CPython method dispatch
-        makes direct calls the lightweight option at typical victim
-        counts. Decisions are byte-identical on every plane (asserted
+        ``"device_full"`` runs the whole simulation step on the device:
+        ``access_batch`` resolves ``chunk`` accesses per ``lax.scan``
+        launch (window, main, miss cascade, decisions, adaptive climber;
+        see :mod:`repro.kernels.device_full`), with the cache state
+        device-resident between launches, while scalar ``access`` decides
+        each admission with one jitted call (CMS backend only; see
+        :mod:`repro.kernels.admission`). The default ``"auto"`` picks per
+        sketch backend (``sketch.batched_native``): batched for the CMS
+        kernels — one fused launch per decision beats per-victim kernel
+        calls — and the scalar walk for the host sketch, where CPython
+        method dispatch makes direct calls the lightweight option at
+        typical victim counts. Decisions are byte-identical on every plane (asserted
         trace-wide in tests).
-    chunk: decision-buffer capacity of the ``device_batched`` pipeline
-        (decisions resolved per chunk-kernel launch); ignored by the other
+    chunk: accesses per ``device_full`` launch; ignored by the other
         planes. Spec-string ``?chunk=`` plumbs it through the registry.
     """
 
@@ -151,7 +146,7 @@ class SizeAwareWTinyLFU:
             raise ValueError(f"sketch_backend must be one of {SKETCH_BACKENDS}")
         if data_plane not in DATA_PLANES:
             raise ValueError(f"data_plane must be one of {DATA_PLANES}")
-        device_plane = data_plane in ("device", "device_batched", "device_full")
+        device_plane = data_plane == "device_full"
         if sketch_backend == "auto":
             sketch_backend = "cms" if device_plane else "host"
         if device_plane and sketch_backend != "cms":
@@ -207,30 +202,16 @@ class SizeAwareWTinyLFU:
         if data_plane == "auto":
             data_plane = "batched" if getattr(self.sketch, "batched_native", False) else "scalar"
         self.data_plane = data_plane  # resolved, never "auto"
-        #: Decision-batched chunk pipeline; set for BOTH device planes —
-        #: ``access_batch`` routes whole chunks through it ("device"
-        #: auto-upgrades once the caller hands over chunks), while scalar
-        #: ``access`` (and the adaptive-window drain) stays per-decision.
+        #: The ``device_full`` chunk plane: ``access_batch`` routes whole
+        #: chunks through it, while scalar ``access`` (the host-resync path)
+        #: decides through the per-decision device plane.
         self._device_pipeline = None
         if device_plane:
-            self.admission_policy.bind_device_plane(self.main)
-            if data_plane == "device_full":
-                from repro.kernels.device_full import DeviceFullSimulationPlane
+            from repro.kernels.device_full import DeviceFullSimulationPlane
 
-                # the whole simulation step runs in the chunk scan; scalar
-                # ``access`` (the host-resync fallback path) decides through
-                # the per-decision device plane
-                self._device_pipeline = DeviceFullSimulationPlane(
-                    self.admission_policy._device, chunk=chunk)
-                self._admit = self.admission_policy.admit_device
-            else:
-                self._device_pipeline = self.admission_policy.bind_device_batch_plane(
-                    self.main, chunk=chunk)
-                self._admit = (
-                    self.admission_policy.admit_device_batch
-                    if data_plane == "device_batched"
-                    else self.admission_policy.admit_device
-                )
+            self._device_pipeline = DeviceFullSimulationPlane(
+                self.admission_policy.bind_device_plane(self.main), chunk=chunk)
+            self._admit = self.admission_policy.admit_device
         elif data_plane == "batched":
             self._admit = self.admission_policy.admit
         else:
@@ -241,9 +222,10 @@ class SizeAwareWTinyLFU:
     def __contains__(self, key: int) -> bool:
         pipe = self._device_pipeline
         if pipe is not None and pipe.needs_host_sync:
-            # a deferred chunk (or, under device_full, device-authoritative
-            # state) could flip membership: resolve before answering
-            pipe.sync(self)
+            # a launch in flight or device-authoritative state: the plane
+            # collects the launch and answers from the device's resident
+            # keys, leaving the state on the device
+            return pipe.contains(self, key)
         return key in self.window or key in self.main
 
     def used_bytes(self) -> int:
@@ -251,8 +233,8 @@ class SizeAwareWTinyLFU:
 
     # -- deferred-pipeline control ----------------------------------------
     def sync_deferred(self) -> None:
-        """Resolve any decisions the device-batched pipeline left queued or
-        in flight (no-op on host planes, or when nothing is deferred).
+        """Collect any ``device_full`` launch left in flight and restore
+        host authority (no-op on host planes, or when nothing is deferred).
         Host-view structures, membership, and stats are exact after this."""
         pipe = self._device_pipeline
         if pipe is not None and pipe.has_deferred_work:
@@ -301,7 +283,7 @@ class SizeAwareWTinyLFU:
         if pipe is not None and pipe.needs_host_sync:
             # scalar access reads/mutates the host dicts: restore host
             # authority first (device_full leaves it on device between
-            # chunks; device_batched may hold deferred decisions)
+            # chunks)
             pipe.sync(self)
         st = self.stats
         st.accesses += 1
@@ -330,11 +312,10 @@ class SizeAwareWTinyLFU:
         hot attributes hoisted out, and with the ``cms`` sketch backend the
         per-access increments are buffered and flushed through one batched
         Pallas kernel call fused with the next admission decision's victim
-        scoring. Under the device planes the chunk is handed straight to
-        the decision-batched pipeline, which defers admission decisions
-        and resolves them in batched ``lax.scan`` launches — still
-        byte-identical, with every buffered decision resolved (and stats
-        exact) by the time this returns.
+        scoring. Under ``device_full`` the chunk is handed straight to the
+        device plane, which resolves it in ``lax.scan`` launches — still
+        byte-identical, with stats exact by the time this returns unless
+        the plane's ``defer_collect`` leaves the last launch in flight.
         """
         if self._device_pipeline is not None:
             return self._device_pipeline.drive_chunk(self, keys, sizes)

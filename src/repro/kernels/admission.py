@@ -1,32 +1,41 @@
-"""Device-resident admission decision plane: sample -> score -> select.
+"""Admission decisions on the device: victim walks, verdict replay, and
+the per-decision plane.
 
-The PR-2/PR-3 data plane moved admission *scoring* onto the device (one
-fused CMS flush+estimate kernel per decision) but replayed every *decision*
-in host Python over the returned scores. This module closes the loop: the
-whole per-decision pipeline runs as ONE jitted device call and only the
-final verdict crosses back to the host:
+Every device program that decides which victims to evict reaches the
+same pieces here:
 
-    counter-RNG victim draws  ->  slot/key/size gather  ->  fused CMS
-    flush + estimate  ->  IV/QV/AV verdict replay  ->  victim selection
+* :func:`_freq_of` — a frequency estimate as a plain gather of the
+  flushed CMS table;
+* :func:`_sample_picker` — the sampled mains' next victim: the
+  counter-RNG pool draw (``repro.core.crng``'s splitmix64 stream,
+  reproduced with the uint32-limb helpers behind
+  ``kernels.cms.ops.counter_draws``), the eviction rule's score, and the
+  deterministic already-taken fallback scan;
+* :func:`_recency_select` — the LRU/SLRU mains' next victim: the oldest
+  live stamp, probation first for SLRU.
 
-returning ``(admit, victim slots/counts)``; the host applies the verdict
-to the (authoritative) eviction-policy structures. Per the TinyLFU
-observation, the sketch is the entire per-decision working set, so once the
-sketch table and a key/size table live on device there is nothing left for
-the host to supply mid-decision.
+On them sit the two walks, :func:`_walk_sampled_sel` and
+:func:`_walk_prefix_sel`, which replay the IV/QV/AV verdict with the
+per-discipline stop rule inside one ``lax.while_loop`` and record the
+eviction order as ``sel[slot] = order``. The chunk program of
+``data_plane="device_full"`` (:mod:`repro.kernels.device_full`) runs them
+in its scan body, its adaptive-window drain runs the picker and the
+selector, and the per-decision kernels below run the walks too.
 
-Two decision kernels cover the admission x eviction grid:
+:class:`DeviceAdmissionPlane` decides one admission at a time, as one
+jitted call that returns only the verdict; the host applies it to the
+(authoritative) eviction-policy structures. It is ``device_full``'s host
+path: scalar ``access`` and the boundary access of every sketch aging
+reset decide through it. Two decision kernels cover the admission x
+eviction grid:
 
 * ``_decide_sampled`` — sampling mains (``SampledEviction``/``Random``).
-  The module keeps a :class:`DeviceMirror` of the policy's slot-addressed
+  The plane keeps a :class:`DeviceMirror` of the policy's slot-addressed
   ``keys``/``sizes`` swap-remove table, maintained incrementally by the
   policy's insert/evict hooks (dirty slots land as a masked scatter inside
   the next decision call; the arrays themselves stay device-resident
-  between decisions). Victim selection replays the host walk exactly:
-  splitmix64 counter draws (``repro.core.crng`` stream, reproduced with the
-  uint32-limb helpers behind ``kernels.cms.ops.counter_draws``), per-step
-  best-of-``SAMPLE`` pools, the deterministic already-taken fallback scan,
-  and the per-discipline stop rule — all inside one ``lax.while_loop``.
+  between decisions). The sample walk's ``sel`` becomes the ordered
+  victim slots with one scatter.
 * ``_decide_prefix`` — deterministic-order mains (LRU/SLRU). Their victim
   order lives in host order dicts (control plane), so the host hands the
   covering victim prefix (``EvictionPolicy.peek_victims``) to the kernel,
@@ -34,20 +43,6 @@ Two decision kernels cover the admission x eviction grid:
   replays the IV/QV/AV verdict with masked prefix scans (cumulative sizes
   for QV's first-loss stop, cumulative frequencies for AV's early-pruning
   stop) — still one jitted call, no per-victim host round-trips.
-
-On top of the per-decision kernels, ``_decide_sampled_chunk`` batches a
-whole CHUNK of decisions per launch for the sampling mains (the
-``data_plane="device_batched"`` tentpole): a ``lax.scan`` speculatively
-unrolls the window->main cascade — per-decision pending-increment
-segments, the free-space check, the decision-counter advance (a 64-bit
-limb GAMMA add replaying ``begin_decision`` + ``crng.stream_key``), the
-shared sample walk, and the verdict's swap-remove/insert applied to the
-in-scan mirror so decision ``d+1`` draws against post-``d`` state. The
-host-side :class:`DeviceBatchedAdmissionPlane` drives access chunks,
-defers decisions while no interleaved access can observe a pending
-verdict, and resyncs speculation overruns (aging reset, oversized
-segment, victim-cap overflow, mirror growth mid-chunk) through the
-per-decision plane — byte-identity preserved throughout.
 
 Byte-identity with the scalar walk rests on the same arguments as the
 batched plane (see :mod:`repro.core.admission`): estimates are pure reads
@@ -80,12 +75,7 @@ from repro.core import crng
 from .cms.ops import _mix64_u32, _mul64_const, flush_scores
 from .cms.ref import row_indexes
 
-__all__ = [
-    "DeviceAdmissionPlane",
-    "DeviceBatchedAdmissionPlane",
-    "DeviceMirror",
-    "MAX_MIRROR_ENTRIES",
-]
+__all__ = ["DeviceAdmissionPlane", "DeviceMirror", "MAX_MIRROR_ENTRIES"]
 
 #: ``draw mod n`` is computed in uint32 8-bit Horner steps — exact for
 #: entry counts below 2**24 (16M cached objects).
@@ -154,58 +144,51 @@ def _argmin_frac(num, den, pos, valid):
     return pos[0]
 
 
-# The fused flush+score step (one Pallas launch, or the value-identical
-# scatter-add + gather) moved to the shared kernel-op layer so the segmented
-# decision-chunk path can reuse it: see ``repro.kernels.cms.ops.flush_scores``.
+def _freq_of(table, keys32):
+    """Frequency estimates as pure gathers of the flushed table — value-
+    identical to the estimate kernels."""
+    idx = row_indexes(keys32, table.shape[1])
+    return jnp.take_along_axis(table, idx, axis=1).min(0)
 
 
-def _sampled_walk(table, mkeys, msizes, n, cand_f, needed, base_hi, base_lo,
-                  *, discipline, rule, sample, early_pruning, vcap):
-    """The counter-RNG sample walk + IV/QV/AV verdict replay over the
-    current mirror state — the discipline core shared by the per-decision
-    kernel (``vcap = slots``: the victim buffer can never overflow) and the
-    decision-chunk scan (``vcap`` small and static; a decision that selects
-    more than ``vcap`` victims sets ``overflow`` so the host can resync it
-    through the per-decision path).
+# -- victim walks (pure: record a ``sel`` eviction order, mutate nothing) ----
 
-    Returns ``(admit, victims[vcap], n_evict, examined, fallbacks,
-    overflow)``; ``victims`` holds walk-time slots, writes beyond ``vcap``
-    are dropped.
-    """
-    slots = mkeys.shape[0]
-    width = table.shape[1]
+def _sample_picker(table, mk_lo, msz, n, needed, base_hi, base_lo, *, rule,
+                   sample):
+    """The sampled mains' victim picker over the first ``n`` slots:
+    returns ``next_victim(taken, step, fallbacks) -> (best, step + 1,
+    fallbacks')``. Step ``step`` draws its pool of ``sample`` slots from
+    the counter-RNG stream ``(base_hi, base_lo)`` (the device twin of
+    ``crng.draws``), scores the untaken ones by ``rule`` exactly as the
+    host ``SampledEviction._score`` orders them (ascending = evict first)
+    and takes the lowest; when every draw hit a taken slot, the
+    deterministic linear scan over all live slots picks instead and
+    ``fallbacks`` counts it."""
+    slots = mk_lo.shape[0]
     # The draw modulus: n >= 1 whenever a walk actually runs (needed > 0
     # implies a non-empty main); the clamp only guards masked-out scan
     # lanes from an integer mod-by-zero.
     n_mod = jnp.maximum(n, 1).astype(jnp.uint32)
 
-    def freq_of(keys_arr):
-        # estimates are plain gathers of the (flushed, device-resident)
-        # table — value-identical to the estimate kernels
-        idx = row_indexes(keys_arr, width)
-        return jnp.take_along_axis(table, idx, axis=1).min(0)
-
     def scores_of(slot_arr):
-        """``(num, den)`` fractions for the given slots, ordering exactly
-        like the host ``SampledEviction._score`` (ascending = evict first).
-        Scoring is per-pool, not per-table: a decision only ever touches
-        ~SAMPLE x steps slots, so the kernel must not do O(entries) sketch
-        work (the all-slot form runs only under the rare fallback scan)."""
-        sz = msizes[slot_arr]
+        """``(num, den)`` fractions for the given slots. Scoring is
+        per-pool, not per-table: a decision only ever touches ~SAMPLE x
+        steps slots, so the walk must not do O(entries) sketch work (the
+        all-slot form runs only under the rare fallback scan)."""
+        sz = msz[slot_arr]
         one = jnp.ones_like(sz)
         if rule == "frequency":
-            return freq_of(mkeys[slot_arr]), one
+            return _freq_of(table, mk_lo[slot_arr]), one
         if rule == "size":
             return -sz, one
         if rule == "frequency_size":
-            return freq_of(mkeys[slot_arr]), sz
+            return _freq_of(table, mk_lo[slot_arr]), sz
         if rule == "needed_size":
             return jnp.abs(sz - needed), one
         return jnp.zeros_like(sz), one  # random: constant, first draw wins
 
     iota = jnp.arange(slots, dtype=jnp.int32)
     in_use = iota < n
-
     pool_pad = _next_pow2(sample)
     pool_pos = jnp.arange(pool_pad, dtype=jnp.int32)
 
@@ -221,32 +204,60 @@ def _sampled_walk(table, mkeys, msizes, n, cand_f, needed, base_hi, base_lo,
             return raw[_argmin_frac(num, den, pool_pos, free)]
 
         def from_scan():
-            # every draw hit an already-taken slot: the deterministic
-            # linear-scan fallback over the full (fixed) slot view
             num, den = scores_of(iota)
             return _argmin_frac(num, den, iota, in_use & ~taken)
 
         best = jax.lax.cond(have, from_pool, from_scan)
         return best, step + jnp.int32(1), fallbacks + jnp.int32(~have)
 
+    return next_victim
+
+
+def _recency_select(mstamp, mseg, n, *, slru):
+    """The recency mains' victim order over the first ``n`` slots: returns
+    ``select(taken)``, the untaken slot with the oldest stamp — for SLRU
+    the probation segment (``mseg == 0``) first, then protected."""
+    live = jnp.arange(mstamp.shape[0], dtype=jnp.int32) < n
+
+    def select(taken):
+        cand_mask = live & ~taken
+        if slru:
+            prob = cand_mask & (mseg == 0)
+            mask = jnp.where(prob.any(), prob, cand_mask)
+        else:
+            mask = cand_mask
+        return jnp.argmin(jnp.where(mask, mstamp, _I32_MAX)).astype(jnp.int32)
+
+    return select
+
+
+def _walk_sampled_sel(table, mk_lo, msz, n, cand_f, needed, base_hi, base_lo,
+                      *, discipline, rule, sample, early_pruning):
+    """The counter-RNG sample walk + IV/QV/AV verdict replay over a
+    sampling main, recording selections into a full-width ``sel`` array
+    (``sel[slot] = selection order``). Returns ``(admit, sel, n_evict,
+    examined, fallbacks)``."""
+    slots = mk_lo.shape[0]
+    next_victim = _sample_picker(table, mk_lo, msz, n, needed, base_hi,
+                                 base_lo, rule=rule, sample=sample)
     z = jnp.int32(0)
     taken0 = jnp.zeros(slots, bool)
-    victims0 = jnp.full(vcap, -1, jnp.int32)
+    sel0 = jnp.full(slots, -1, jnp.int32)
     if discipline == "iv":
         # IV compares against the FIRST victim only: draw it up front and
         # gate the covering walk on a win, mirroring the scalar plane's RNG
         # pattern (no draws — hence no fallback scans — on a loss).
         first, step0, fb0 = next_victim(taken0, z, z)
-        win = cand_f >= freq_of(mkeys[first][None])[0]
-        init = (taken0.at[first].set(True), victims0.at[0].set(first),
-                jnp.int32(1), jnp.int32(1), msizes[first], z, z,
+        win = cand_f >= _freq_of(table, mk_lo[first][None])[0]
+        init = (taken0.at[first].set(True), sel0.at[first].set(0),
+                jnp.int32(1), jnp.int32(1), msz[first], z, z,
                 jnp.bool_(False), z, fb0, step0)
     else:
         win = None
-        init = (taken0, victims0, z, z, z, z, z, jnp.bool_(False), z, z, z)
+        init = (taken0, sel0, z, z, z, z, z, jnp.bool_(False), z, z, z)
 
     def cond(st):
-        taken, victims, g, count, covered, freed, vfreq, stopped, examined, fallbacks, step = st
+        taken, sel, g, count, covered, freed, vfreq, stopped, examined, fallbacks, step = st
         more = count < n
         if discipline == "iv":
             return more & win & (covered < needed)
@@ -255,36 +266,36 @@ def _sampled_walk(table, mkeys, msizes, n, cand_f, needed, base_hi, base_lo,
         return more & ~stopped & (covered < needed)
 
     def body(st):
-        taken, victims, g, count, covered, freed, vfreq, stopped, examined, fallbacks, step = st
+        taken, sel, g, count, covered, freed, vfreq, stopped, examined, fallbacks, step = st
         best, step, fallbacks = next_victim(taken, step, fallbacks)
         taken = taken.at[best].set(True)
         count = count + 1
-        s = msizes[best]
+        s = msz[best]
         if discipline != "iv":  # IV scores only its first victim (pre-loop)
-            f = freq_of(mkeys[best][None])[0]
+            f = _freq_of(table, mk_lo[best][None])[0]
         if discipline == "iv":
-            victims = victims.at[g].set(best, mode="drop")
+            sel = sel.at[best].set(g)
             g = g + 1
             covered = covered + s
         elif discipline == "qv":
             examined = examined + 1
-            win = cand_f >= f
-            victims = jnp.where(win, victims.at[g].set(best, mode="drop"), victims)
-            g = g + jnp.int32(win)
-            freed = freed + jnp.where(win, s, 0)
-            stopped = ~win
+            win_q = cand_f >= f
+            sel = jnp.where(win_q, sel.at[best].set(g), sel)
+            g = g + jnp.int32(win_q)
+            freed = freed + jnp.where(win_q, s, 0)
+            stopped = ~win_q
         else:
-            victims = victims.at[g].set(best, mode="drop")
+            sel = sel.at[best].set(g)
             g = g + 1
             covered = covered + s
             vfreq = vfreq + f
             examined = examined + 1
             if early_pruning:
                 stopped = cand_f < vfreq
-        return (taken, victims, g, count, covered, freed, vfreq, stopped,
+        return (taken, sel, g, count, covered, freed, vfreq, stopped,
                 examined, fallbacks, step)
 
-    (taken, victims, g, count, covered, freed, vfreq, stopped,
+    (taken, sel, g, count, covered, freed, vfreq, stopped,
      examined, fallbacks, step) = jax.lax.while_loop(cond, body, init)
 
     if discipline == "iv":
@@ -298,7 +309,109 @@ def _sampled_walk(table, mkeys, msizes, n, cand_f, needed, base_hi, base_lo,
         pruned = stopped | (covered < needed)
         admit = ~pruned & (cand_f >= vfreq)
         n_evict = jnp.where(admit, g, 0)
-    return admit, victims, n_evict, examined, fallbacks, g > jnp.int32(vcap)
+    return admit, sel, n_evict, examined, fallbacks
+
+
+def _walk_prefix_sel(table, mk_lo, msz, mstamp, mseg, n, cand_f, needed, tick,
+                     *, discipline, early_pruning, slru):
+    """IV/QV/AV verdict replay over the recency-order (LRU / SLRU
+    probation-first) victim walk — the device twin of
+    ``EvictionPolicy.peek_victims`` + ``_decide_prefix``, selecting by
+    ``argmin`` over live stamps instead of a host-gathered prefix.
+    Rejected-candidate promotions are applied to ``mstamp`` here, BEFORE
+    the eviction apply (safe: stamps travel with rows through swap-removes
+    and promoted entries are never evicted). Returns ``(admit, sel,
+    n_evict, examined, mstamp, tick)``."""
+    slots = mk_lo.shape[0]
+    select = _recency_select(mstamp, mseg, n, slru=slru)
+    z = jnp.int32(0)
+    taken0 = jnp.zeros(slots, bool)
+    sel0 = jnp.full(slots, -1, jnp.int32)
+
+    if discipline == "iv":
+        first = select(taken0)
+        admit = cand_f >= _freq_of(table, mk_lo[first][None])[0]
+
+        # gather the covering prefix unconditionally, mirroring the host's
+        # peek_victims (which gathers before the verdict); zero RNG/tick use
+        def cond(st):
+            taken, sel, g, covered = st
+            return (g < n) & (covered < needed)
+
+        def body(st):
+            taken, sel, g, covered = st
+            v = select(taken)
+            return (taken.at[v].set(True), sel.at[v].set(g), g + 1,
+                    covered + msz[v])
+
+        taken, sel, g, covered = jax.lax.while_loop(
+            cond, body, (taken0, sel0, z, z))
+        n_evict = jnp.where(admit, g, 0)
+        examined = jnp.int32(1)
+        # loss: promote the first victim (Alg. 4 line 14)
+        mstamp = mstamp.at[jnp.where(admit, slots, first)].set(tick, mode="drop")
+        tick = tick + jnp.int32(~admit)
+        return admit, sel, n_evict, examined, mstamp, tick
+
+    if discipline == "qv":
+        def cond(st):
+            taken, sel, g, count, freed, examined, stopped, loser = st
+            return (count < n) & ~stopped & (freed < needed)
+
+        def body(st):
+            taken, sel, g, count, freed, examined, stopped, loser = st
+            v = select(taken)
+            taken = taken.at[v].set(True)
+            f = _freq_of(table, mk_lo[v][None])[0]
+            win = cand_f >= f
+            sel = jnp.where(win, sel.at[v].set(g), sel)
+            g = g + jnp.int32(win)
+            freed = freed + jnp.where(win, msz[v], 0)
+            examined = examined + 1
+            loser = jnp.where(win, loser, v)
+            return (taken, sel, g, count + 1, freed, examined, ~win, loser)
+
+        init = (taken0, sel0, z, z, z, z, jnp.bool_(False), jnp.int32(slots))
+        (taken, sel, g, count, freed, examined, stopped,
+         loser) = jax.lax.while_loop(cond, body, init)
+        admit = freed >= needed
+        n_evict = g  # QV evictions stick on a reject
+        # reject: promote the loser (never evicted — it lost, so it was
+        # never selected)
+        mstamp = mstamp.at[jnp.where(admit, slots, loser)].set(tick, mode="drop")
+        tick = tick + jnp.int32(~admit)
+        return admit, sel, n_evict, examined, mstamp, tick
+
+    # AV: gather victims (and their frequency sum) until covered or pruned
+    def cond(st):
+        taken, sel, g, covered, vfreq, stopped = st
+        return (g < n) & ~stopped & (covered < needed)
+
+    def body(st):
+        taken, sel, g, covered, vfreq, stopped = st
+        v = select(taken)
+        taken = taken.at[v].set(True)
+        f = _freq_of(table, mk_lo[v][None])[0]
+        sel = sel.at[v].set(g)
+        g = g + 1
+        covered = covered + msz[v]
+        vfreq = vfreq + f
+        if early_pruning:
+            stopped = cand_f < vfreq
+        return (taken, sel, g, covered, vfreq, stopped)
+
+    init = (taken0, sel0, z, z, z, jnp.bool_(False))
+    taken, sel, g, covered, vfreq, stopped = jax.lax.while_loop(cond, body, init)
+    pruned = stopped | (covered < needed)
+    admit = ~pruned & (cand_f >= vfreq)
+    n_evict = jnp.where(admit, g, 0)
+    examined = g
+    # reject: promote every gathered victim in selection order (the prune
+    # point included) — one vectorized stamp write, ticks in sel order
+    promote = (~admit) & (sel >= 0)
+    mstamp = jnp.where(promote, tick + sel, mstamp)
+    tick = tick + jnp.where(admit, 0, g)
+    return admit, sel, n_evict, examined, mstamp, tick
 
 
 # -- decision kernels --------------------------------------------------------
@@ -314,9 +427,9 @@ def _decide_sampled(table, mkeys, msizes, wr_slots, wr_keys, wr_sizes,
                     use_pallas, interpret):
     """One whole admission decision over a sampling main, on device.
 
-    Mirror scatter -> fused CMS flush + candidate estimate -> counter-RNG
-    sample walk (``lax.while_loop``; each step gathers and scores only its
-    drawn pool) with the per-discipline stop rule -> verdict. Returns
+    Mirror scatter -> fused CMS flush + candidate estimate ->
+    :func:`_walk_sampled_sel` (each step gathers and scores only its drawn
+    pool) -> verdict, its ``sel`` order scattered into ``victims``. Returns
     ``(table, mkeys, msizes, admit, victims, n_evict, examined,
     fallbacks)``; ``victims[:n_evict]`` are decision-time slots.
     """
@@ -326,12 +439,14 @@ def _decide_sampled(table, mkeys, msizes, wr_slots, wr_keys, wr_sizes,
     cand = jnp.asarray(cand_key, jnp.int32).reshape(1)
     table, est = flush_scores(table, upd_keys, n_pend, cand,
                               cap=cap, use_pallas=use_pallas, interpret=interpret)
-    # vcap = slots: the per-decision victim buffer covers the whole mirror,
-    # so the overflow flag is statically unreachable here.
-    admit, victims, n_evict, examined, fallbacks, _ = _sampled_walk(
+    admit, sel, n_evict, examined, fallbacks = _walk_sampled_sel(
         table, mkeys, msizes, n, est[0], needed, base_hi, base_lo,
         discipline=discipline, rule=rule, sample=sample,
-        early_pruning=early_pruning, vcap=slots)
+        early_pruning=early_pruning)
+    # sel[slot] = order  ->  victims[order] = slot (unselected slots drop)
+    victims = jnp.full(slots, -1, jnp.int32).at[
+        jnp.where(sel >= 0, sel, slots)].set(
+        jnp.arange(slots, dtype=jnp.int32), mode="drop")
     return table, mkeys, msizes, admit, victims, n_evict, examined, fallbacks
 
 
@@ -388,153 +503,6 @@ def _decide_prefix(table, vkeys, vsizes, m, upd_keys, n_pend, cand_key, needed,
     return table, admit, n_evict, g, examined, has_loser
 
 
-# -- decision-batched kernel (speculative window-cascade unrolling) ----------
-
-_GAMMA_HI = jnp.uint32(crng.GAMMA >> 32)
-_GAMMA_LO = jnp.uint32(crng.GAMMA & 0xFFFFFFFF)
-
-
-def _apply_verdict(mkeys, msizes, n, used, victims, n_evict, admit, cand, size, vcap):
-    """Replay one decision's verdict onto the in-scan mirror state: the
-    host's swap-remove evictions (in selection order, with the back-fill
-    slot remap the host's ``pos`` dict performs implicitly) followed by the
-    candidate insert on an admit. This is what lets decision ``d+1``'s
-    draws see exactly the slot layout the host will have after applying
-    decision ``d`` — the speculation that makes chunking sound."""
-    drop = jnp.int32(mkeys.shape[0])  # OOB sentinel: scatter lanes dropped
-
-    def evict_one(j, st):
-        mkeys, msizes, n, used, victims = st
-        act = j < n_evict
-        s = victims[j]
-        last = n - 1
-        lk = mkeys[last]
-        ls = msizes[last]
-        vsz = msizes[s]
-        tgt = jnp.where(act, s, drop)
-        mkeys = mkeys.at[tgt].set(lk, mode="drop")
-        msizes = msizes.at[tgt].set(ls, mode="drop")
-        used = used - jnp.where(act, vsz, 0)
-        n = n - act.astype(n.dtype)
-        # a later victim recorded at the (old) last slot now lives at s
-        pos = jnp.arange(vcap, dtype=jnp.int32)
-        victims = jnp.where(act & (pos > j) & (victims == last), s, victims)
-        return mkeys, msizes, n, used, victims
-
-    mkeys, msizes, n, used, victims = jax.lax.fori_loop(
-        0, vcap, evict_one, (mkeys, msizes, n, used, victims))
-    tgt = jnp.where(admit, n, drop)
-    mkeys = mkeys.at[tgt].set(cand, mode="drop")
-    msizes = msizes.at[tgt].set(size, mode="drop")
-    used = used + jnp.where(admit, size, 0)
-    n = n + admit.astype(n.dtype)
-    return mkeys, msizes, n, used
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("discipline", "rule", "sample", "early_pruning", "cap",
-                     "use_pallas", "interpret", "vcap"),
-    # steady-state chunks update the same sketch/mirror state they read:
-    # donating those buffers lets XLA alias them in place of a fresh
-    # allocation per launch (the dispatch path adopts the outputs
-    # immediately, so the stale inputs are never touched again)
-    donate_argnums=(0, 1, 2),
-)
-def _decide_sampled_chunk(table, mkeys, msizes, wr, upd, meta, scal, key_limbs,
-                          *, discipline, rule, sample, early_pruning, cap,
-                          use_pallas, interpret, vcap):
-    """A whole CHUNK of admission decisions over a sampling main, as ONE
-    jitted call: ``lax.scan`` speculatively unrolls the window->main
-    admission cascade, each decision's verdict feeding the next through
-    masked in-scan mirror updates.
-
-    Per scanned decision: apply its pending-increment *segment* (the
-    accesses between it and the previous decision) through the fused
-    flush+score step, replay the free-space check (``needed <= 0`` admits
-    without a decision — no counter bump, no draws), otherwise advance the
-    decision counter (a 64-bit limb add of GAMMA to the unmixed stream
-    key — bit-identical to ``begin_decision`` + ``crng.stream_key``), run
-    the shared sample walk, and replay the verdict onto the in-scan
-    key/size mirror so the next decision draws against post-verdict state.
-
-    Speculation depth: a decision selecting more than ``vcap`` victims
-    cannot be applied in-scan; it and every later decision in the chunk
-    report ``ok=False`` (the *poisoned* suffix — its own segment flush has
-    already landed, its mirror/counter effects have not), and the host
-    resyncs it through the per-decision plane.
-
-    Arguments are packed to minimize per-launch host->device transfers
-    (dispatch amortization is the whole point): ``wr`` is the mirror's
-    ``[3, PAD]`` dirty-scatter block (slots/keys/sizes rows), ``upd`` the
-    ``[B, P]`` increment segments, ``meta`` ``[B, 4]`` int32 rows of
-    ``(cand_key, cand_size, n_pend, valid)``, ``scal`` ``[3]`` int32
-    ``(n, used, main_cap)`` and ``key_limbs`` ``[2]`` uint32 — the unmixed
-    decision-stream key. Returns ``(table, mkeys, msizes, out, victims)``
-    where ``out`` is ``[B, 6]`` int32 rows of ``(ok, admit, free_insert,
-    n_evict, examined, fallbacks)`` and ``victims`` ``[B, vcap]``
-    decision-time slots (the host resolves them against its own state
-    while applying the verdict vector in one pass).
-    """
-    mkeys = mkeys.at[wr[0]].set(wr[1], mode="drop")
-    msizes = msizes.at[wr[0]].set(wr[2], mode="drop")
-    n, used, main_cap = scal[0], scal[1], scal[2]
-    key_hi, key_lo = key_limbs[0], key_limbs[1]
-    z = jnp.int32(0)
-
-    def step(carry, x):
-        table, mkeys, msizes, n, used, khi, klo, poisoned = carry
-        meta_row, upd_row = x
-        cand, size, np_row = meta_row[0], meta_row[1], meta_row[2]
-        v = meta_row[3] > z
-        run = v & ~poisoned
-        table, est = flush_scores(
-            table, upd_row, jnp.where(run, np_row, 0), cand.reshape(1),
-            cap=cap, use_pallas=use_pallas, interpret=interpret)
-        cand_f = est[0]
-        needed = size - (main_cap - used)
-        is_free = needed <= z
-        walk = run & ~is_free
-
-        # begin_decision: bump the unmixed stream key by GAMMA (64-bit limb
-        # add; mix13 of the bumped key == crng.stream_key(seed, decision+1))
-        nlo = klo + _GAMMA_LO
-        nhi = khi + _GAMMA_HI + (nlo < klo).astype(jnp.uint32)
-        khi = jnp.where(walk, nhi, khi)
-        klo = jnp.where(walk, nlo, klo)
-        base_hi, base_lo = _mix64_u32(khi, klo)
-
-        def do_walk(_):
-            return _sampled_walk(
-                table, mkeys, msizes, n, cand_f, needed, base_hi, base_lo,
-                discipline=discipline, rule=rule, sample=sample,
-                early_pruning=early_pruning, vcap=vcap)
-
-        def no_walk(_):
-            return (jnp.bool_(False), jnp.full(vcap, -1, jnp.int32), z, z, z,
-                    jnp.bool_(False))
-
-        admit_w, victims, n_evict, examined, fallbacks, overflow = jax.lax.cond(
-            walk, do_walk, no_walk, None)
-
-        ok = run & ~overflow
-        admit = jnp.where(is_free, run, admit_w & ok)
-        app_evict = jnp.where(ok, n_evict, z)  # QV evictions stick on reject
-        mkeys, msizes, n, used = _apply_verdict(
-            mkeys, msizes, n, used, victims, app_evict, admit & ok,
-            cand, size, vcap)
-        poisoned = poisoned | (run & overflow)
-        out_row = jnp.stack([ok.astype(jnp.int32), admit.astype(jnp.int32),
-                             (is_free & run).astype(jnp.int32), n_evict,
-                             examined, fallbacks])
-        return (table, mkeys, msizes, n, used, khi, klo, poisoned), (out_row, victims)
-
-    init = (table, mkeys, msizes, n, used, key_hi, key_lo, jnp.bool_(False))
-    (table, mkeys, msizes, n, used, khi, klo, poisoned), (out, victims) = jax.lax.scan(
-        step, init, (meta, upd))
-    return table, mkeys, msizes, out, victims
-
-
 # -- host-side plane ---------------------------------------------------------
 
 class DeviceMirror:
@@ -559,16 +527,13 @@ class DeviceMirror:
         self.max_size = int(max_size)
         self._dirty: set[int] = set()
         self._dev: "tuple | None" = None
-        self._applied = False  # writes already landed on device (chunk apply)
         self.uploads = 0  # full re-uploads (observability for tests)
 
-    def ensure_capacity(self, slots: int) -> bool:
-        """Grow the slot table to hold at least ``slots`` entries; returns
-        True when it grew (shape change: full upload next decision). The
-        decision-batched plane calls this pre-flight so an in-scan insert
-        can never land past the device arrays mid-chunk."""
+    def ensure_capacity(self, slots: int) -> None:
+        """Grow the slot table to hold at least ``slots`` entries (a shape
+        change: full upload next decision)."""
         if slots <= self._cap:
-            return False
+            return
         grow = self._cap
         while slots > grow:
             grow <<= 1
@@ -578,13 +543,12 @@ class DeviceMirror:
         sizes[: self._cap] = self._sizes
         self._keys, self._sizes, self._cap = keys, sizes, grow
         self._dev = None
-        return True
 
     def load(self, keys, sizes_by_key) -> None:
         """Bulk (re)load of the whole slot table — the batched twin of
-        per-slot :meth:`record` used by ``SampledEviction.attach_mirror``
-        when the policy already holds entries: one vectorized fill + one
-        full upload instead of len(keys) dirty-slot records."""
+        per-slot :meth:`record`, which ``SampledEviction.attach_mirror`` and
+        ``load_rows`` call: one vectorized fill + one full upload instead
+        of len(keys) dirty-slot records."""
         n = len(keys)
         self.ensure_capacity(n)
         if n:
@@ -600,18 +564,6 @@ class DeviceMirror:
         self._dirty.clear()
         self._dev = None  # full upload next decision
 
-    def begin_applied(self) -> None:
-        """Enter chunk-apply mode: the decision kernel has already applied
-        the upcoming writes to the device arrays in-scan (the host apply
-        pass replays the same evict/insert sequence), so :meth:`record`
-        keeps the host copy authoritative but skips dirty-marking —
-        re-scattering identical values per decision would blow the scatter
-        budget and force a full re-upload every chunk."""
-        self._applied = True
-
-    def end_applied(self) -> None:
-        self._applied = False
-
     def record(self, slot: int, key: int, size: int) -> None:
         if size > self.max_size:
             raise ValueError(
@@ -622,12 +574,12 @@ class DeviceMirror:
             self.ensure_capacity(slot + 1)
         self._keys[slot] = key & 0xFFFFFFFF
         self._sizes[slot] = size
-        if not self._applied:
-            self._dirty.add(slot)
+        self._dirty.add(slot)
 
-    def _sync(self):
-        """Resident arrays + the ``[3, _WRITE_PAD]`` dirty-scatter block
-        (slots/keys/sizes rows; pad slots point past the arrays and drop)."""
+    def device_state(self):
+        """``(keys, sizes, wr_slots, wr_keys, wr_sizes)`` for one decision:
+        the resident arrays and the dirty-slot scatter (pad slots point
+        past the arrays and drop)."""
         if self._dev is None or len(self._dirty) > _WRITE_PAD:
             self._dev = (
                 jnp.asarray(self._keys.astype(np.int32)),
@@ -643,18 +595,7 @@ class DeviceMirror:
             wr[2, j] = self._sizes[slot]
         self._dirty.clear()
         dk, ds = self._dev
-        return dk, ds, wr
-
-    def device_state(self):
-        """``(keys, sizes, wr_slots, wr_keys, wr_sizes)`` for one decision."""
-        dk, ds, wr = self._sync()
         return dk, ds, jnp.asarray(wr[0]), jnp.asarray(wr[1]), jnp.asarray(wr[2])
-
-    def device_state_packed(self):
-        """``(keys, sizes, wr[3, PAD])`` — the decision-chunk kernel's
-        one-upload form of :meth:`device_state`."""
-        dk, ds, wr = self._sync()
-        return dk, ds, jnp.asarray(wr)
 
     def accept(self, dev_keys, dev_sizes) -> None:
         """Adopt the kernel's post-scatter arrays as the resident copy."""
@@ -662,7 +603,8 @@ class DeviceMirror:
 
 
 class DeviceAdmissionPlane:
-    """The ``data_plane="device"`` engine behind one admission discipline.
+    """The per-decision device decider behind one admission discipline:
+    ``device_full``'s host path.
 
     Binds a CMS sketch and a Main eviction policy; :meth:`decide` runs the
     closed sample->score->select loop as one jitted call and applies the
@@ -812,451 +754,3 @@ class DeviceAdmissionPlane:
             return True
         stats.rejections += 1
         return False
-
-
-class _InFlightChunk:
-    """A dispatched-but-uncollected chunk launch: the queue slice it
-    covers plus the (possibly still computing) device arrays. Holding
-    un-materialized jax arrays here is what lets the chunk resolve on
-    device while the host gathers the next batch of accesses."""
-
-    __slots__ = ("q", "b_last", "table", "mkeys", "msizes", "out", "victims")
-
-    def __init__(self, *, q, b_last, table, mkeys, msizes, out, victims):
-        self.q = q
-        self.b_last = b_last
-        self.table = table
-        self.mkeys = mkeys
-        self.msizes = msizes
-        self.out = out
-        self.victims = victims
-
-
-class DeviceBatchedAdmissionPlane:
-    """``data_plane="device_batched"``: amortize kernel dispatch over a
-    CHUNK of admission decisions.
-
-    The per-decision :class:`DeviceAdmissionPlane` (PR 4) proved the
-    closed-loop semantics but launches one jitted call per decision, so
-    dispatch — not the kernel — dominates throughput. This plane drives a
-    whole access chunk on the host (hit/miss bookkeeping, the Alg. 1 window
-    cascade), *defers* the main-cache admission decisions it generates into
-    a buffer, and resolves the buffer with ONE
-    :func:`_decide_sampled_chunk` launch that speculatively unrolls the
-    cascade in a ``lax.scan`` — per-decision pending-increment segments,
-    the free-space check, decision-counter advance, sample walk, and
-    verdict application to the in-scan mirror all on device. The host then
-    applies the verdict vector in one pass.
-
-    Deferring is only sound while no interleaved access can observe a
-    pending verdict, so the drive loop **flushes** the buffer before:
-
-    * an access that hits the host-view Main (a pending decision might
-      have evicted that key) or touches a pending candidate key (its
-      hit/miss status IS the pending verdict);
-    * ``_maybe_adapt`` under the adaptive window (it drains against live
-      Main state);
-    * the end of every ``access_batch`` call (engine snapshots must read
-      exact stats — see ``SimulationEngine``'s chunk-splitting contract).
-
-    Window hits never flush: pending decisions cannot touch the Window.
-
-    Speculation limits resync through the per-decision plane (counted in
-    ``resyncs`` / ``resync_reasons``, byte-identity preserved):
-
-    * ``aging``  — the chunk's increments would cross the sketch's reset
-      boundary (the per-decision path stages the boundary-splitting
-      ``flush()`` exactly like the other planes);
-    * ``flush_block`` — a single decision's segment outgrew the fused-
-      flush memory budget;
-    * ``victim_cap`` — a decision selected more than ``victim_cap``
-      victims, poisoning the chunk suffix in-kernel;
-    * ``mirror_grow`` — the chunk's worst-case inserts would overflow the
-      device mirror, forcing a grow + full re-upload pre-flight.
-
-    Deterministic-order mains (LRU/SLRU) keep their covering-prefix walk
-    in host order dicts, so every decision resolves immediately through
-    the per-decision prefix kernel — same spec surface, batching engages
-    on the mirror-slot (sampled/random) mains.
-    """
-
-    def __init__(self, device: DeviceAdmissionPlane, *, chunk: int = 64,
-                 victim_cap: int = 16):
-        if chunk < 1:
-            raise ValueError("device_batched chunk must be >= 1")
-        self.device = device
-        self.sketch = device.sketch
-        self.main = device.main
-        self.mirror = device.mirror
-        self.sampled = device.sampled
-        self.chunk = int(chunk)
-        #: Static per-decision victim budget of the scan kernel; decisions
-        #: needing more resync through the per-decision plane.
-        self.victim_cap = int(victim_cap)
-        self.chunk_calls = 0  # chunk-kernel launches
-        self.decisions = 0  # decisions resolved through this plane
-        self.batched_decisions = 0  # ... resolved inside a chunk kernel
-        self.flushes = 0  # buffer flushes (any size, incl. size-1)
-        self.resyncs = 0  # host-resync fallbacks, by reason below
-        self.resync_reasons = {"aging": 0, "flush_block": 0,
-                               "victim_cap": 0, "mirror_grow": 0}
-        #: When True the trailing end-of-``access_batch`` flush dispatches
-        #: the chunk kernel but does NOT block on its result: the chunk
-        #: resolves on device while the caller gathers the next batch of
-        #: accesses (JAX async dispatch). Stats and host structures are
-        #: exact only after :meth:`sync`; the drive loop's visibility
-        #: triggers (main hit / pending-candidate touch) still force a
-        #: collect, so hit/miss answers stay byte-identical. Set by the
-        #: serving-layer async admission pipeline.
-        self.defer_collect = False
-        self.deferred_dispatches = 0  # chunk launches left in flight
-        self._queue: list[tuple[int, int, int]] = []  # (key, size, boundary)
-        self._pending_keys: set[int] = set()
-        self._inflight: "_InFlightChunk | None" = None
-
-    # -- the chunked drive loop -------------------------------------------
-    def drive_chunk(self, pol, keys, sizes) -> np.ndarray:
-        """Drive one access chunk for ``pol`` (the owning
-        ``SizeAwareWTinyLFU``) — observationally identical to its scalar
-        ``access`` loop, with admission decisions batched per launch."""
-        n = len(keys)
-        hits = np.empty(n, dtype=bool)
-        keys = keys.tolist() if hasattr(keys, "tolist") else list(keys)
-        sizes = sizes.tolist() if hasattr(sizes, "tolist") else list(sizes)
-        st = pol.stats
-        window = pol.window
-        main = self.main
-        increment = self.sketch.increment
-        adaptive = pol.adaptive_window
-        pending_keys = self._pending_keys
-        for i in range(n):
-            key = keys[i]
-            size = sizes[i]
-            st.accesses += 1
-            st.bytes_requested += size
-            increment(key)
-            if key in window:
-                window.move_to_end(key)
-                st.hits += 1
-                st.bytes_hit += size
-                hits[i] = True
-                continue
-            if pending_keys and (key in pending_keys or key in main):
-                # a pending verdict could flip this access's hit/miss
-                # status (victim eviction / candidate admission): resolve
-                # the buffer, then re-read Main
-                self._flush(pol)
-            if key in main:
-                main.on_access(key)
-                st.hits += 1
-                st.bytes_hit += size
-                hits[i] = True
-                continue
-            hits[i] = False
-            self._on_miss(pol, key, size)
-            if adaptive:
-                self._flush(pol)
-                pol._maybe_adapt()
-        # exact-stats contract: resolve everything before returning —
-        # unless the owner opted into deferred collection, in which case
-        # the trailing chunk is dispatched and left resolving on device
-        self._flush(pol, defer=self.defer_collect)
-        return hits
-
-    @property
-    def has_deferred_work(self) -> bool:
-        """True while decisions are queued or a chunk is in flight."""
-        return bool(self._queue) or self._inflight is not None
-
-    #: the owning policy consults this before host-structure reads (scalar
-    #: ``access``, ``__contains__``); this plane never lets host structures
-    #: go stale beyond the deferred decisions, so the two are the same
-    needs_host_sync = has_deferred_work
-
-    def sync(self, pol) -> None:
-        """Resolve every deferred decision — queued and in flight. After
-        this, host structures and ``pol.stats`` are exact."""
-        self._flush(pol)
-
-    def _on_miss(self, pol, key: int, size: int) -> None:
-        """Alg. 1 miss cascade, decisions deferred into the buffer."""
-        if size > pol.capacity:  # line 2: can never fit
-            pol.stats.rejections += 1
-            return
-        if size > pol.window_cap:
-            # line 6: too large for the Window -> direct Main candidate
-            self._enqueue(pol, key, size)
-            return
-        window = pol.window
-        window[key] = size
-        pol.window_bytes += size
-        while pol.window_bytes > pol.window_cap:  # lines 9-11
-            vk, vs = window.popitem(last=False)
-            pol.window_bytes -= vs
-            self._enqueue(pol, vk, vs)
-
-    def _enqueue(self, pol, key: int, size: int) -> None:
-        st = pol.stats
-        if size > pol.main_cap:
-            st.rejections += 1
-            return
-        sk = self.sketch
-        if (not self.sampled or pol.main_cap > _I32_MAX
-                or size > self.device.max_size):
-            # prefix mains (and shapes past the kernel's int32 bounds)
-            # resolve per decision through the covering-prefix kernel
-            self._flush(pol)
-            self._execute_now(pol, key, size)
-            return
-        boundary = len(sk._pending)
-        # the previous decision's boundary may live in the in-flight chunk
-        # (sk._pending is only sliced at collect, so boundaries recorded
-        # before and after a deferred dispatch share one offset space)
-        if self._queue:
-            prev = self._queue[-1][2]
-        elif self._inflight is not None:
-            prev = self._inflight.b_last
-        else:
-            prev = 0
-        if boundary - prev > sk.flush_block or sk._ops + boundary >= sk.sample_size:
-            # speculation depth exceeded: an aging reset lands inside the
-            # chunk (or one segment outgrew the fused-flush budget) —
-            # resync through the per-decision plane, whose staged
-            # ``sketch.flush()`` splits at the reset boundary exactly like
-            # the host planes (ops + boundary == the scalar plane's
-            # ops + npend at this decision, so the trigger point matches)
-            self._flush(pol)
-            self.resyncs += 1
-            self.resync_reasons[
-                "flush_block" if boundary - prev > sk.flush_block else "aging"
-            ] += 1
-            self._execute_now(pol, key, size)
-            return
-        self._queue.append((key, size, boundary))
-        self._pending_keys.add(key)
-        if len(self._queue) >= self.chunk:
-            self._flush(pol)
-
-    def _execute_now(self, pol, key: int, size: int) -> None:
-        """One decision through the per-decision plane — the host-resync
-        path, byte-identical to ``SizeAwareWTinyLFU._evict_or_admit``."""
-        main = self.main
-        st = pol.stats
-        free = pol.main_cap - main.used
-        if free >= size:
-            main.insert(key, size)
-            st.admissions += 1
-        else:
-            main.begin_decision()
-            self.device.decide(key, size, size - free, main, st)
-        self.decisions += 1
-
-    # -- buffer resolution -------------------------------------------------
-    def _rebuild_pending(self) -> None:
-        """Recompute the pending-candidate key set from the queue and the
-        in-flight chunk, mutating the live set in place (the drive loop
-        holds a reference to it)."""
-        pk = {k for k, _, _ in self._queue}
-        if self._inflight is not None:
-            pk.update(k for k, _, _ in self._inflight.q)
-        self._pending_keys.clear()
-        self._pending_keys.update(pk)
-
-    def _flush(self, pol, defer: bool = False) -> None:
-        """Resolve every buffered decision: one chunk-kernel launch per
-        iteration, applying the ok-prefix and resyncing a poisoned
-        (victim-cap overflow) decision through the per-decision plane.
-
-        With ``defer=True`` the last chunk launch is left IN FLIGHT: its
-        device arrays are not materialized and its verdicts are not yet
-        applied to the host structures. The next ``_flush`` (or
-        :meth:`sync`) collects it first — chunk N resolves on device while
-        chunk N+1's accesses are gathered."""
-        if defer and not self._queue:
-            return  # nothing new to resolve; leave any in-flight chunk be
-        self._collect(pol)
-        if not self._queue:
-            return
-        self.flushes += 1
-        while self._queue:
-            q = self._queue
-            self._queue = []
-            if self.sampled:
-                n0 = len(self.main.keys)
-                if n0 + len(q) >= MAX_MIRROR_ENTRIES:
-                    raise ValueError(
-                        f"device plane supports < {MAX_MIRROR_ENTRIES} "
-                        f"entries, got {n0} (+{len(q)} queued)"
-                    )
-                if self.mirror.ensure_capacity(n0 + len(q)):
-                    # mirror overflow mid-chunk: worst case every queued
-                    # decision admits — grow + full upload pre-flight so no
-                    # in-scan (or applied) insert can land past the arrays
-                    self.resyncs += 1
-                    self.resync_reasons["mirror_grow"] += 1
-            if len(q) == 1:
-                # a batch of one: the per-decision kernel is the cheaper
-                # launch (no scan machinery), byte-identical by definition.
-                # Hide the post-decision increment tail so its estimates
-                # see exactly the decision-time sketch state.
-                key, size, b = q[0]
-                sk = self.sketch
-                saved = sk._pending[b:]
-                sk._pending = sk._pending[:b]
-                self._execute_now(pol, key, size)
-                sk._pending = sk._pending + saved
-                continue
-            self._inflight = self._dispatch(pol, q)
-            if defer:
-                self.deferred_dispatches += 1
-                break
-            self._collect(pol)  # blocks; may re-buffer a poisoned suffix
-        self._rebuild_pending()
-
-    def _collect(self, pol) -> None:
-        """Materialize the in-flight chunk (blocking on the device result)
-        and apply its verdicts. A poisoned (victim-cap overflow) decision
-        resyncs through the per-decision plane and the untouched suffix is
-        re-buffered AHEAD of any newer queued decisions, all boundaries
-        rebased onto the sliced pending list."""
-        if self._inflight is None:
-            return
-        inf, self._inflight = self._inflight, None
-        okn = self._apply(pol, inf)
-        q = inf.q
-        # decisions enqueued while the chunk was in flight recorded
-        # boundaries into the pre-slice pending list; _apply sliced off
-        # applied_b (== b_last, or the poisoned decision's own boundary),
-        # so every surviving boundary rebases by that amount
-        applied_b = q[okn][2] if okn < len(q) else inf.b_last
-        suffix = []
-        if okn < len(q):
-            key, size, b = q[okn]
-            sk = self.sketch
-            saved = sk._pending
-            sk._pending = []
-            self.resyncs += 1
-            self.resync_reasons["victim_cap"] += 1
-            self._execute_now(pol, key, size)
-            sk._pending = saved
-            suffix = [(k, s, bb - applied_b) for k, s, bb in q[okn + 1:]]
-        self._queue = suffix + [(k, s, bb - applied_b) for k, s, bb in self._queue]
-        self._rebuild_pending()
-
-    def _statics(self) -> dict:
-        """The static kwargs of `_decide_sampled_chunk` for this plane."""
-        sk, main, dev = self.sketch, self.main, self.device
-        return dict(discipline=dev.discipline, rule=main.rule,
-                    sample=main.SAMPLE, early_pruning=dev.early_pruning,
-                    cap=sk.cap, use_pallas=sk.use_pallas,
-                    interpret=dev._interpret, vcap=self.victim_cap)
-
-    def _dispatch(self, pol, q) -> "_InFlightChunk":
-        """One `_decide_sampled_chunk` launch over ``q`` — host-side prep
-        plus the (async) kernel call, WITHOUT materializing the result.
-        Pair with :meth:`_apply`."""
-        sk = self.sketch
-        main = self.main
-        n0 = len(main.keys)
-        nq = len(q)
-        b_last = q[-1][2]
-        pend = sk._pending
-        # B pads the queue to a power of two (scan steps are real work even
-        # when masked, so the scan length tracks the actual batch); P pads
-        # the widest segment to a coarse bucket — both keep the jit cache
-        # small (log-many variants) across launches.
-        B = _next_pow2(nq)
-        max_seg = 0
-        prevb = 0
-        for _, _, b in q:
-            max_seg = max(max_seg, b - prevb)
-            prevb = b
-        P = 16
-        while P < max_seg:
-            P <<= 3  # buckets 16, 128, 1024 (<= flush_block guard)
-        upd = np.zeros((B, P), np.int32)
-        meta = np.zeros((B, 4), np.int32)  # cand, size, n_pend, valid
-        prevb = 0
-        for i, (k, s, b) in enumerate(q):
-            seg = pend[prevb:b]
-            prevb = b
-            if seg:
-                meta[i, 2] = len(seg)
-                upd[i, : len(seg)] = np.asarray(seg, np.int64).astype(np.int32)
-            meta[i, 0] = _key32(k)
-            meta[i, 1] = s
-            meta[i, 3] = 1
-        # unmixed stream key of the CURRENT counter; each in-scan decision
-        # bumps by GAMMA before mixing, replaying begin_decision exactly
-        key0 = (main.seed * crng.GOLDEN + main.decision * crng.GAMMA) & ((1 << 64) - 1)
-        scal = np.asarray([n0, main.used, pol.main_cap], np.int32)
-        key_limbs = np.asarray([key0 >> 32, key0 & 0xFFFFFFFF], np.uint32)
-        mkeys, msizes, wr = self.mirror.device_state_packed()
-        table, mkeys, msizes, out, victims = _decide_sampled_chunk(
-            sk.table, mkeys, msizes, wr, jnp.asarray(upd), jnp.asarray(meta),
-            jnp.asarray(scal), jnp.asarray(key_limbs), **self._statics())
-        self.chunk_calls += 1
-        # adopt the (async) output buffers NOW: the inputs were donated to
-        # the launch and must not be read again. The scan masks segment
-        # flushes and mirror writes past a poisoned decision, so the
-        # adopted arrays are exact regardless of where the ok-prefix ends.
-        sk.table = table
-        self.mirror.accept(mkeys, msizes)
-        return _InFlightChunk(q=q, b_last=b_last, table=table, mkeys=mkeys,
-                              msizes=msizes, out=out, victims=victims)
-
-    def _apply(self, pol, inf: "_InFlightChunk") -> int:
-        """Blocking tail of a chunk launch: materialize the verdict vector
-        (this is where JAX async dispatch makes us wait for the device),
-        commit the sketch, adopt the mirror arrays, and replay the
-        ok-prefix verdicts on the host structures. Returns ok_count."""
-        sk = self.sketch
-        main = self.main
-        q = inf.q
-        nq = len(q)
-        out = np.asarray(inf.out)  # [B, 6]: ok, admit, free, n_evict, examined, fallbacks
-        victims = inf.victims
-        mkeys, msizes = inf.mkeys, inf.msizes
-        ok = out[:, 0]
-        okn = 0
-        while okn < nq and ok[okn]:
-            okn += 1
-        # commit the sketch through the last in-kernel-flushed segment: the
-        # ok-prefix plus, when poisoned, the overflowing decision's own
-        applied_b = q[okn][2] if okn < nq else inf.b_last
-        # sketch table + mirror arrays were adopted at dispatch (the launch
-        # donated the old buffers); commit the host-side flush accounting,
-        # then replay the verdict vector on the host structures with
-        # dirty-marking suppressed (the scan already performed these exact
-        # slot writes)
-        sk._ops += applied_b
-        sk._pending = sk._pending[applied_b:]
-        victims = np.asarray(victims)
-        st = pol.stats
-        self.mirror.begin_applied()
-        try:
-            for i in range(okn):
-                key, size, _ = q[i]
-                _, admit, free_ins, n_evict, examined, fallbacks = out[i]
-                st.victims_examined += int(examined)
-                main.fallback_scans += int(fallbacks)
-                if free_ins:
-                    main.insert(key, size)
-                    st.admissions += 1
-                else:
-                    main.begin_decision()
-                    evict_keys = [main.keys[int(sl)]
-                                  for sl in victims[i][: int(n_evict)]]
-                    for v in evict_keys:
-                        main.evict(v)
-                        st.evictions += 1
-                    if admit:
-                        main.insert(key, size)
-                        st.admissions += 1
-                    else:
-                        st.rejections += 1
-                self.decisions += 1
-                self.batched_decisions += 1
-        finally:
-            self.mirror.end_applied()
-        return okn

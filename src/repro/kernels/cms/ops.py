@@ -158,7 +158,7 @@ def flush_scores(table, upd_keys, n_pend, est_keys, *, cap, use_pallas, interpre
     (the same saturating non-conservative semantics as ``cms_update_ref``).
     Padded update lanes are masked to the out-of-range ``width`` sentinel,
     which no width block ever matches. Traceable (``n_pend`` may be
-    dynamic), so it composes into ``lax.scan`` decision chunks.
+    dynamic), so it composes into the chunk program's ``lax.scan``.
     """
     width = table.shape[1]
     upd_idx = row_indexes(upd_keys, width)
@@ -199,8 +199,8 @@ def update_estimate_segments(table, upd, n_pend, est, *, cap: int = 15,
     is exactness-preserving (saturating non-conservative increments
     commute, and ``min(min(t+c1, cap)+c2, cap) == min(t+c1+c2, cap)``), so
     estimates observe precisely the increments that precede their decision
-    in access order — the decision-batched admission plane's sketch
-    primitive, also used standalone by tests and benchmarks.
+    in access order. No device plane calls it; the tests check the
+    segmented flush semantics through it.
     """
     interpret = jax.default_backend() != "tpu"  # like the sibling ops
 

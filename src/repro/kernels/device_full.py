@@ -1,11 +1,10 @@
 """Whole-simulation-on-device data plane: ``data_plane="device_full"``.
 
-The ``device_batched`` plane (PR 5/6) amortizes kernel dispatch over a
-chunk of admission *decisions*, but still walks every access on the host:
-window occupancy, window-LRU order, the adaptive-window climber, and the
-LRU/SLRU recency dicts all live in host Python, so a main-cache hit (the
-common case on a warm cache) costs a host round-trip per access. This
-module moves the **entire simulation step** into one jitted ``lax.scan``:
+The host planes walk every access in Python: window occupancy,
+window-LRU order, the adaptive-window climber and the LRU/SLRU recency
+dicts, so a main-cache hit (the common case on a warm cache) costs host
+work per access. This module moves the **entire simulation step** into one
+jitted ``lax.scan``:
 
     per access — fused CMS increment -> window membership + LRU stamp ->
     main membership + LRU/SLRU promotion (with protected-overflow
@@ -26,9 +25,12 @@ exactly two (both counted in ``resync_reasons``):
   slot arrays; the arrays are zero-padded **on device** (no host
   round-trip of the contents, but counted for observability).
 
-Byte-identity with the host planes rests on the same arguments as
-``kernels.admission`` (commuting saturating increments, peek-stable victim
-replay, exact int32 cross-multiplied score comparisons) plus two new ones:
+The victim walks, the sample picker, the recency selector and the
+estimate gather come from :mod:`repro.kernels.admission`, which the
+per-decision kernels of the host path share. Byte-identity with the host
+planes rests on the same arguments as that module (commuting saturating
+increments, peek-stable victim replay, exact int32 cross-multiplied score
+comparisons) plus two of its own:
 
 * **recency as stamps** — an int32 tick counter stamps every
   insert/touch/promote; victim order is ``argmin`` over live stamps
@@ -51,7 +53,6 @@ compares and the int32 sketch hash-input truncation both hold.
 from __future__ import annotations
 
 import contextlib
-import functools
 from collections import OrderedDict
 
 import jax
@@ -61,16 +62,16 @@ import numpy as np
 from repro.core import crng
 
 from .admission import (
-    _GAMMA_HI,
-    _GAMMA_LO,
     _I32_MAX,
     MAX_MIRROR_ENTRIES,
-    _argmin_frac,
+    _freq_of,
     _next_pow2,
-    _step_slots,
+    _recency_select,
+    _sample_picker,
+    _walk_prefix_sel,
+    _walk_sampled_sel,
 )
 from .cms.ops import _mix64_u32, flush_scores
-from .cms.ref import row_indexes
 
 __all__ = ["DeviceFullSimulationPlane", "OrderedDeviceMirror", "PLANE_SPANS"]
 
@@ -94,6 +95,11 @@ _SCAL_IDX = {name: i for i, name in enumerate(_CARRY_FIELDS + _CONST_FIELDS)}
 #: Packed fields that may wrap: the climber only reads their int32 delta.
 _WRAP_FIELDS = ("hits", "prev_hits")
 
+#: ``crng.GAMMA`` as uint32 limbs: ``begin_decision`` adds it to the
+#: unmixed stream key (``bump_decision``, a 64-bit limb add).
+_GAMMA_HI = jnp.uint32(crng.GAMMA >> 32)
+_GAMMA_LO = jnp.uint32(crng.GAMMA & 0xFFFFFFFF)
+
 #: The plane's host spans: a launch's phases in the order it opens them,
 #: then the resync span (``reason=`` as counted in ``resync_reasons``).
 #: Each carries ``launch=<chunk_calls>``, the launch's index, so one
@@ -104,243 +110,6 @@ PLANE_SPANS = (_PREPARE, _PACK, _ENQUEUE, _WAIT, _FETCH, _COMMIT, _RESYNC) = (
     "plane.wait", "plane.fetch", "plane.commit", "plane.resync")
 #: Opens every span above.
 _span = jax.profiler.TraceAnnotation
-
-
-def _freq_of(table, keys32):
-    """Frequency estimates as pure gathers of the flushed table — value-
-    identical to the estimate kernels."""
-    idx = row_indexes(keys32, table.shape[1])
-    return jnp.take_along_axis(table, idx, axis=1).min(0)
-
-
-# -- victim walks (pure: record a ``sel`` eviction order, mutate nothing) ----
-
-def _walk_sampled_sel(table, mk_lo, msz, n, cand_f, needed, base_hi, base_lo,
-                      *, discipline, rule, sample, early_pruning):
-    """The counter-RNG sample walk + IV/QV/AV verdict replay of
-    ``kernels.admission._sampled_walk``, recording selections into a
-    full-width ``sel`` array (``sel[slot] = selection order``) instead of a
-    capped victim buffer — no overflow is possible, which is what removes
-    the ``victim_cap`` resync reason. Returns ``(admit, sel, n_evict,
-    examined, fallbacks)``."""
-    slots = mk_lo.shape[0]
-    n_mod = jnp.maximum(n, 1).astype(jnp.uint32)
-
-    def scores_of(slot_arr):
-        sz = msz[slot_arr]
-        one = jnp.ones_like(sz)
-        if rule == "frequency":
-            return _freq_of(table, mk_lo[slot_arr]), one
-        if rule == "size":
-            return -sz, one
-        if rule == "frequency_size":
-            return _freq_of(table, mk_lo[slot_arr]), sz
-        if rule == "needed_size":
-            return jnp.abs(sz - needed), one
-        return jnp.zeros_like(sz), one  # random: constant, first draw wins
-
-    iota = jnp.arange(slots, dtype=jnp.int32)
-    in_use = iota < n
-    pool_pad = _next_pow2(sample)
-    pool_pos = jnp.arange(pool_pad, dtype=jnp.int32)
-
-    def next_victim(taken, step, fallbacks):
-        raw = _step_slots(base_hi, base_lo, step * sample, sample, n_mod)
-        if pool_pad > sample:
-            raw = jnp.concatenate([raw, jnp.zeros(pool_pad - sample, jnp.int32)])
-        free = ~taken[raw] & (pool_pos < sample)
-        have = free.any()
-
-        def from_pool():
-            num, den = scores_of(raw)
-            return raw[_argmin_frac(num, den, pool_pos, free)]
-
-        def from_scan():
-            num, den = scores_of(iota)
-            return _argmin_frac(num, den, iota, in_use & ~taken)
-
-        best = jax.lax.cond(have, from_pool, from_scan)
-        return best, step + jnp.int32(1), fallbacks + jnp.int32(~have)
-
-    z = jnp.int32(0)
-    taken0 = jnp.zeros(slots, bool)
-    sel0 = jnp.full(slots, -1, jnp.int32)
-    if discipline == "iv":
-        first, step0, fb0 = next_victim(taken0, z, z)
-        win = cand_f >= _freq_of(table, mk_lo[first][None])[0]
-        init = (taken0.at[first].set(True), sel0.at[first].set(0),
-                jnp.int32(1), jnp.int32(1), msz[first], z, z,
-                jnp.bool_(False), z, fb0, step0)
-    else:
-        win = None
-        init = (taken0, sel0, z, z, z, z, z, jnp.bool_(False), z, z, z)
-
-    def cond(st):
-        taken, sel, g, count, covered, freed, vfreq, stopped, examined, fallbacks, step = st
-        more = count < n
-        if discipline == "iv":
-            return more & win & (covered < needed)
-        if discipline == "qv":
-            return more & ~stopped & (freed < needed)
-        return more & ~stopped & (covered < needed)
-
-    def body(st):
-        taken, sel, g, count, covered, freed, vfreq, stopped, examined, fallbacks, step = st
-        best, step, fallbacks = next_victim(taken, step, fallbacks)
-        taken = taken.at[best].set(True)
-        count = count + 1
-        s = msz[best]
-        if discipline != "iv":  # IV scores only its first victim (pre-loop)
-            f = _freq_of(table, mk_lo[best][None])[0]
-        if discipline == "iv":
-            sel = sel.at[best].set(g)
-            g = g + 1
-            covered = covered + s
-        elif discipline == "qv":
-            examined = examined + 1
-            win_q = cand_f >= f
-            sel = jnp.where(win_q, sel.at[best].set(g), sel)
-            g = g + jnp.int32(win_q)
-            freed = freed + jnp.where(win_q, s, 0)
-            stopped = ~win_q
-        else:
-            sel = sel.at[best].set(g)
-            g = g + 1
-            covered = covered + s
-            vfreq = vfreq + f
-            examined = examined + 1
-            if early_pruning:
-                stopped = cand_f < vfreq
-        return (taken, sel, g, count, covered, freed, vfreq, stopped,
-                examined, fallbacks, step)
-
-    (taken, sel, g, count, covered, freed, vfreq, stopped,
-     examined, fallbacks, step) = jax.lax.while_loop(cond, body, init)
-
-    if discipline == "iv":
-        admit = win
-        n_evict = jnp.where(admit, g, 0)
-        examined = jnp.int32(1)
-    elif discipline == "qv":
-        admit = freed >= needed
-        n_evict = g
-    else:
-        pruned = stopped | (covered < needed)
-        admit = ~pruned & (cand_f >= vfreq)
-        n_evict = jnp.where(admit, g, 0)
-    return admit, sel, n_evict, examined, fallbacks
-
-
-def _walk_prefix_sel(table, mk_lo, msz, mstamp, mseg, n, cand_f, needed, tick,
-                     *, discipline, early_pruning, slru):
-    """IV/QV/AV verdict replay over the recency-order (LRU / SLRU
-    probation-first) victim walk — the device twin of
-    ``EvictionPolicy.peek_victims`` + ``_decide_prefix``, selecting by
-    ``argmin`` over live stamps instead of a host-gathered prefix.
-    Rejected-candidate promotions are applied to ``mstamp`` here, BEFORE
-    the eviction apply (safe: stamps travel with rows through swap-removes
-    and promoted entries are never evicted). Returns ``(admit, sel,
-    n_evict, examined, mstamp, tick)``."""
-    slots = mk_lo.shape[0]
-    iota = jnp.arange(slots, dtype=jnp.int32)
-    live = iota < n
-    z = jnp.int32(0)
-    taken0 = jnp.zeros(slots, bool)
-    sel0 = jnp.full(slots, -1, jnp.int32)
-
-    def select(taken):
-        cand_mask = live & ~taken
-        if slru:
-            prob = cand_mask & (mseg == 0)
-            mask = jnp.where(prob.any(), prob, cand_mask)
-        else:
-            mask = cand_mask
-        return jnp.argmin(jnp.where(mask, mstamp, _I32_MAX)).astype(jnp.int32)
-
-    if discipline == "iv":
-        first = select(taken0)
-        admit = cand_f >= _freq_of(table, mk_lo[first][None])[0]
-
-        # gather the covering prefix unconditionally, mirroring the host's
-        # peek_victims (which gathers before the verdict); zero RNG/tick use
-        def cond(st):
-            taken, sel, g, covered = st
-            return (g < n) & (covered < needed)
-
-        def body(st):
-            taken, sel, g, covered = st
-            v = select(taken)
-            return (taken.at[v].set(True), sel.at[v].set(g), g + 1,
-                    covered + msz[v])
-
-        taken, sel, g, covered = jax.lax.while_loop(
-            cond, body, (taken0, sel0, z, z))
-        n_evict = jnp.where(admit, g, 0)
-        examined = jnp.int32(1)
-        # loss: promote the first victim (Alg. 4 line 14)
-        mstamp = mstamp.at[jnp.where(admit, slots, first)].set(tick, mode="drop")
-        tick = tick + jnp.int32(~admit)
-        return admit, sel, n_evict, examined, mstamp, tick
-
-    if discipline == "qv":
-        def cond(st):
-            taken, sel, g, count, freed, examined, stopped, loser = st
-            return (count < n) & ~stopped & (freed < needed)
-
-        def body(st):
-            taken, sel, g, count, freed, examined, stopped, loser = st
-            v = select(taken)
-            taken = taken.at[v].set(True)
-            f = _freq_of(table, mk_lo[v][None])[0]
-            win = cand_f >= f
-            sel = jnp.where(win, sel.at[v].set(g), sel)
-            g = g + jnp.int32(win)
-            freed = freed + jnp.where(win, msz[v], 0)
-            examined = examined + 1
-            loser = jnp.where(win, loser, v)
-            return (taken, sel, g, count + 1, freed, examined, ~win, loser)
-
-        init = (taken0, sel0, z, z, z, z, jnp.bool_(False), jnp.int32(slots))
-        (taken, sel, g, count, freed, examined, stopped,
-         loser) = jax.lax.while_loop(cond, body, init)
-        admit = freed >= needed
-        n_evict = g  # QV evictions stick on a reject
-        # reject: promote the loser (never evicted — it lost, so it was
-        # never selected)
-        mstamp = mstamp.at[jnp.where(admit, slots, loser)].set(tick, mode="drop")
-        tick = tick + jnp.int32(~admit)
-        return admit, sel, n_evict, examined, mstamp, tick
-
-    # AV: gather victims (and their frequency sum) until covered or pruned
-    def cond(st):
-        taken, sel, g, covered, vfreq, stopped = st
-        return (g < n) & ~stopped & (covered < needed)
-
-    def body(st):
-        taken, sel, g, covered, vfreq, stopped = st
-        v = select(taken)
-        taken = taken.at[v].set(True)
-        f = _freq_of(table, mk_lo[v][None])[0]
-        sel = sel.at[v].set(g)
-        g = g + 1
-        covered = covered + msz[v]
-        vfreq = vfreq + f
-        if early_pruning:
-            stopped = cand_f < vfreq
-        return (taken, sel, g, covered, vfreq, stopped)
-
-    init = (taken0, sel0, z, z, z, jnp.bool_(False))
-    taken, sel, g, covered, vfreq, stopped = jax.lax.while_loop(cond, body, init)
-    pruned = stopped | (covered < needed)
-    admit = ~pruned & (cand_f >= vfreq)
-    n_evict = jnp.where(admit, g, 0)
-    examined = g
-    # reject: promote every gathered victim in selection order (the prune
-    # point included) — one vectorized stamp write, ticks in sel order
-    promote = (~admit) & (sel >= 0)
-    mstamp = jnp.where(promote, tick + sel, mstamp)
-    tick = tick + jnp.where(admit, 0, g)
-    return admit, sel, n_evict, examined, mstamp, tick
 
 
 def _apply_evictions(mk_hi, mk_lo, msz, mstamp, mseg, sel, n, used, pbytes,
@@ -557,76 +326,32 @@ def _simulate_chunk_impl(table, mk_hi, mk_lo, msz, mstamp, mseg,
         because peeking never consumes state)."""
         overflow = st["used"] - st["main_cap"]
         needed0 = jnp.maximum(z, overflow)
+        msz_a = st["msz"]
         if sampled:
             base_hi, base_lo = _mix64_u32(st["khi"], st["klo"])
-            n_mod = jnp.maximum(st["n"], 1).astype(jnp.uint32)
-            table = st["table"]
-            mk_lo_a = st["mk_lo"]
-            msz_a = st["msz"]
-            in_use = miota < st["n"]
-            pool_pad = _next_pow2(sample)
-            pool_pos = jnp.arange(pool_pad, dtype=jnp.int32)
-
-            def scores_of(slot_arr):
-                sz = msz_a[slot_arr]
-                one = jnp.ones_like(sz)
-                if rule == "frequency":
-                    return _freq_of(table, mk_lo_a[slot_arr]), one
-                if rule == "size":
-                    return -sz, one
-                if rule == "frequency_size":
-                    return _freq_of(table, mk_lo_a[slot_arr]), sz
-                if rule == "needed_size":
-                    return jnp.abs(sz - needed0), one
-                return jnp.zeros_like(sz), one
-
-            def next_victim(wst):
-                taken, sel, g, freed, step, fb = wst
-                raw = _step_slots(base_hi, base_lo, step * sample, sample, n_mod)
-                if pool_pad > sample:
-                    raw = jnp.concatenate(
-                        [raw, jnp.zeros(pool_pad - sample, jnp.int32)])
-                free = ~taken[raw] & (pool_pos < sample)
-                have = free.any()
-
-                def from_pool():
-                    num, den = scores_of(raw)
-                    return raw[_argmin_frac(num, den, pool_pos, free)]
-
-                def from_scan():
-                    num, den = scores_of(miota)
-                    return _argmin_frac(num, den, miota, in_use & ~taken)
-
-                best = jax.lax.cond(have, from_pool, from_scan)
-                return (taken.at[best].set(True), sel.at[best].set(g), g + 1,
-                        freed + msz_a[best], step + jnp.int32(1),
-                        fb + jnp.int32(~have))
+            next_victim = _sample_picker(
+                st["table"], st["mk_lo"], msz_a, st["n"], needed0, base_hi,
+                base_lo, rule=rule, sample=sample)
 
             def wcond(wst):
                 taken, sel, g, freed, step, fb = wst
                 return (g < st["n"]) & (freed < overflow)
 
+            def wbody(wst):
+                taken, sel, g, freed, step, fb = wst
+                best, step, fb = next_victim(taken, step, fb)
+                return (taken.at[best].set(True), sel.at[best].set(g), g + 1,
+                        freed + msz_a[best], step, fb)
+
             init = (jnp.zeros(slots, bool), jnp.full(slots, -1, jnp.int32),
                     z, z, z, z)
             taken, sel, g, freed, step, fb = jax.lax.while_loop(
-                wcond, next_victim, init)
+                wcond, wbody, init)
             st = dict(st)
             st["fallbacks"] = st["fallbacks"] + fb
         else:
-            live = miota < st["n"]
-            mstamp_a = st["mstamp"]
-            mseg_a = st["mseg"]
-            msz_a = st["msz"]
-
-            def select(taken):
-                cand_mask = live & ~taken
-                if slru:
-                    prob = cand_mask & (mseg_a == 0)
-                    mask = jnp.where(prob.any(), prob, cand_mask)
-                else:
-                    mask = cand_mask
-                return jnp.argmin(
-                    jnp.where(mask, mstamp_a, _I32_MAX)).astype(jnp.int32)
+            select = _recency_select(st["mstamp"], st["mseg"], st["n"],
+                                     slru=slru)
 
             def wcond(wst):
                 taken, sel, g, freed = wst
@@ -886,6 +611,14 @@ class OrderedDeviceMirror:
         self.main = main_arrays
         self.window = window_arrays
 
+    def resident_keys(self, n: int, wn: int) -> set:
+        """The keys in the first ``n`` Main and ``wn`` Window slots: a read
+        of the four key-limb arrays only, for membership between launches."""
+        mk_hi, mk_lo = (np.asarray(a) for a in self.main[:2])
+        wk_hi, wk_lo = (np.asarray(a) for a in self.window[:2])
+        return (set(_keys_of(mk_hi[:n], mk_lo[:n]))
+                | set(_keys_of(wk_hi[:wn], wk_lo[:wn])))
+
     def download(self, n: int, wn: int, sampled: bool):
         """Materialize ``(rows, window_items)`` in the host contract order:
         slot order for the sampled mains (draws address slots), stamp order
@@ -934,10 +667,13 @@ class DeviceFullSimulationPlane:
     and ``mirror_grow`` (device arrays zero-padded on device). Both are
     counted in ``resyncs`` / ``resync_reasons`` and forced in tests.
 
-    Exposes the same deferred-collection surface as
-    :class:`~repro.kernels.admission.DeviceBatchedAdmissionPlane`
-    (``defer_collect``, ``sync``, ``has_deferred_work``, ``chunk``,
-    counters) so the serving-layer async pipeline drives it unchanged.
+    The serving-layer async pipeline drives it through its
+    deferred-collection surface (``defer_collect``, ``sync``,
+    ``has_deferred_work``, ``chunk``, ``deferred_dispatches``,
+    ``chunk_calls``, ``decisions``): with ``defer_collect`` a whole-chunk
+    drive leaves its launch in flight until the next drive or ``sync``,
+    and a launch scans only its take's power-of-two bucket
+    (:meth:`scan_len`), since each resolve waits on a few accesses.
     """
 
     def __init__(self, device, *, chunk: int = 64):
@@ -977,6 +713,11 @@ class DeviceFullSimulationPlane:
         self._wn = 0
         self._tick = 0
         self._pbytes = 0
+        #: the resident keys after the last launch, read off the device by
+        #: :meth:`contains`; kept across launches whose keys are all
+        #: resident (all hits), dropped by any other launch and whenever
+        #: the host takes authority back (a fleet's release included)
+        self._resident = None
 
     # -- plane surface ------------------------------------------------------
     @property
@@ -994,6 +735,28 @@ class DeviceFullSimulationPlane:
         """Collect any in-flight launch AND restore host authority —
         after this, host structures, membership, and stats are exact."""
         self.ensure_host(pol)
+
+    def collect(self, pol) -> None:
+        """Collect any in-flight launch: stats and the committed shadows
+        are exact after this, while the cache state stays on the device."""
+        self._collect(pol)
+
+    def contains(self, pol, key) -> bool:
+        """Membership without restoring host authority. It answers from
+        the resident keys read off the device (the key limbs only), which
+        are read after collecting the launch in flight and then kept while
+        every later launch only hits: such a launch cannot change
+        residency, so it is not waited for. A caller that reads membership
+        between launches, as the serving pipeline does at every resolve,
+        pays neither the download and host rebuild of :meth:`ensure_host`
+        nor the re-upload at the next launch."""
+        if self._resident is None or self._fleet_restore is not None:
+            self._collect(pol)
+            if self._host_auth or self._fleet_restore is not None:
+                self.ensure_host(pol)
+                return key in pol.window or key in self.main
+            self._resident = self.mirror.resident_keys(self._n, self._wn)
+        return key in self._resident
 
     # -- chunk drive --------------------------------------------------------
     def drive_chunk(self, pol, keys, sizes):
@@ -1136,17 +899,25 @@ class DeviceFullSimulationPlane:
             cap=self.sketch.cap, use_pallas=self.sketch.use_pallas,
             interpret=self.device._interpret)
 
-    def scan_len(self) -> int:
-        """The scan length of every launch: one per plane, not per take, so
-        a take cut short (aging boundary, trace end) compiles no new
-        program. Its padded steps are masked but still run."""
-        return _next_pow2(max(8, self.chunk))
+    def scan_len(self, take: "int | None" = None) -> int:
+        """The scan length of a launch. A lockstep drive (the engine, the
+        fleet) runs one per plane, not per take, so a take cut short (aging
+        boundary, trace end) compiles no new program; its padded steps are
+        masked but still run. A deferred drive (``defer_collect``, the
+        serving pipeline) is waited on at each resolve, a few accesses at
+        a time, so there ``take`` runs its own power-of-two bucket: at most
+        log2(chunk / 8) + 1 programs."""
+        full = _next_pow2(max(8, self.chunk))
+        if take is None or not self.defer_collect:
+            return full
+        return min(full, _next_pow2(max(8, take)))
 
     def launch_inputs(self, pol, khi, klo, szs, take, pad=None) -> tuple:
         """The host-built inputs of a ``take``-access launch, after the
         state buffers: key limbs and sizes padded to ``pad`` (default
-        :meth:`scan_len`), the packed scalars, the RNG stream-key limbs."""
-        xs = np.zeros((3, self.scan_len() if pad is None else pad), np.int32)
+        :meth:`scan_len` of ``take``), the packed scalars, the RNG
+        stream-key limbs."""
+        xs = np.zeros((3, self.scan_len(take) if pad is None else pad), np.int32)
         xs[0, :take] = khi
         xs[1, :take] = klo
         xs[2, :take] = szs
@@ -1166,6 +937,9 @@ class DeviceFullSimulationPlane:
         sk = self.sketch
         launch = self.chunk_calls
         with _span(_PACK, launch=launch):
+            if (self._resident is not None
+                    and not self._resident.issuperset(_keys_of(khi, klo))):
+                self._resident = None  # a miss can insert or evict
             self._preflight(pol, take)
             args = self.chunk_args(pol, khi, klo, szs, take)
             statics = self._statics(pol)
@@ -1266,6 +1040,7 @@ class DeviceFullSimulationPlane:
             # the fleet's stacked buffers — materialize this instance's lane
             # into the mirror (and sketch table) before downloading
             self._fleet_restore()
+        self._resident = None  # the host may change residency from here
         rows, window_items = self.mirror.download(
             self._n, self._wn, self.sampled)
         self.main.load_rows(rows)

@@ -1,10 +1,11 @@
 """Async size-aware admission pipeline for the serving layer.
 
 The paper's pitch is admission at a fraction of the CPU cost of
-AdaptSize/LHD/GDSF; PR 5's ``device_batched`` data plane delivered that by
-amortizing one ``lax.scan`` kernel launch over a whole chunk of admission
-decisions. This module closes the remaining gap to the serving loop: a
-request must never *wait* on an admission verdict.
+AdaptSize/LHD/GDSF; the ``device_full`` data plane delivers that by
+resolving a whole chunk of cache accesses, admission decisions included,
+in one ``lax.scan`` launch. This module closes the remaining gap to the
+serving loop: a request waits on an admission verdict only when that
+verdict could change what the request sees.
 
 Two hooks implement one protocol:
 
@@ -14,10 +15,10 @@ Two hooks implement one protocol:
 * :class:`AsyncAdmissionPipeline` — the pipeline: cache accesses (lookup
   touches and admission offers, sizes in KV bytes) are *enqueued*; a full
   event chunk drains through ``policy.access_batch`` whose trailing
-  decision chunk is left resolving on device (``defer_collect`` on
-  :class:`~repro.kernels.admission.DeviceBatchedAdmissionPlane`) while the
-  next chunk fills from live requests — double-buffered decisions, with
-  verdicts applied lazily under PR 5's deferred-visibility contract.
+  launch is left resolving on device (``defer_collect`` on
+  :class:`~repro.kernels.device_full.DeviceFullSimulationPlane`) while the
+  next chunk fills from live requests — double-buffered launches, with
+  verdicts applied lazily under the deferred-visibility contract below.
 
 Laziness never changes observable behaviour. ``PrefixCache`` resolves the
 pipeline exactly when a pending verdict could flip what a request sees:
@@ -35,9 +36,12 @@ serving-driven column of the differential suite asserts the whole
 arrangement is byte-identical to :class:`SyncAdmission` replay.
 
 Event-queue invariant (load-bearing for identity): a touch is only ever
-enqueued when the queue holds no offers (matching lookups force a resolve
-first), so queued touches always drain as policy hits — exactly what the
-synchronous replay sees at the same position in the access stream.
+enqueued when the queue holds no pending verdict (matching lookups force a
+resolve first), so queued touches always drain as policy hits — exactly
+what the synchronous replay sees at the same position in the access
+stream. ``PrefixCache`` queues a re-offer of a materialized prefix, made
+while no verdict is pending, as a touch for the same reason: it hits, its
+verdict is known, and it forces no resolve.
 """
 
 from __future__ import annotations
@@ -134,8 +138,8 @@ class SyncAdmission(AdmissionHook):
 
 class AsyncAdmissionPipeline(AdmissionHook):
     """Non-blocking hook: events queue up and drain through
-    ``policy.access_batch`` in chunks; on the ``device_batched`` plane the
-    trailing decision chunk stays in flight on device between drains."""
+    ``policy.access_batch`` in chunks; on the ``device_full`` plane the
+    trailing chunk launch stays in flight on device between drains."""
 
     is_async = True
 
@@ -197,7 +201,7 @@ class AsyncAdmissionPipeline(AdmissionHook):
     # -- draining ----------------------------------------------------------
     def _pump(self) -> None:
         """Drain the event queue into the policy. Under ``defer_collect``
-        the policy's trailing decision chunk dispatches without blocking —
+        the policy's trailing chunk launch dispatches without blocking —
         it resolves on device while new events gather here."""
         if not self._keys:
             return
@@ -218,9 +222,10 @@ class AsyncAdmissionPipeline(AdmissionHook):
         return the accumulated offer verdicts in offer order."""
         self.syncs += 1
         self._pump()
-        sync_deferred = getattr(self.policy, "sync_deferred", None)
-        if sync_deferred is not None:
-            sync_deferred()
+        if self._plane is not None:
+            # verdicts and stats need the launch, not host authority:
+            # membership is answered from the device's resident keys
+            self._plane.collect(self.policy)
         if not self._pending_offers:
             return []
         now = self._clock()

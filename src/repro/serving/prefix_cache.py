@@ -20,8 +20,9 @@ Mechanics:
   the policy said had room;
 * admission runs through a pluggable :mod:`repro.serving.admission` hook —
   synchronous by default, or the async pipeline (``admission="async"``)
-  that defers offers/touches into device-batched decision chunks and
-  resolves them only when a request could observe the verdict.
+  that defers offers/touches into chunks that ``access_batch`` resolves
+  (one device launch each on ``data_plane="device_full"``) and resolves
+  them only when a request could observe the verdict.
 """
 
 from __future__ import annotations
@@ -281,6 +282,13 @@ class PrefixCache:
         key = hashes[-1]
         size = len(hashes) * self.block_bytes
         if self.admission.is_async:
+            if key in self.entries and not self.admission.has_pending_offers:
+                # every event since the last resolve hit a resident entry,
+                # so this one hits too: the verdict is known, nothing pends
+                self.admission.touch(key, size)
+                if payload is not None:
+                    self.entries[key].payload = payload
+                return None
             self.admission.offer(key, size)
             cand = self._pending_cands.get(key)
             if cand is None:
@@ -299,6 +307,9 @@ class PrefixCache:
         """Resolve every pending admission verdict; afterwards entries,
         policy state, and stats are exact."""
         self._resolve()
+        sync_deferred = getattr(self.policy, "sync_deferred", None)
+        if sync_deferred is not None:
+            sync_deferred()  # host structures too, not only membership
 
     # -- stats -----------------------------------------------------------------
     @property
@@ -322,7 +333,7 @@ class PrefixCache:
                 if self.blocks_requested else 0.0)
 
     def stats(self) -> dict:
-        self._resolve()
+        self.sync()
         out = {
             "requests": self.requests,
             "request_hit_ratio": round(self.request_hit_ratio, 5),

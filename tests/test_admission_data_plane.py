@@ -13,12 +13,14 @@ Covers, layer by layer:
   admission decision and zero scalar ``estimate`` calls on the hot path;
 * ``CMSSketch.estimate_batch``'s fused flush+score kernel path equals the
   staged flush-then-estimate path;
-* the device plane (ISSUE 4): plane/backend resolution and spec
-  round-tripping, exactly ONE jitted decision call per admission decision
-  (no per-victim host round-trips), the incrementally-maintained device
-  key/size mirror staying in sync with the eviction policy, and three-way
-  scalar == batched == device byte-identity (the exhaustive grid lives in
-  ``tests/test_property_differential.py``).
+* the per-decision device kernel (``device_full``'s host path, driven
+  through scalar ``access``): exactly ONE jitted decision call per
+  admission decision (no per-victim host round-trips), the
+  incrementally-maintained device key/size mirror staying in sync with the
+  eviction policy, three-way scalar == batched == device_full
+  byte-identity (the exhaustive grid lives in
+  ``tests/test_property_differential.py``), and the retired plane names
+  refused.
 """
 
 import random
@@ -174,6 +176,8 @@ def _run_both_planes(spec, tr, cap, **kw):
 
 
 def _assert_byte_identical(a, b, hits_a, hits_b, label=""):
+    for p in (a, b):
+        p.sync_deferred()  # device_full: restore host authority first
     assert np.array_equal(hits_a, hits_b), f"{label}: hit/miss streams diverge"
     sa, sb = a.stats, b.stats
     for field in ("accesses", "hits", "bytes_hit", "victims_examined",
@@ -309,39 +313,34 @@ class TestBatchedNeverFallsBack:
 
 
 class TestDevicePlane:
-    """ISSUE 4: the closed-loop device-resident admission decision."""
+    """The per-decision device kernel: ``device_full``'s host path, driven
+    through scalar ``access``."""
 
-    def test_device_plane_implies_cms_backend(self):
-        p = SizeAwareWTinyLFU(10_000, expected_entries=64, data_plane="device")
-        assert p.data_plane == "device"
-        assert p.sketch_backend == "cms"
-        with pytest.raises(ValueError, match="cms"):
-            SizeAwareWTinyLFU(10_000, expected_entries=64,
-                              data_plane="device", sketch_backend="host")
+    @pytest.mark.parametrize("plane", ("device", "device_batched"))
+    def test_retired_planes_refused(self, plane):
+        """``device_full`` is the one device plane: the retired names fail
+        the constructor's validation, spec-built or not, naming the valid
+        planes."""
+        from repro.core.tinylfu import DATA_PLANES
 
-    def test_spec_round_trip(self):
-        from repro.core import PolicySpec
-
-        spec = PolicySpec.parse("wtlfu-av-random?data_plane=device&seed=0x5EED")
-        assert PolicySpec.parse(spec.to_string()) == spec
-        assert spec.with_params(data_plane="scalar").params_dict["data_plane"] == "scalar"
-        p = REGISTRY.build(spec, 5_000, expected_entries=64)
-        assert p.data_plane == "device"
-        assert p.sketch_backend == "cms"
-        assert p.main.seed == 0x5EED
+        assert DATA_PLANES == ("auto", "batched", "scalar", "device_full")
+        with pytest.raises(ValueError, match="device_full"):
+            SizeAwareWTinyLFU(10_000, expected_entries=64, data_plane=plane)
+        with pytest.raises(ValueError, match="data_plane"):
+            REGISTRY.build(f"wtlfu-av-random?data_plane={plane}&seed=0x5EED",
+                           5_000, expected_entries=64)
 
     @pytest.mark.parametrize("eviction", ("sampled_frequency", "slru"))
     def test_one_jitted_call_per_decision(self, eviction):
         """Acceptance: at most one jitted device call per admission
         decision — and with no aging reset due, exactly one (zero staged
         flushes), for both the mirror walk and the prefix kernel. Scalar
-        drive pins the per-decision contract (under ``access_batch`` the
-        device plane auto-upgrades to decision batching — see
-        ``TestDeviceBatchedPlane``)."""
+        drive pins the per-decision contract (``access_batch`` resolves
+        whole chunks in the scan instead — see ``TestDeviceFullPlane``)."""
         tr = make_trace("msr2", seed=9, scale=0.0008)
         cap = max(1, int(tr.total_object_bytes * 0.02))
         p = SizeAwareWTinyLFU(
-            cap, admission="av", eviction=eviction, data_plane="device",
+            cap, admission="av", eviction=eviction, data_plane="device_full",
             expected_entries=max(64, int(cap / tr.mean_object_size)),
             sketch_kwargs={"sample_factor": 10_000},  # no resets this trace
         )
@@ -365,7 +364,7 @@ class TestDevicePlane:
         counter must keep matching scalar driving."""
         tr = make_trace("msr2", seed=9, scale=0.0008)
         cap = max(1, int(tr.total_object_bytes * 0.02))
-        p = SizeAwareWTinyLFU(cap, data_plane="device", expected_entries=16,
+        p = SizeAwareWTinyLFU(cap, data_plane="device_full", expected_entries=16,
                               eviction="sampled_frequency")
         SimulationEngine(use_batch=False).run(p, tr)
         plane = p.admission_policy._device
@@ -381,7 +380,7 @@ class TestDevicePlane:
         tr = make_trace("cdn1", seed=3, scale=0.0008)
         cap = max(1, int(tr.total_object_bytes * 0.02))
         p = SizeAwareWTinyLFU(cap, admission="qv", eviction="sampled_size",
-                              data_plane="device",
+                              data_plane="device_full",
                               expected_entries=max(64, int(cap / tr.mean_object_size)))
         SimulationEngine(use_batch=False).run(p, tr)
         plane = p.admission_policy._device
@@ -397,22 +396,23 @@ class TestDevicePlane:
 
     @pytest.mark.parametrize("admission", ("iv", "qv", "av"))
     def test_three_way_trace_equivalence(self, admission):
-        """Spot three-way check on an engine-driven trace (the exhaustive
-        21-combo grid runs in tests/test_property_differential.py)."""
+        """Spot three-way check on an engine-driven trace, device_full
+        through ``access_batch`` (the exhaustive 21-combo grid runs in
+        tests/test_property_differential.py)."""
         tr = make_trace("msr2", seed=5, scale=0.0008)
         cap = max(1, int(tr.total_object_bytes * 0.02))
         kw = dict(expected_entries=max(64, int(cap / tr.mean_object_size)),
                   sketch_backend="cms")
         spec = f"wtlfu-{admission}-sampled_frequency_size"
         out = []
-        for plane in ("scalar", "batched", "device"):
+        for plane in ("scalar", "batched", "device_full"):
             p = REGISTRY.build(spec, cap, data_plane=plane, **kw)
             rec = HitMaskRecorder()
             SimulationEngine(instruments=(rec,)).run(p, tr)
             out.append((p, rec.hits))
         (a, ha), (b, hb), (c, hc) = out
         _assert_byte_identical(a, b, ha, hb, f"{spec} scalar-vs-batched")
-        _assert_byte_identical(a, c, ha, hc, f"{spec} scalar-vs-device")
+        _assert_byte_identical(a, c, ha, hc, f"{spec} scalar-vs-device_full")
 
 
 def _drive_batched(p, keys, sizes, step=37):
@@ -448,89 +448,41 @@ def _assert_mirror_synced(p, label=""):
 
 
 class TestDeviceBatchedPlane:
-    """ISSUE 5: the decision-batched device pipeline (speculative
-    window-cascade unrolling — chunks of admission decisions per launch)."""
+    """Chunked and per-access drives of ``device_full`` against the scalar
+    plane: engine-driven identity, snapshot alignment, the aging and
+    mirror-growth resyncs, and the per-decision kernel's key/size mirror."""
 
     def _trace(self, seed=5, scale=0.0015):
         tr = make_trace("msr2", seed=seed, scale=scale)
         cap = max(1, int(tr.total_object_bytes * 0.02))
         return tr, cap, max(64, int(cap / tr.mean_object_size))
 
-    def test_plane_resolution_and_spec_round_trip(self):
-        from repro.core import PolicySpec
-
-        spec = PolicySpec.parse(
-            "wtlfu-qv-sampled_frequency?data_plane=device_batched&chunk=16&seed=0xA11CE")
-        assert PolicySpec.parse(spec.to_string()) == spec
-        p = REGISTRY.build(spec, 10_000, expected_entries=64)
-        assert p.data_plane == "device_batched"
-        assert p.sketch_backend == "cms"  # implied, like data_plane=device
-        assert p.admission_policy._device_batch.chunk == 16
-        assert p.main.seed == 0xA11CE
-        with pytest.raises(ValueError, match="cms"):
-            SizeAwareWTinyLFU(10_000, expected_entries=64,
-                              data_plane="device_batched", sketch_backend="host")
-        with pytest.raises(ValueError, match="chunk"):
-            SizeAwareWTinyLFU(10_000, expected_entries=64,
-                              data_plane="device_batched", chunk=0)
-
-    def test_decisions_batched_per_launch(self):
-        """Acceptance: the chunk kernel amortizes dispatch — decisions
-        resolve in strictly fewer launches than the per-decision plane
-        would take, with the bulk of them resolved inside chunk kernels."""
-        tr, cap, ee = self._trace()
-        p = SizeAwareWTinyLFU(cap, admission="qv", eviction="sampled_frequency",
-                              data_plane="device_batched", expected_entries=ee,
-                              chunk=16)
-        SimulationEngine().run(p, tr)
-        pipe = p.admission_policy._device_batch
-        dev = p.admission_policy._device
-        assert pipe.decisions > 100, "trace too small to be meaningful"
-        launches = pipe.chunk_calls + dev.calls
-        assert launches < pipe.decisions / 2, (
-            f"{launches} launches for {pipe.decisions} decisions: batching "
-            "is not amortizing dispatch")
-        assert pipe.batched_decisions > pipe.decisions / 2
-
     def test_scalar_access_resolves_per_decision(self):
-        """Scalar ``access()`` on device_batched (admit_device_batch — also
-        the adaptive-window drain path) resolves each decision through the
-        per-decision kernel, byte-identical to the scalar plane, without
-        engaging the chunk pipeline."""
+        """Scalar ``access()`` on device_full (also the adaptive-window
+        drain's path) resolves each decision through the per-decision
+        kernel, byte-identical to the scalar plane, without launching the
+        chunk program."""
         rng = np.random.default_rng(17)
         keys = ((rng.zipf(1.25, size=400) - 1) % 35).astype(np.int64).tolist()
         sizes = [10 + (k * 11) % 80 for k in keys]
         spec = "wtlfu-av-sampled_frequency?sketch_backend=cms&adaptive_window=1"
         a = REGISTRY.build(spec, 600, data_plane="scalar", expected_entries=64)
         ha = [a.access(k, s) for k, s in zip(keys, sizes)]
-        d = REGISTRY.build(spec, 600, data_plane="device_batched", expected_entries=64)
+        d = REGISTRY.build(spec, 600, data_plane="device_full", expected_entries=64)
         hd = [d.access(k, s) for k, s in zip(keys, sizes)]
         _assert_byte_identical(a, d, np.asarray(ha), np.asarray(hd), "scalar access")
-        assert d._device_pipeline.decisions == 0  # batching is chunk-path only
+        assert d._device_pipeline.chunk_calls == 0  # the scan is chunk-path only
         assert d.admission_policy._device.calls > 0
-
-    def test_device_plane_auto_upgrades_under_access_batch(self):
-        """data_plane="device" driven through the engine's access_batch
-        path routes whole chunks into the decision-batched pipeline; the
-        scalar drive stays per-decision."""
-        tr, cap, ee = self._trace(scale=0.0008)
-        batched = SizeAwareWTinyLFU(cap, data_plane="device", expected_entries=ee)
-        SimulationEngine().run(batched, tr)
-        assert batched.admission_policy._device_batch.decisions > 0
-        scalar = SizeAwareWTinyLFU(cap, data_plane="device", expected_entries=ee)
-        SimulationEngine(use_batch=False).run(scalar, tr)
-        assert scalar.admission_policy._device_batch.decisions == 0
-        assert scalar.admission_policy._device.calls > 0
 
     @pytest.mark.parametrize("admission,eviction",
                              [("iv", "sampled_size"), ("qv", "sampled_frequency"),
                               ("av", "random"), ("av", "slru")])
     def test_engine_driven_byte_identity(self, admission, eviction):
-        """Engine-driven device_batched == scalar-driven scalar plane:
+        """Engine-driven device_full == scalar-driven scalar plane:
         decisions, CacheStats, contents, fallback counters."""
         tr, cap, ee = self._trace(scale=0.0008)
         out = []
-        for plane, use_batch in (("scalar", False), ("device_batched", "auto")):
+        for plane, use_batch in (("scalar", False), ("device_full", "auto")):
             p = REGISTRY.build(
                 f"wtlfu-{admission}-{eviction}?sketch_backend=cms", cap,
                 data_plane=plane, expected_entries=ee, chunk=8)
@@ -538,96 +490,72 @@ class TestDeviceBatchedPlane:
             SimulationEngine(instruments=(rec,), use_batch=use_batch).run(p, tr)
             out.append((p, rec.hits))
         (a, ha), (b, hb) = out
-        _assert_byte_identical(a, b, ha, hb, f"{admission}/{eviction} device_batched")
+        _assert_byte_identical(a, b, ha, hb, f"{admission}/{eviction} device_full")
         if eviction not in ("lru", "slru"):
             assert a.main.fallback_scans == b.main.fallback_scans
             _assert_mirror_synced(b, f"{admission}/{eviction}")
 
     def test_warmup_snapshot_alignment_with_buffered_decisions(self):
-        """ISSUE 5 satellite: the pipeline resolves every buffered decision
-        before access_batch returns, so engine snapshots land exactly
-        ``snapshot_every`` accesses after warmup even when warmup ends
-        mid-chunk and decisions were in flight."""
+        """access_batch leaves stats exact when it returns, so engine
+        snapshots land exactly ``snapshot_every`` accesses after warmup
+        even when warmup ends mid-chunk."""
         tr, cap, ee = self._trace(scale=0.0008)
         n = len(tr)
         warmup, every = 137, 250
-        p = SizeAwareWTinyLFU(cap, data_plane="device_batched",
+        p = SizeAwareWTinyLFU(cap, data_plane="device_full",
                               eviction="sampled_frequency", expected_entries=ee)
         res = SimulationEngine(chunk_size=100, warmup=warmup,
                                snapshot_every=every).run(p, tr)
         got = [s.accesses for s in res.snapshots]
         assert got == [every * (i + 1) for i in range((n - warmup) // every)]
 
-    # -- speculation fallback coverage (ISSUE 5 satellite) -----------------
-
     def test_aging_reset_mid_chunk_resyncs_and_matches(self):
-        """A tiny sketch forces aging resets inside buffered chunks: the
-        pipeline must split at the boundary via the per-decision staged
-        path (counted in resync_reasons['aging']) and stay byte-identical
-        — same resets, same ops counter, same decisions."""
+        """A tiny sketch forces aging resets inside chunks: the plane must
+        replay the boundary access through the per-decision staged path
+        (counted in resync_reasons['aging']) and stay byte-identical —
+        same resets, same ops counter, same decisions."""
         tr, cap, ee = self._trace(scale=0.0008)
         out = []
-        for plane in ("scalar", "device_batched"):
+        for plane in ("scalar", "device_full"):
             p = REGISTRY.build("wtlfu-qv-sampled_frequency?sketch_backend=cms",
                                cap, data_plane=plane, expected_entries=16, chunk=8)
             rec = HitMaskRecorder()
             SimulationEngine(instruments=(rec,)).run(p, tr)
             out.append((p, rec.hits))
         (a, ha), (b, hb) = out
+        b.sync_deferred()
         assert a.sketch.resets > 0, "sketch never aged; shrink expected_entries"
         assert a.sketch.resets == b.sketch.resets
         assert a.sketch._ops == b.sketch._ops
-        pipe = b.admission_policy._device_batch
+        pipe = b._device_pipeline
         assert pipe.resync_reasons["aging"] > 0, "aging resync never exercised"
         _assert_byte_identical(a, b, ha, hb, "aging resync")
 
-    def test_victim_cap_overflow_poisons_and_resyncs(self):
-        """A decision selecting more victims than the scan kernel's static
-        victim_cap poisons the chunk suffix; the host must redo it through
-        the per-decision plane (resync_reasons['victim_cap']) and re-batch
-        the rest — byte-identical throughout. AV without early pruning
-        gathers long victim runs, so victim_cap=2 trips constantly."""
-        tr, cap, ee = self._trace(scale=0.0008)
-        spec = "wtlfu-av-random?early_pruning=0&sketch_backend=cms"
-        a = REGISTRY.build(spec, cap, data_plane="scalar", expected_entries=ee)
-        rec_a = HitMaskRecorder()
-        SimulationEngine(instruments=(rec_a,), use_batch=False).run(a, tr)
-        b = REGISTRY.build(spec, cap, data_plane="device_batched",
-                           expected_entries=ee)
-        b._device_pipeline = b.admission_policy.bind_device_batch_plane(
-            b.main, chunk=8, victim_cap=2)
-        rec_b = HitMaskRecorder()
-        SimulationEngine(instruments=(rec_b,)).run(b, tr)
-        pipe = b.admission_policy._device_batch
-        assert pipe.resync_reasons["victim_cap"] > 0, "victim cap never tripped"
-        assert pipe.batched_decisions > 0, "everything fell back: not a batching test"
-        _assert_byte_identical(a, b, rec_a.hits, rec_b.hits, "victim_cap resync")
-        _assert_mirror_synced(b, "victim_cap resync")
-
     def test_mirror_overflow_mid_chunk_grows_and_matches(self):
-        """Entry growth past the mirror's slot table mid-run: the flush
-        pre-flight grows + re-uploads (resync_reasons['mirror_grow']) so no
-        in-scan insert can land past the device arrays; contents stay
-        byte-identical and the twin stays in sync."""
+        """Entry growth past the device slot arrays mid-run: the launch
+        pre-flight grows them on device (resync_reasons['mirror_grow']) so
+        no in-scan insert can land past the arrays; contents stay
+        byte-identical and the per-decision twin stays in sync."""
         rng = np.random.default_rng(5)
         ks = 800
         keys = ((rng.zipf(1.25, size=2500) - 1) % ks).astype(np.int64)
         sizes = np.minimum(rng.integers(8, 40, size=ks)[keys], 20).astype(np.int64)
-        cap = 20 * 400  # ~400 small entries: well past the 128-slot initial mirror
+        cap = 20 * 400  # ~400 small entries: well past the 64-slot first upload
         out = []
-        for plane in ("scalar", "device_batched"):
+        for plane in ("scalar", "device_full"):
             p = REGISTRY.build("wtlfu-qv-sampled_size?seed=9&sketch_backend=cms",
                                cap, data_plane=plane, expected_entries=256, chunk=16)
             hits = _drive_batched(p, keys, sizes, step=53)
+            p.sync_deferred()
             out.append((p, hits))
         (a, ha), (b, hb) = out
         assert ha == hb and a.main.sizes == b.main.sizes
-        pipe = b.admission_policy._device_batch
+        pipe = b._device_pipeline
         assert len(b.main.keys) > 128
         assert pipe.resync_reasons["mirror_grow"] > 0, "mirror growth never exercised"
         _assert_mirror_synced(b, "mirror growth")
 
-    # -- DeviceMirror stale-slot regression (ISSUE 5 satellite) ------------
+    # -- DeviceMirror stale-slot regression ----------------------------------
 
     def test_mirror_stale_slot_same_decision_backfill_chain(self):
         """Evicting multiple victims in one decision chains swap-removes:
@@ -636,7 +564,7 @@ class TestDeviceBatchedPlane:
         host eviction state byte-for-byte after every decision."""
         p = SizeAwareWTinyLFU(
             600, admission="av", eviction="sampled_size",
-            data_plane="device", window_frac=0.05, expected_entries=64,
+            data_plane="device_full", window_frac=0.05, expected_entries=64,
             sketch_kwargs={"sample_factor": 10_000})
         rnd = random.Random(0xBEEF)
         for i in range(600):
@@ -647,22 +575,24 @@ class TestDeviceBatchedPlane:
     def test_mirror_slot_reuse_across_decision_boundary(self):
         """Evict-then-reinsert of the same key across a decision boundary
         reuses freed slots: the twin must track the reused slot's new
-        (key, size), not the stale tenant — on both device planes."""
-        for plane in ("device", "device_batched"):
+        (key, size), not the stale tenant — under per-access drive and
+        after chunked drives."""
+        for drive in ("access", "access_batch"):
             p = SizeAwareWTinyLFU(
                 400, admission="qv", eviction="sampled_frequency",
-                data_plane=plane, window_frac=0.1, expected_entries=64)
+                data_plane="device_full", window_frac=0.1, expected_entries=64)
             rnd = random.Random(7)
             keys = [rnd.randrange(25) for _ in range(500)]
             sizes = [15 + (k * 7) % 60 for k in keys]
-            if plane == "device":
+            if drive == "access":
                 for i, (k, s) in enumerate(zip(keys, sizes)):
                     p.access(k, s)
-                    _assert_mirror_synced(p, f"{plane} access {i}")
+                    _assert_mirror_synced(p, f"{drive} access {i}")
             else:
                 for lo in range(0, len(keys), 31):
                     _drive_batched(p, keys[lo : lo + 31], sizes[lo : lo + 31], step=31)
-                    _assert_mirror_synced(p, f"{plane} chunk at {lo}")
+                    p.sync_deferred()
+                    _assert_mirror_synced(p, f"{drive} chunk at {lo}")
 
 
 class TestDeviceFullPlane:
@@ -815,81 +745,84 @@ class TestDeviceFullPlane:
                 eng.add(p, keys, sizes)
                 eng.run()
 
-    def test_device_batched_dispatch_adopts_donated_buffers(self):
-        """ISSUE 7 satellite (device_batched side): `_decide_sampled_chunk`
-        donates (table, mkeys, msizes); the pipeline must adopt the launch
-        outputs at DISPATCH time — by collect the stale inputs are gone."""
-        from repro.kernels import admission as adm
-
-        recorded = []
-        real = adm._decide_sampled_chunk
-
-        def recording(*args, **kw):
-            outs = real(*args, **kw)
-            recorded.append(outs)
-            return outs
-
-        # huge sketch sample (no aging), all-distinct keys (no visibility
-        # flushes): decisions buffer and resolve only through chunk
-        # launches. defer_collect leaves the trailing launch in flight, so
-        # dispatch-time adoption is observable before any collect.
-        p = SizeAwareWTinyLFU(
-            800, admission="qv", eviction="sampled_frequency",
-            data_plane="device_batched", chunk=8, expected_entries=64,
-            sketch_kwargs={"sample_factor": 10_000})
-        pipe = p.admission_policy._device_batch
-        pipe.defer_collect = True
-        fresh = iter(range(10 ** 6))
-        try:
-            adm._decide_sampled_chunk = recording
-            for _ in range(20):
-                ks = np.asarray([next(fresh) for _ in range(12)], np.int64)
-                p.access_batch(ks, np.full(12, 30, np.int64))
-                if pipe._inflight is not None:
-                    break
-        finally:
-            adm._decide_sampled_chunk = real
-        assert pipe._inflight is not None, "no trailing chunk stayed in flight"
-        assert recorded, "chunk kernel never launched"
-        table, mkeys, msizes = recorded[-1][:3]
-        assert p.sketch.table is table, "table adopted only at collect"
-        assert pipe.mirror._dev[0] is mkeys
-        assert pipe.mirror._dev[1] is msizes
-        pipe.sync(p)  # settle before teardown
-
     def test_host_sync_guards_restore_authority(self):
-        """Scalar ``access`` and ``__contains__`` between chunked drives
-        must transparently restore host authority (download + rebuild) and
-        stay byte-identical to a pure-scalar replay."""
+        """Scalar ``access`` between chunked drives must transparently
+        restore host authority (download + rebuild), ``__contains__`` must
+        answer exactly from the device without taking authority back, and
+        the run must stay byte-identical to a pure-scalar replay."""
         rng = np.random.default_rng(11)
         keys = ((rng.zipf(1.2, size=420) - 1) % 32).astype(np.int64).tolist()
         sizes = [10 + (k * 13) % 70 for k in keys]
         spec = "wtlfu-av-slru?sketch_backend=cms"
         a = REGISTRY.build(spec, 700, data_plane="scalar", expected_entries=64)
         ha = [a.access(k, s) for k, s in zip(keys, sizes)]
+        r = REGISTRY.build(spec, 700, data_plane="scalar", expected_entries=64)
         e = REGISTRY.build(spec, 700, data_plane="device_full",
                            expected_entries=64, chunk=16)
+        plane = e._device_pipeline
         he = []
         # interleave chunk drives with scalar accesses and membership reads
         i = 0
         while i < len(keys):
             take = 48 if (i // 48) % 2 == 0 else 5
             block_k, block_s = keys[i:i + take], sizes[i:i + take]
+            for k, s in zip(block_k, block_s):
+                r.access(k, s)  # the scalar reference, in lockstep
             if take == 5:  # scalar path: forces ensure_host via the guard
                 he.extend(e.access(k, s) for k, s in zip(block_k, block_s))
-                assert not e._device_pipeline.has_deferred_work
+                assert not plane.has_deferred_work
             else:
                 he.extend(bool(h) for h in e.access_batch(
                     np.asarray(block_k, np.int64), np.asarray(block_s, np.int64)))
-                # membership read mid-run: the guard must sync first (the
-                # answer itself is validated by the final byte-identity)
-                probe = block_k[0]
-                probe in e
-                assert not e._device_pipeline.has_deferred_work
+                # membership read mid-run: exact for every key, read off the
+                # device, and the state stays there (no download + re-upload)
+                uploads = plane.uploads
+                assert [k in e for k in range(40)] == [k in r for k in range(40)]
+                assert plane.has_deferred_work and not plane._host_auth
+                assert plane.uploads == uploads
             i += take
         e.sync_deferred()
         _assert_byte_identical(a, e, np.asarray(ha), np.asarray(he),
                                "host-sync guards")
+
+    @pytest.mark.parametrize("eviction", ("slru", "sampled_frequency"))
+    def test_membership_waits_only_on_a_launch_that_misses(self, eviction):
+        """``__contains__`` reads the resident keys off the device once and
+        keeps them across deferred launches whose keys are all resident
+        (all hits, so residency cannot change: no wait on the launch); a
+        launch with a miss is collected before the next answer. Every
+        answer equals a scalar replay driven in lockstep."""
+        spec = f"wtlfu-av-{eviction}?sketch_backend=cms"
+        r = REGISTRY.build(spec, 2_000, data_plane="scalar", expected_entries=64)
+        e = REGISTRY.build(spec, 2_000, data_plane="device_full",
+                           expected_entries=64, chunk=16)
+        plane = e._device_pipeline
+        plane.defer_collect = True
+        probes = range(80)
+
+        def drive(keys):
+            sizes = [20 + (k * 7) % 60 for k in keys]
+            for k, s in zip(keys, sizes):
+                r.access(k, s)
+            e.access_batch(np.asarray(keys, np.int64), np.asarray(sizes, np.int64))
+
+        def exact():
+            return [k in e for k in probes] == [k in r for k in probes]
+
+        drive(list(range(16)))
+        assert plane._inflight is not None
+        assert exact() and plane._inflight is None  # first read collects
+        resident = [k for k in probes if k in r]
+        drive([resident[i % len(resident)] for i in range(16)])  # all hits
+        assert plane._inflight is not None
+        assert exact()
+        assert plane._inflight is not None, "an all-hit launch was waited for"
+        drive(list(range(60, 76)))  # misses: residency changes
+        assert exact() and plane._inflight is None
+        assert plane.uploads == 1 and not plane._host_auth
+        e.sync_deferred()
+        assert sorted(e.window) == sorted(r.window)
+        assert sorted(e.main.sizes.items()) == sorted(r.main.sizes.items())
 
     def test_serving_defer_collect_double_buffers(self):
         """The serving async pipeline drives device_full unchanged through
@@ -910,7 +843,9 @@ class TestDeviceFullPlane:
         pipe.sync()
         plane = p._device_pipeline
         assert plane.deferred_dispatches > 0, "defer path never engaged"
-        assert not plane.has_deferred_work
+        # settled: the launch is collected, and the verdicts were read off
+        # the device without taking authority back (one upload in all)
+        assert plane._inflight is None and plane.uploads == 1
         m = pipe.metrics()
         assert m["decisions"] == plane.decisions
 

@@ -291,6 +291,112 @@ class TestDeviceAdmissionPrimitives:
         valid = jnp.asarray([1, 0, 0, 1, 0, 0, 0, 0], bool)  # tie 1/2 vs 2/4
         assert int(_argmin_frac(num, den, pos, valid)) == 0
 
+    @pytest.mark.parametrize("eviction", [
+        "sampled_frequency", "sampled_size", "sampled_frequency_size",
+        "sampled_needed_size", "random"])
+    def test_sample_picker_matches_iter_victims(self, eviction):
+        """The device sample picker, called until every slot is taken,
+        yields the host walk's whole victim order (pool draws, rule
+        scores, ties, and the fallback scans of the late steps) from the
+        same seed, decision and sketch table."""
+        import functools
+
+        from repro.core import crng
+        from repro.core.eviction import make_eviction
+        from repro.kernels.admission import _sample_picker
+
+        rng = np.random.default_rng(0x51C)
+        width, slots, n, needed = 128, 64, 40, 57
+        table = rng.integers(0, 16, size=(cms_ref.ROWS, width)).astype(np.int32)
+
+        def freq(key):
+            idx = np.asarray(cms_ref.row_indexes(jnp.asarray([key], jnp.int32), width))
+            return int(table[np.arange(cms_ref.ROWS), idx[:, 0]].min())
+
+        e = make_eviction(eviction, capacity=10**9, freq_fn=freq, seed=0xA11CE)
+        keys = rng.choice(1 << 20, size=n, replace=False).tolist()
+        for k, s in zip(keys, rng.integers(1, 9, size=n).tolist()):
+            e.insert(k, 8 * s)  # few distinct sizes: score ties happen
+        for _ in range(3):
+            e.begin_decision()
+        host = list(e.iter_victims(needed))
+        assert sorted(host) == sorted(keys)
+
+        @functools.partial(jax.jit, static_argnames=("rule", "sample"))
+        def order(table, mk_lo, msz, bhi, blo, *, rule, sample):
+            next_victim = _sample_picker(table, mk_lo, msz, jnp.int32(n),
+                                         jnp.int32(needed), bhi, blo,
+                                         rule=rule, sample=sample)
+
+            def body(carry, _):
+                taken, step, fb = carry
+                best, step, fb = next_victim(taken, step, fb)
+                return (taken.at[best].set(True), step, fb), best
+
+            init = (jnp.zeros(slots, bool), jnp.int32(0), jnp.int32(0))
+            (_, _, fb), seq = jax.lax.scan(body, init, None, length=n)
+            return seq, fb
+
+        mk_lo = np.zeros(slots, np.int32)
+        msz = np.zeros(slots, np.int32)
+        mk_lo[:n] = e.keys
+        msz[:n] = [e.sizes[k] for k in e.keys]
+        base = crng.stream_key(e.seed, e.decision)
+        seq, fb = order(jnp.asarray(table), jnp.asarray(mk_lo), jnp.asarray(msz),
+                        jnp.uint32(base >> 32), jnp.uint32(base & 0xFFFFFFFF),
+                        rule=e.rule, sample=e.SAMPLE)
+        assert [e.keys[s] for s in np.asarray(seq).tolist()] == host
+        assert int(fb) == e.fallback_scans > 0
+
+    @pytest.mark.parametrize("eviction", ["lru", "slru"])
+    def test_recency_select_matches_eviction_order(self, eviction):
+        """The device recency selector over stamped slots (rows in a
+        shuffled slot order, each stamped at its last insert or touch)
+        yields the host policy's victim order: LRU first, and for SLRU the
+        whole probation segment before protected."""
+        from repro.core.eviction import make_eviction
+        from repro.kernels.admission import _recency_select
+
+        rng = np.random.default_rng(0x5E1)
+        slots, n = 64, 40
+        e = make_eviction(eviction, capacity=10**9, freq_fn=lambda k: 0)
+        stamp = {}
+        tick = 0
+        # inserts and touches interleaved: some protected entries are
+        # older than some probation ones, so probation-first is not LRU
+        fresh = iter(rng.choice(10_000, size=n, replace=False).tolist())
+        for touch in rng.permutation(np.r_[np.zeros(n, bool), np.ones(60, bool)]):
+            if touch and not stamp:
+                continue
+            if touch:
+                k = int(rng.choice(list(stamp)))
+                e.on_access(k)
+            else:
+                k = next(fresh)
+                e.insert(k, 10)
+            stamp[k] = tick = tick + 1
+        host = list(e.iter_victims())
+        protected = getattr(e, "protected", {})
+        if eviction == "slru":
+            assert 0 < len(protected) < n, "both segments must be populated"
+
+        slot_of = dict(zip(stamp, rng.permutation(n).tolist()))
+        mstamp = np.zeros(slots, np.int32)
+        mseg = np.zeros(slots, np.int32)
+        for k, s in slot_of.items():
+            mstamp[s] = stamp[k]
+            mseg[s] = int(k in protected)
+        select = jax.jit(lambda taken, mstamp, mseg: _recency_select(
+            mstamp, mseg, jnp.int32(n), slru=eviction == "slru")(taken))
+        taken = np.zeros(slots, bool)
+        key_at = {s: k for k, s in slot_of.items()}
+        got = []
+        for _ in range(n):
+            v = int(select(jnp.asarray(taken), jnp.asarray(mstamp), jnp.asarray(mseg)))
+            taken[v] = True
+            got.append(key_at[v])
+        assert got == host
+
 
 # ---------------------------------------------------------------------------
 # flash attention

@@ -140,3 +140,37 @@ def test_results_equal_the_scalar_plane(main, defer):
     assert p.main.sizes == scalar.main.sizes
     assert np.array_equal(np.asarray(p.sketch.table), np.asarray(scalar.sketch.table))
     assert p.sketch.resets == scalar.sketch.resets > 0
+
+
+
+@pytest.mark.parametrize("main", sorted(SPECS))
+def test_deferred_takes_run_their_own_bucket(main, monkeypatch):
+    """A deferred drive (the serving pipeline's) scans its take's
+    power-of-two bucket, at least 8 and at most the plane's scan length; a
+    collected drive keeps that one length. Both equal the scalar plane."""
+    keys, sizes = _trace(n=300)
+    cuts = np.cumsum([0] + [3, 11, 16] * 10)
+    pads = {}
+    for defer in (False, True):
+        p = _build(main)
+        pipe = p._device_pipeline
+        pipe.defer_collect = defer
+        rec = Recorder(df._simulate_chunk, pipe)
+        monkeypatch.setattr(df, "_simulate_chunk", rec)
+        hits = []
+        for lo, hi in zip(cuts[:-1], cuts[1:]):
+            # a deferred launch hands back its padded mask
+            hits.extend(bool(h) for h in np.asarray(
+                p.access_batch(keys[lo:hi], sizes[lo:hi]))[:hi - lo])
+        p.sync_deferred()
+        assert pipe.scan_len() == 16
+        pads[defer] = [(args[10].shape[0], int(args[13][df._SCAL_IDX["a_n"]]))
+                       for args, _, _ in rec.launches]
+        scalar = _build(main, plane="scalar")
+        assert hits == [scalar.access(int(k), int(s))
+                        for k, s in zip(keys[:cuts[-1]], sizes[:cuts[-1]])]
+        assert p.main.sizes == scalar.main.sizes
+        assert list(p.window.items()) == list(scalar.window.items())
+    assert {pad for pad, _ in pads[False]} == {16}
+    assert all(pad == (8 if take <= 8 else 16) for pad, take in pads[True])
+    assert {pad for pad, _ in pads[True]} == {8, 16}

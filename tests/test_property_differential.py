@@ -1,26 +1,23 @@
-"""Property-based differential suite: scalar vs batched vs device planes.
+"""Property-based differential suite: scalar vs batched vs device_full.
 
-ISSUE 3 acceptance: with counter-based victim sampling every eviction
-policy is peek-stable, so ``data_plane="batched"`` must be **byte-identical**
-to ``"scalar"`` — same hit/miss decision stream, same ``CacheStats``
-counters, same final cache contents — for every admission x eviction combo,
-sampled evictions included. ISSUE 4 extends the assertion three ways:
-``data_plane="device"`` (the closed-loop device-resident decision kernel,
-CMS backend) must match both host planes over the same 21-combo grid.
-ISSUE 5 extends it four ways: ``data_plane="device_batched"`` (decision
-chunks per launch, driven through ``access_batch`` so the buffering
-engages) must match too — decisions, stats, contents, fallback counters.
-ISSUE 7 extends it five ways: ``data_plane="device_full"`` (the WHOLE
-simulation step — window hits, recency updates, miss cascade, adaptive
-climber — in one ``lax.scan`` per chunk, cache state device-resident
-between chunks) must match on decisions, stats, final contents, window
-occupancy, and the adaptive ``window_cap`` trajectory, with host resyncs
-only on sketch aging resets and mirror growth (both test-forced below).
-ISSUE 8 adds the sixth column: every member of a vmapped
-``FleetEngine`` sweep (``TestFleetDifferential``) must match the
-sequential ``device_full`` loop per instance — hit stream, stats,
-contents, resync/upload counters — including test-forced per-lane aging
-and mirror-growth resyncs inside a mixed multi-bucket fleet.
+With counter-based victim sampling every eviction policy is peek-stable,
+so ``data_plane="batched"`` must be **byte-identical** to ``"scalar"`` —
+same hit/miss decision stream, same ``CacheStats`` counters, same final
+cache contents — for every admission x eviction combo, sampled evictions
+included. ``data_plane="device_full"`` (CMS backend) must match both host
+planes over the same 21-combo grid, driven two ways: through scalar
+``access``, where each admission decision is one jitted per-decision
+kernel call, and through ``access_batch``, where the WHOLE simulation
+step — window hits, recency updates, miss cascade, adaptive climber —
+runs in one ``lax.scan`` per chunk with the cache state device-resident
+between chunks. The chunked drive must match on decisions, stats, final
+contents, window occupancy, and the adaptive ``window_cap`` trajectory,
+with host resyncs only on sketch aging resets and mirror growth (both
+test-forced below). Every member of a vmapped ``FleetEngine`` sweep
+(``TestFleetDifferential``) must match the sequential ``device_full`` loop
+per instance — hit stream, stats, contents, resync/upload counters —
+including test-forced per-lane aging and mirror-growth resyncs inside a
+mixed multi-bucket fleet.
 
 Four layers:
 
@@ -28,9 +25,9 @@ Four layers:
   hypothesis (tier-1), re-seedable via ``REPRO_DIFF_SEED`` (the nightly CI
   seed-matrix job reruns it under several fixed seeds);
 * the **device-plane grid**: the same 21 combos under ``sketch_backend=
-  "cms"``, asserting scalar == batched == device == device_batched
-  (decisions, CacheStats, final cache contents, sampling fallback
-  counters), same reseeding;
+  "cms"``, asserting scalar == batched == device_full by ``access`` ==
+  device_full by ``access_batch`` (decisions, CacheStats, final cache
+  contents, sampling fallback counters), same reseeding;
 * **hypothesis properties** generating random traces (key skew, size
   distributions, capacities) and random ``PolicySpec`` strings (window
   fraction, pruning, ``?seed=``), asserting plane equivalence and spec
@@ -94,10 +91,9 @@ def _run_plane(spec, capacity, keys, sizes, plane, **kw):
 
 
 def _run_plane_chunked(spec, capacity, keys, sizes, plane, step=29, **kw):
-    """Drive via ``access_batch`` in uneven chunks — the decision-batched
-    plane defers admissions inside a chunk, so this is the path that
-    exercises its buffering (access-by-access it degenerates to the
-    per-decision kernel)."""
+    """Drive via ``access_batch`` in uneven chunks — under ``device_full``
+    this is the path that runs the chunk program (access-by-access it
+    degenerates to the per-decision kernel)."""
     p = REGISTRY.build(spec, capacity, data_plane=plane, **kw)
     hits = []
     ka = np.asarray(keys, dtype=np.int64)
@@ -163,10 +159,9 @@ class TestSeededGrid:
 
 
 class TestDeviceSeededGrid:
-    """ISSUE 4/5 acceptance: ``data_plane="device"`` — the closed-loop
-    sample->score->select decision kernel — and ``"device_batched"`` — the
-    decision-chunked ``lax.scan`` pipeline, driven through ``access_batch``
-    so its buffering actually engages — are byte-identical to BOTH host
+    """``data_plane="device_full"`` driven through scalar ``access`` — the
+    closed sample->score->select per-decision kernel — and through
+    ``access_batch`` — the chunk program — is byte-identical to BOTH host
     planes for every admission x eviction combo under the CMS backend,
     reseedable via ``REPRO_DIFF_SEED``."""
 
@@ -179,26 +174,21 @@ class TestDeviceSeededGrid:
                 f"?window_frac=0.1&seed={DIFF_SEED}&sketch_backend=cms")
         out = [
             _run_plane(spec, cap, keys, sizes, plane, expected_entries=64)
-            for plane in ("scalar", "batched", "device")
+            for plane in ("scalar", "batched", "device_full")
         ]
-        out.append(_run_plane_chunked(spec, cap, keys, sizes, "device_batched",
-                                      expected_entries=64, chunk=4))
         out.append(_run_plane_chunked(spec, cap, keys, sizes, "device_full",
                                       expected_entries=64, chunk=4))
-        (a, ha), (b, hb), (c, hc), (d, hd), (e, he) = out
+        (a, ha), (b, hb), (c, hc), (e, he) = out
         _assert_identical(a, b, ha, hb, f"{spec} scalar-vs-batched")
-        _assert_identical(a, c, ha, hc, f"{spec} scalar-vs-device")
-        _assert_identical(a, d, ha, hd, f"{spec} scalar-vs-device_batched")
+        _assert_identical(a, c, ha, hc, f"{spec} scalar-vs-device_full access")
         e.sync_deferred()  # restore host authority before content compares
-        _assert_identical(a, e, ha, he, f"{spec} scalar-vs-device_full")
+        _assert_identical(a, e, ha, he, f"{spec} scalar-vs-device_full chunks")
         assert a.stats.evictions > 0, f"{spec}: trace never evicted"
         if eviction not in ("lru", "slru"):
             assert a.main.fallback_scans == c.main.fallback_scans, \
-                f"{spec}: device fallback-scan count diverges"
-            assert a.main.fallback_scans == d.main.fallback_scans, \
-                f"{spec}: device_batched fallback-scan count diverges"
+                f"{spec}: device_full access fallback-scan count diverges"
             assert a.main.fallback_scans == e.main.fallback_scans, \
-                f"{spec}: device_full fallback-scan count diverges"
+                f"{spec}: device_full chunks fallback-scan count diverges"
 
     @pytest.mark.parametrize("eviction", ("sampled_frequency", "slru"))
     def test_device_pallas_branch_matches_scalar(self, eviction):
@@ -215,24 +205,24 @@ class TestDeviceSeededGrid:
         # table reads, so use_pallas cannot change its values — and Pallas
         # interpret mode per scalar estimate would dominate the suite
         a, ha = _run_plane(spec, cap, keys, sizes, "scalar", expected_entries=64)
-        c, hc = _run_plane(spec, cap, keys, sizes, "device", expected_entries=64,
-                           sketch_kwargs={"use_pallas": True})
+        c, hc = _run_plane(spec, cap, keys, sizes, "device_full",
+                           expected_entries=64, sketch_kwargs={"use_pallas": True})
         assert c.sketch.use_pallas
-        _assert_identical(a, c, ha, hc, f"{spec} device/use_pallas=True")
+        _assert_identical(a, c, ha, hc, f"{spec} device_full/use_pallas=True")
 
     @pytest.mark.parametrize("admission,eviction",
                              [("iv", "random"), ("qv", "sampled_frequency"), ("av", "slru")])
     def test_three_planes_across_aging_resets(self, admission, eviction):
-        """A small sketch forces aging resets mid-trace: the device plane
-        must stage its pending flush at the same boundaries the host planes
-        do (same resets, same tables, same decisions)."""
+        """A small sketch forces aging resets mid-trace: the per-decision
+        kernel must stage its pending flush at the same boundaries the host
+        planes do (same resets, same tables, same decisions)."""
         rng = np.random.default_rng([DIFF_SEED, 0xA61, _combo_key(admission, eviction)])
         keys, sizes = _synth_trace(rng, n=400, key_space=40, size_mode="clustered")
         cap = max(120, int(np.mean(sizes) * 8))
         spec = f"wtlfu-{admission}-{eviction}?seed={DIFF_SEED}&sketch_backend=cms"
         out = [
             _run_plane(spec, cap, keys, sizes, plane, expected_entries=16)
-            for plane in ("scalar", "device")
+            for plane in ("scalar", "device_full")
         ]
         (a, ha), (c, hc) = out
         assert a.sketch.resets > 0, "trace too short to age the sketch"
@@ -584,21 +574,22 @@ class TestCMSBackendDifferential:
         out = [
             _run_plane(spec, cap, keys, sizes, plane,
                        expected_entries=64, sketch_backend="cms")
-            for plane in ("scalar", "batched", "device")
+            for plane in ("scalar", "batched", "device_full")
         ]
-        out.append(_run_plane_chunked(spec, cap, keys, sizes, "device_batched",
+        out.append(_run_plane_chunked(spec, cap, keys, sizes, "device_full",
                                       expected_entries=64, sketch_backend="cms",
                                       chunk=6))
         (a, ha), (b, hb), (c, hc), (d, hd) = out
+        d.sync_deferred()
         _assert_identical(a, b, ha, hb, f"cms:{spec}")
-        _assert_identical(a, c, ha, hc, f"cms-device:{spec}")
-        _assert_identical(a, d, ha, hd, f"cms-device_batched:{spec}")
+        _assert_identical(a, c, ha, hc, f"cms-device_full-access:{spec}")
+        _assert_identical(a, d, ha, hd, f"cms-device_full-chunks:{spec}")
 
 
 class TestServingDifferential:
-    """ISSUE 6 fifth column: decisions driven *through the serving layer*
-    — ``PrefixCache`` with the async admission pipeline (event queue ->
-    ``access_batch`` -> deferred device chunks) must replay byte-identical
+    """Decisions driven *through the serving layer* — ``PrefixCache``
+    with the async admission pipeline (event queue -> ``access_batch`` ->
+    deferred device chunk launches) must replay byte-identical
     to the synchronous per-access hook: same resident entries, same hit
     ratios, same policy stats, same window/main contents."""
 
@@ -641,7 +632,7 @@ class TestServingDifferential:
         self._assert_serving_identical(spec, _combo_key(admission, eviction))
 
     @pytest.mark.parametrize("admission", ADMISSIONS)
-    def test_device_batched_serving_identity(self, admission):
+    def test_device_full_serving_identity(self, admission):
         spec = (f"wtlfu-{admission}-sampled_frequency?seed={DIFF_SEED}"
-                "&data_plane=device_batched&chunk=16&sketch_backend=cms")
+                "&data_plane=device_full&chunk=16&sketch_backend=cms")
         self._assert_serving_identical(spec, 0x5E41 + _combo_key(admission, "d"))

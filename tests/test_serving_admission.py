@@ -21,7 +21,7 @@ from repro.serving import (
 
 DEVICE_SPEC = (
     "wtlfu-av-sampled_frequency"
-    "?data_plane=device_batched&chunk=16&sketch_backend=cms"
+    "?data_plane=device_full&chunk=16&sketch_backend=cms"
 )
 
 
@@ -94,6 +94,40 @@ class TestHooks:
         assert m["mode"] == "async" and m["syncs"] == 1
         assert m["max_queue_depth"] == 3
 
+    def test_resident_prefix_offer_forces_no_resolve(self):
+        """PrefixCache: re-offering a materialized prefix with no verdict
+        pending pends nothing, so the next matching lookup is answered
+        without a resolve."""
+        c = make_cache(admission="async")
+        prompt = list(range(8))  # two blocks
+        c.offer(prompt)
+        c.sync()
+        assert c.lookup(prompt + [99])[0] == 8
+        syncs = c.admission.syncs
+        assert c.offer(prompt, payload="kv") is None
+        assert not c.admission.has_pending_offers and not c._pending_cands
+        assert c.entries[block_hashes(prompt, 4)[-1]].payload == "kv"
+        assert c.lookup(prompt + [98])[0] == 8
+        assert c.admission.syncs == syncs, "a known hit forced a resolve"
+
+    def test_resident_offer_behind_a_pending_eviction(self):
+        """A re-offer of a materialized prefix while another offer is
+        pending may no longer hit (the pending offer can evict it), so it
+        pends and resolves like the synchronous replay."""
+        caches = [make_cache(policy="lru", admission=m, capacity_blocks=4)
+                  for m in ("sync", "async")]
+        for c in caches:
+            a, b, new = ([t * 100 + j for j in range(8)] for t in (1, 2, 3))
+            for prompt in (a, b):
+                c.offer(prompt)
+                c.sync()
+            c.offer(new)  # evicts a, the LRU entry
+            c.offer(a)  # a miss now: re-admitted, evicting b
+            c.sync()
+        sync, a = caches
+        assert len(sync.entries) == 2
+        assert_caches_identical(sync, a)
+
     def test_unknown_mode_raises(self):
         c = make_cache()
         with pytest.raises(ValueError, match="unknown admission mode"):
@@ -110,7 +144,7 @@ class TestAsyncIdentity:
         assert_caches_identical(sync, a)
         assert sync.request_hit_ratio > 0  # regime sanity
 
-    def test_device_batched_identity(self):
+    def test_device_full_identity(self):
         sync = drive(make_cache(policy=DEVICE_SPEC, admission="sync"), n=250)
         a = drive(make_cache(policy=DEVICE_SPEC, admission="async"), n=250)
         assert_caches_identical(sync, a)

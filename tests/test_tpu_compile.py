@@ -177,20 +177,22 @@ def test_simulate_chunk(one_chip, main_kind):
 
 
 def test_decision_chunk(one_chip):
-    """The serving prefix cache's decision-chunk program (device_batched)."""
+    """The per-decision program of the host path over a sampled main
+    (``_decide_sampled``: mirror scatter, CMS flush, sample walk)."""
     import jax.numpy as jnp
 
-    from repro.kernels.admission import _WRITE_PAD, _decide_sampled_chunk
+    from repro.kernels.admission import _WRITE_PAD, _decide_sampled
     from repro.kernels.cms.ref import ROWS
 
     def s(*shape, dtype=None):
         return _sds(one_chip, shape, dtype)
 
-    compiled = _decide_sampled_chunk.lower(
-        s(ROWS, 1024), s(64), s(64), s(3, _WRITE_PAD), s(8, 16), s(8, 4),
-        s(3), s(2, dtype=jnp.uint32),
-        discipline="av", rule="frequency", sample=5, early_pruning=True,
-        cap=15, use_pallas=True, interpret=False, vcap=16).compile()
+    pad = (s(_WRITE_PAD), s(_WRITE_PAD), s(_WRITE_PAD))
+    compiled = _decide_sampled.lower(
+        s(ROWS, WIDTH), s(SLOTS), s(SLOTS), *pad, s(16), s(), s(), s(), s(),
+        s(dtype=jnp.uint32), s(dtype=jnp.uint32),
+        discipline="qv", rule="frequency_size", sample=5, early_pruning=True,
+        cap=15, use_pallas=True, interpret=False).compile()
     _assert_kernel(compiled)
 
 
